@@ -6,7 +6,8 @@
 PY ?= python
 
 .PHONY: test chaos chaos-restart chaos-serving bench lint lint-shapes \
-	lint-coherence lint-obligations multichip race native-ext test-journal
+	lint-coherence lint-obligations multichip race native-ext test-journal \
+	proto rehearse
 
 # graftlint: the project-native static analysis suite (guarded-by,
 # hot-path purity, registry drift, lock-order, tensor-contract,
@@ -35,9 +36,16 @@ lint-coherence:
 lint-obligations:
 	$(PY) -m kubernetes_tpu.analysis --obligations
 
-test:
+test: rehearse
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow and not chaos' \
 		--continue-on-collection-errors -p no:cacheprovider
+
+# chip_smoke.py's four stages at toy sizes on the CPU platform (~40 s):
+# the pre-flight before any chip call.  Tier-1 is cut off by the clock
+# and carries only stage 2 and the refusal/fallback cases
+# (tests/test_chip_smoke.py), so the passing stage 1 runs here.
+rehearse:
+	JAX_PLATFORMS=cpu $(PY) chip_smoke.py --rehearse-cpu
 
 # graftsched: the concurrency gate (docs/static_analysis.md).  Arms the
 # runtime lock-order tracker for the whole session and runs the
@@ -93,8 +101,17 @@ multichip:
 	XLA_FLAGS="--xla_force_host_platform_device_count=8" JAX_PLATFORMS=cpu \
 		$(PY) -m pytest tests/ -q -m multichip -p no:cacheprovider
 
+# the BENCH_STRICT gates on the CPU platform: parity, recompile and
+# leak COUNTS are valid from any platform; its times and rates are not
+# device figures (the chip is reached with `python chip_smoke.py` through
+# the builder's chip tool — .claude/skills/verify/SKILL.md)
 bench:
 	JAX_PLATFORMS=cpu BENCH_STRICT=1 $(PY) bench.py
+
+# regenerate the committed protobuf gencode after editing the contract
+# (nothing regenerates it at import time)
+proto:
+	cd kubernetes_tpu/proto && protoc --python_out=. snapshot.proto
 
 # optional _hostplane C extension (native/hostplane.c): journal frame
 # trailer splice + CRC and proto wire framing.  Pure accelerator —

@@ -1161,6 +1161,12 @@ class Store:
             for i in range(n)
         ]
         if journal_path:
+            logging.getLogger(__name__).info(
+                "journal %s: %d shard(s), sync=%s, framer=%s",
+                journal_path, n, journal_sync,
+                "native _hostplane" if framing.native_available()
+                else "python (no _hostplane extension built)",
+            )
             t_rec = time.monotonic()
             if inferred is not None and shards and inferred != shards:
                 # explicit shard count disagrees with the on-disk layout:

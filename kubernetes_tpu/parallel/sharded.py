@@ -34,23 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map graduated from jax.experimental after 0.4.x and
-    renamed check_rep to check_vma; accept both APIs so the sharded
-    solvers run on either jax generation."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
 from ..analysis import retrace
 from ..ops.assign import (
     DEFAULT_WAVE_CAP,
@@ -229,7 +212,7 @@ def sharded_greedy_assign(
     if statics is None:
 
         @partial(
-            _shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=_snapshot_in_specs(parts),
             out_specs=out_specs,
@@ -247,7 +230,7 @@ def sharded_greedy_assign(
         return run(*parts)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=_snapshot_in_specs(parts) + (STATICS_SPECS,),
         out_specs=out_specs,
@@ -304,7 +287,7 @@ def sharded_wavefront_assign(
     if statics is None:
 
         @partial(
-            _shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=_snapshot_in_specs(parts) + (rep,),
             out_specs=out_specs,
@@ -322,7 +305,7 @@ def sharded_wavefront_assign(
         return run(*parts, members)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=_snapshot_in_specs(parts) + (rep, STATICS_SPECS),
         out_specs=out_specs,
@@ -388,7 +371,7 @@ def sharded_auction_assign(
     )
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=_snapshot_in_specs(parts),
         out_specs=out_specs,
@@ -696,7 +679,7 @@ def podsharded_wavefront_assign(
     if statics is None:
 
         @partial(
-            _shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=rep_parts + (P(None, POD_AXIS),),
             out_specs=out_specs,
@@ -716,7 +699,7 @@ def podsharded_wavefront_assign(
     statics_rep = ClassStatics(sfeas=rep, aff=rep, taint=rep)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=rep_parts + (P(None, POD_AXIS), statics_rep),
         out_specs=out_specs,
@@ -838,7 +821,7 @@ def sharded_batched_dry_run(
     )
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(in_specs,),
         out_specs=out_specs,
@@ -876,7 +859,7 @@ def sharded_static_feasible_batch(
     )
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P(POD_AXIS, None),

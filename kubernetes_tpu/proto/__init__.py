@@ -1,31 +1,7 @@
 """The dense-snapshot proto boundary (SURVEY §2.6's Go↔JAX shim).
 
-snapshot.proto is the contract; snapshot_pb2 is committed generated
-code, regenerated on import if protoc is available and the .proto is
-newer (so editing the contract never ships stale gencode)."""
+snapshot.proto is the contract; snapshot_pb2 is the committed generated
+code.  Nothing regenerates it behind the caller's back: after editing
+the contract run `make proto`."""
 
-from __future__ import annotations
-
-import os
-import subprocess
-
-_here = os.path.dirname(__file__)
-_proto = os.path.join(_here, "snapshot.proto")
-_gen = os.path.join(_here, "snapshot_pb2.py")
-
-if (
-    os.path.exists(_proto)
-    and (
-        not os.path.exists(_gen)
-        or os.path.getmtime(_proto) > os.path.getmtime(_gen)
-    )
-):
-    try:  # best effort; the committed gencode is the fallback
-        subprocess.run(
-            ["protoc", f"--python_out={_here}", "snapshot.proto"],
-            cwd=_here, check=True, capture_output=True,
-        )
-    except (OSError, subprocess.CalledProcessError):
-        pass
-
-from . import snapshot_pb2  # noqa: E402,F401
+from . import snapshot_pb2  # noqa: F401

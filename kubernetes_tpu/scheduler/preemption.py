@@ -52,7 +52,7 @@ import numpy as np
 
 from ..api import store as st
 from ..api import types as api
-from ..models.batch_scheduler import TPUBatchScheduler
+from ..models.batch_scheduler import TPUBatchScheduler, device_label
 from ..ops import preemption as pre_ops
 from ..testing import faults
 from ..utils.vocab import pad_dim
@@ -231,7 +231,9 @@ class PreemptionEvaluator:
             self._encode_and_dispatch(ctx, elig)
         except Exception:  # noqa: BLE001 — batched dispatch fault
             logging.getLogger(__name__).exception(
-                "batched preemption dry-run failed; retrying once"
+                "batched preemption dry-run failed on %s (%d preemptors "
+                "over %d nodes); retrying once", device_label(),
+                len(elig), len(self.tpu.state._rows),
             )
             try:
                 self._encode_and_dispatch(ctx, elig)
@@ -239,8 +241,8 @@ class PreemptionEvaluator:
                 if breaker is not None:
                     breaker.record_failure()
                 logging.getLogger(__name__).exception(
-                    "batched preemption retry failed; falling back to the "
-                    "per-pod path for this pass"
+                    "batched preemption retry failed on %s; falling back "
+                    "to the per-pod path for this pass", device_label(),
                 )
                 ctx.fallback = True
         return ctx

@@ -749,6 +749,17 @@ class SchedulingQueue:
                 _ledger.discharge("pod", key)
             self._push_backoff(info)
 
+    def retry_parked(self, info: QueuedPodInfo) -> None:
+        """A parked pod whose failure the cluster did not cause (the
+        overload ladder shed its PostFilter pass): nothing has to change
+        in the cluster for it to make progress, so no event will wake
+        it — retry after its backoff instead of waiting out the flush
+        interval.  No-op unless the pod is still parked (an event may
+        have woken it, or a delete dropped it, meanwhile)."""
+        with self._cond:
+            if self._unschedulable.pop(pod_key(info.pod), None) is not None:
+                self._push_backoff(info)
+
     def move_all_to_active_or_backoff(self, event: str = "") -> None:
         """A cluster event may have made unschedulable pods schedulable:
         move them to backoff (still inside their backoff window) or

@@ -50,9 +50,10 @@ from ..api import store as st
 from ..api import types as api
 from ..client.events import EventRecorder
 from ..client.informers import InformerFactory
-from ..models.batch_scheduler import TPUBatchScheduler
+from ..models.batch_scheduler import TPUBatchScheduler, device_label
 from ..ops import assign as assign_ops
 from ..testing import faults
+from ..utils import compileclock
 from ..utils.trace import Trace
 from .cache import SchedulerCache
 from .config import SchedulerConfiguration
@@ -73,7 +74,15 @@ class OverloadController:
     they shed — counting the pass made one expensive preemption round
     trip the ladder to level 2, which deferred preemption, which left
     no cycles to decay the average: preemption froze exactly when the
-    backlog needed it (the self-inhibition bench c9 exposed).
+    backlog needed it (the self-inhibition bench c9 exposed).  A cycle
+    that had to BUILD OR LOAD AN EXECUTABLE is not fed at all
+    (utils/compileclock): a first-of-a-bucket cycle blocks for seconds
+    in trace + XLA compile — or the persistent cache's load — and says
+    nothing about load; fed to the ladder, a cold start on the chip
+    read as severe overload and an otherwise idle cluster had no later
+    cycle to bring the level down, so preemptors waited for the
+    unschedulable flush.  The price: while every cycle compiles (a
+    retrace storm) the ladder learns nothing and holds its level.
 
       0  healthy — full work;
       1  overloaded (ewma > slo) — background work sheds first: the
@@ -81,14 +90,21 @@ class OverloadController:
          amortized the per-pod marginal cost, so an overloaded cycle
          keeps a small batch instead of deferring preemption outright —
          preemption load spikes exactly when the cluster is overloaded);
-         pods past the cap count into scheduler_overload_shed_total,
-         never the placement work itself;
+         pods past the cap count into scheduler_overload_shed_total and
+         retry with backoff (queue.retry_parked) — it is never the
+         placement work itself that is shed;
       2  severe (ewma > 2*slo) — preemption dry-runs defer entirely and
          the adaptive batch window pins at its max: fewer, fuller
          cycles shed per-cycle fixed overhead without dropping pods.
 
     Levels fall only when the EWMA drops below 80% of the rising
     threshold (hysteresis), so one fast cycle doesn't flap the ladder.
+    The average moves only when a cycle runs, so a cluster that goes
+    idle at a raised level would hold it for good with the shed pods
+    parked behind it: their backoff retries are the cycles that bring it
+    down (a 3,900-pod burst on the chip's shared host reached level 2 by
+    real staging time, and 8 preemptors sent behind it waited for the
+    300 s unschedulable flush).
     """
 
     GUARDED_FIELDS = {"_ewma": "_lock", "_level": "_lock"}
@@ -151,11 +167,15 @@ class _Cycle:
 
     __slots__ = ("stats", "trace", "reservations", "failed", "wave",
                  "pending", "solved_any", "batch", "handled",
-                 "spec_token", "mirror_points", "partials_points")
+                 "spec_token", "mirror_points", "partials_points",
+                 "compile_mark")
 
     def __init__(self, stats, trace, reservations, batch):
         self.stats = stats
         self.trace = trace
+        # the lane thread's compile count when the trace started: the
+        # cycle's dispatch and finish halves run on that one thread
+        self.compile_mark = compileclock.events()
         self.reservations = reservations
         self.failed: List[QueuedPodInfo] = []
         self.wave: List[tuple] = []
@@ -522,6 +542,9 @@ class Scheduler:
 
     def start(self) -> None:
         """Start informers + the scheduling loop thread."""
+        logging.getLogger(__name__).info(
+            "scheduler starting: solves run on %s", device_label()
+        )
         self.informers.informer("Node").start()
         self.informers.informer("Pod").start()
         self.informers.informer("PersistentVolume").start()
@@ -1507,6 +1530,10 @@ class Scheduler:
             # while this wave commits (assume entries already bridge it)
             self._dispatch_wave_async(cycle.wave)
             trace.step("dispatch")
+        # did the placement work block on a trace/compile?  (read
+        # before the PostFilter pass, which is timed out of the ladder's
+        # feed whole, its own compiles included)
+        compiled = compileclock.events() != cycle.compile_mark
         if cycle.solved_any:
             # PostFilter: preemption for unschedulable pods, highest
             # priority first (handleSchedulingFailure ->
@@ -1519,8 +1546,10 @@ class Scheduler:
             # load spikes exactly when the cluster is overloaded, so
             # deferring it outright was backwards) and deferred only at
             # level 2; pods past the cap count into overload_shed_total
-            # and stay parked for a later healthy cycle (or the flush
-            # interval).
+            # and retry with backoff — the scheduler's load shed them,
+            # not the cluster, so no event would wake them, and in a
+            # cluster gone idle their retries are the only cycles left
+            # to bring the level down.
             cycle.failed.sort(key=lambda i: -i.pod.spec.priority)
             t_postfilter = self._clock()
             budget = self.max_preemptions_per_cycle
@@ -1555,10 +1584,11 @@ class Scheduler:
                 logging.getLogger(__name__).exception(
                     "PostFilter preemption pass failed; continuing"
                 )
-            if len(eligible) > len(batch_infos):
-                self.metrics.overload_shed_total.inc(
-                    by=float(len(eligible) - len(batch_infos))
-                )
+            shed = eligible[len(batch_infos):]
+            if shed:
+                self.metrics.overload_shed_total.inc(by=float(len(shed)))
+                for info in shed:
+                    self.queue.retry_parked(info)
             postfilter_s = self._clock() - t_postfilter
             trace.step("postfilter")
             qs = self.queue.stats()
@@ -1570,11 +1600,15 @@ class Scheduler:
         self.metrics.schedule_batch_duration.observe(trace.total)
         # overload ladder: feed the cycle's PLACEMENT duration — the
         # PostFilter pass is excluded (see OverloadController: shedding
-        # must not be driven by the work it sheds) — publish the level,
+        # must not be driven by the work it sheds), and a cycle that
+        # compiled is no reading of load at all — publish the level,
         # and let the adaptive window react (level 2 pins it wide)
-        level = self.overload.note_cycle(
-            max(trace.total - postfilter_s, 0.0)
-        )
+        if compiled:
+            level = self.overload.level()
+        else:
+            level = self.overload.note_cycle(
+                max(trace.total - postfilter_s, 0.0)
+            )
         self.metrics.overload_level.set(float(level))
         if self.window_ctl is not None:
             self.window_ctl.set_overload(level)
@@ -2065,7 +2099,15 @@ class Scheduler:
                     pods[:bucket], num_pods_hint=bucket, lock=self.cache.lock,
                 )
             except Exception:
-                log.exception("warmup bucket %d skipped", bucket)
+                # device compile/runtime faults were already contained
+                # (and logged with their executable key) by the solver's
+                # breaker; what reaches here is an encode failure
+                log.exception(
+                    "warmup skipped on %s: pod bucket %d over %d nodes "
+                    "(%d template pods) failed to encode",
+                    device_label(), bucket, len(self.tpu.state._rows),
+                    len(pods[:bucket]),
+                )
 
         def warm_all() -> None:
             # buckets in parallel: encode serializes under the cache

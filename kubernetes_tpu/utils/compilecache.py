@@ -9,10 +9,13 @@ process: JAX's persistent compilation cache serializes every compiled
 program to disk keyed by (HLO, compile options, platform version), and
 later processes deserialize in milliseconds instead of recompiling.
 
-Enabled on import of kubernetes_tpu (kubernetes_tpu/__init__.py) unless
-KUBERNETES_TPU_NO_COMPILE_CACHE is set.  The cache dir defaults to
-~/.cache/kubernetes_tpu/jax and is overridable via
-KUBERNETES_TPU_JAX_CACHE_DIR.
+Enabled on import of kubernetes_tpu.ops.  The directory is placed from
+outside: where JAX already has one (``JAX_COMPILATION_CACHE_DIR``, or a
+``jax.config.update`` by the embedding program) it is kept and this
+module sets none; otherwise the cache is ``<checkout>/.jax_cache``,
+derived from this package's location so it never depends on the
+working directory or ``$HOME``.  JAX's own switch turns it off
+(``JAX_ENABLE_COMPILATION_CACHE=false``).
 
 Reference framing: this plays the role the reference's ahead-of-time
 compilation plays — scheduling code is ready the moment the binary
@@ -22,44 +25,46 @@ jax cache + the Python package).
 
 from __future__ import annotations
 
-import logging
 import os
 
-_log = logging.getLogger(__name__)
-_enabled_dir: str | None = None
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 
 
-def enable(cache_dir: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at `cache_dir` (created
-    if needed).  Idempotent; returns the active dir or None if disabled
-    or unsupported.  Every compile is cached (min-time/min-size gates
-    zeroed): even 100 ms executables are worth never recompiling, and
-    the scheduler's shape-bucket family is small enough that cache size
-    is not a concern."""
-    global _enabled_dir
-    if os.environ.get("KUBERNETES_TPU_NO_COMPILE_CACHE"):
+def enable() -> str | None:
+    """Turn on JAX's persistent compilation cache and return its
+    directory (None when JAX's own switch has it off).  Idempotent.
+    Every compile is cached (min-time/min-size gates zeroed): even
+    100 ms executables are worth never recompiling, and the scheduler's
+    shape-bucket family is small enough that cache size is not a
+    concern.
+
+    A default directory that cannot be created or written raises: a
+    process that silently compiles everything cold looks healthy and is
+    not.  A directory given from outside is JAX's to validate (it may
+    be a remote URL), so it is returned untouched."""
+    import jax
+
+    if not jax.config.jax_enable_compilation_cache:
         return None
-    if _enabled_dir is not None:
-        return _enabled_dir
-    cache_dir = (
-        cache_dir
-        or os.environ.get("KUBERNETES_TPU_JAX_CACHE_DIR")
-        or os.path.join(
-            os.path.expanduser("~"), ".cache", "kubernetes_tpu", "jax"
-        )
-    )
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    outside = jax.config.jax_compilation_cache_dir
+    if outside:
+        return outside
     try:
-        os.makedirs(cache_dir, exist_ok=True)
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # also persist XLA-internal (autotune etc.) caches where the
-        # backend supports it
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except Exception:  # pragma: no cover - unsupported backend/readonly fs
-        _log.exception("persistent compilation cache unavailable; continuing")
-        return None
-    _enabled_dir = cache_dir
-    return cache_dir
+        os.makedirs(CHECKOUT_CACHE_DIR, exist_ok=True)
+        if not os.access(CHECKOUT_CACHE_DIR, os.W_OK | os.X_OK):
+            raise PermissionError(13, "not writable", CHECKOUT_CACHE_DIR)
+    except OSError as e:
+        raise OSError(
+            f"compile cache directory {CHECKOUT_CACHE_DIR} is unusable "
+            f"({e}); set JAX_COMPILATION_CACHE_DIR to a writable one, or "
+            "JAX_ENABLE_COMPILATION_CACHE=false to run uncached"
+        ) from e
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR

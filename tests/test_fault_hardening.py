@@ -213,6 +213,22 @@ def test_breaker_trips_after_retry_and_falls_back_to_host():
         sched.stop()
 
 
+def test_failure_log_labels_cannot_raise(monkeypatch):
+    """The breaker's handlers format the device and the executable key
+    as log arguments: a label that raised there would escape the handler
+    and skip the retry and the host fallback it guards."""
+    from kubernetes_tpu.models import batch_scheduler as bs
+
+    def no_backend(*a, **kw):
+        raise RuntimeError("backend gone")
+
+    monkeypatch.setattr(bs.jax, "devices", no_backend)
+    assert bs.device_label() == "unknown device"
+    assert bs._executable_key(None, None) == "unknown executable"
+    # finalize_pending accepts a HostSolve (no result, no meta)
+    assert "host" in bs.HostSolve([None]).executable_key()
+
+
 def test_tripped_breaker_keeps_scheduling_throughput():
     """With the breaker open, later batches go straight to the host path
     (no device attempt) and still schedule."""

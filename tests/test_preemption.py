@@ -710,6 +710,101 @@ def test_overload_level1_caps_instead_of_deferring():
         sched.stop()
 
 
+def test_shed_preemptor_retries_when_the_cluster_goes_idle():
+    """Level 2 defers the PostFilter pass, and the ladder's average only
+    moves when a cycle runs.  A preemptor shed at level 2 with nothing
+    else going on used to stay parked behind a level no cycle would ever
+    lower, until the 300 s unschedulable flush (on the chip: 8
+    preemptors behind a 3,900-pod burst).  Shed pods retry with backoff,
+    and those retries are the cycles that bring the level down."""
+    from kubernetes_tpu.scheduler.config import SchedulerConfiguration
+
+    store = st.Store()
+    store.create(make_node("n0").capacity(cpu_milli=2000, pods=10).obj())
+    p = make_pod("low").req(cpu_milli=2000).priority(0).node_name("n0").obj()
+    p.status.phase = "Running"
+    store.create(p)
+    sched = _mk_scheduler(
+        store,
+        config=SchedulerConfiguration(pod_initial_backoff_seconds=0.1),
+    )
+    try:
+        # a burst's worth of slow cycles: just past the level-2 threshold
+        for _ in range(10):
+            sched.overload.note_cycle(2.2 * sched.overload.slo)
+        assert sched.overload.level() == 2
+        store.create(make_pod("hi").req(cpu_milli=1500).priority(100).obj())
+        deadline = time.monotonic() + 15
+        placed = None
+        while time.monotonic() < deadline and not placed:
+            sched.schedule_batch(timeout=0.2)
+            placed = store.get("Pod", "hi").spec.node_name
+        assert sched.metrics.overload_shed_total.total >= 1  # it WAS shed
+        assert placed == "n0"
+        assert sched.overload.level() < 2
+    finally:
+        sched.stop()
+
+
+def test_cold_compile_cycle_is_not_overload(monkeypatch):
+    """A first-of-a-bucket cycle blocks for seconds in trace + compile.
+    That wall is not load: fed to the ladder it read as severe overload
+    (level 2 defers PostFilter), and in an idle cluster no later cycle
+    brought the level down — on the chip, preemptors sent right after a
+    cold start waited for the 300 s unschedulable flush.  A cycle that
+    compiled (utils/compileclock) is not fed to the ladder."""
+    import jax.monitoring
+
+    from kubernetes_tpu.scheduler.config import SchedulerConfiguration
+
+    store = st.Store()
+    store.create(make_node("n0").capacity(cpu_milli=2000, pods=10).obj())
+    store.create(make_node("n1").capacity(cpu_milli=500, pods=10).obj())
+    p = make_pod("low").req(cpu_milli=2000).priority(0).node_name("n0").obj()
+    p.status.phase = "Running"
+    store.create(p)
+    sched = _mk_scheduler(
+        store, config=SchedulerConfiguration(batch_latency_slo_seconds=0.1)
+    )
+    inner = sched.tpu.schedule_pending_async
+    walls = []
+
+    def first_of_a_bucket(pods, **kw):
+        # what a jit call that has to compile does to its caller: block,
+        # then report the step on the caller's thread (as JAX does)
+        if not walls:
+            t0 = time.time()
+            time.sleep(0.8)  # 8x the SLO: level 2 if it counted as load
+            walls.append(time.time() - t0)
+            jax.monitoring.record_event_duration_secs(
+                "/jax/core/compile/backend_compile_duration", walls[0]
+            )
+        return inner(pods, **kw)
+
+    monkeypatch.setattr(sched.tpu, "schedule_pending_async", first_of_a_bucket)
+
+    def drive(name):
+        deadline = time.monotonic() + 15
+        placed = None
+        while time.monotonic() < deadline and not placed:
+            sched.schedule_batch(timeout=0.2)
+            placed = store.get("Pod", name).spec.node_name
+        return placed
+
+    try:
+        # the cold cycle: one small pod, bound while its caller "compiled"
+        store.create(make_pod("first").req(cpu_milli=100).obj())
+        assert drive("first") == "n1" and walls
+        assert sched.overload.level() == 0
+        # a preemptor right behind it, nothing else going on: its
+        # PostFilter pass runs now, not after the unschedulable flush
+        store.create(make_pod("hi").req(cpu_milli=1500).priority(100).obj())
+        assert drive("hi") == "n0"
+        assert sched.metrics.overload_shed_total.total == 0
+    finally:
+        sched.stop()
+
+
 def test_gang_preemption_evicts_across_nodes():
     """A whole gang preempts: victims accumulate over multiple nodes
     until the group fits all-or-nothing (previously gang members were

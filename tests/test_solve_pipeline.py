@@ -198,5 +198,10 @@ def test_packed_device_put_scratch_reuse():
     assert cache1.keys() == cache2.keys()
     for k in cache1:
         assert cache1[k] == cache2[k]  # same buffers, alternated in place
+    # device_put returns before the runtime has read host memory, so each
+    # slot remembers an output of the unpack program that last consumed
+    # it; the slot's next rewrite waits on that output
+    for v in s._unpack_cache.values():
+        assert all(c is not None and c.is_ready() for c in v["consumed"])
     # and the placements stay correct across reuse
     assert sum(n is not None for n in names3) == 80
