@@ -1,0 +1,89 @@
+"""A configuration file turned into the objects a run creates.
+
+Nodes and pods are plain dictionaries here (the benchmark's own copy of the
+upstream templates with a name filled in); the system adapter turns them
+into whatever its API takes.  The seed decides only the order in which the
+measured pods walk the namespaces: every seed gives the same set of pods.
+"""
+
+from __future__ import annotations
+
+import os
+import random
+
+import yaml
+
+ZONE_KEY = "topology.kubernetes.io/zone"
+
+
+def load_template(config: dict, rel: str) -> dict:
+    with open(os.path.join(config["_dir"], rel)) as f:
+        return yaml.safe_load(f)
+
+
+def substitute_index(obj, index: int):
+    """$index and $index_modN in string values, as upstream's templates use
+    them."""
+    if isinstance(obj, dict):
+        return {k: substitute_index(v, index) for k, v in obj.items()}
+    if isinstance(obj, list):
+        return [substitute_index(v, index) for v in obj]
+    if isinstance(obj, str) and "$index" in obj:
+        out = obj
+        while "$index_mod" in out:
+            pos = out.find("$index_mod") + len("$index_mod")
+            end = pos
+            while end < len(out) and out[end].isdigit():
+                end += 1
+            mod = int(out[pos:end]) if end > pos else 1
+            out = out[: pos - len("$index_mod")] + str(index % mod) + out[end:]
+        return out.replace("$index", str(index))
+    return obj
+
+
+class Deployment:
+    """Sizes and templates of one configuration, at full or toy size."""
+
+    def __init__(self, config: dict, toy: bool = False):
+        self.config = config
+        params = dict(config["test_case"]["workloads"][0]["params"])
+        self.capacity_pods = int(config["capacity_pods"])
+        if toy:
+            over = config["toy"]
+            params.update({k: v for k, v in over.items() if k in params})
+            self.capacity_pods = int(over["capacity_pods"])
+        self.n_nodes = int(params["initNodes"])
+        self.n_init_pods = int(params["initPods"])
+        self.node_template = load_template(config, config["node_template"])
+        self.templates = {
+            "init": load_template(config, config["init_pod_template"]),
+            "measure": load_template(config, config["measure_pod_template"]),
+        }
+        assumed = config["assumed"]
+        self.namespaces = [f"team-{i}" for i in range(int(assumed["namespaces"]))]
+        self.store_args = dict(assumed["store"])
+        self.scheduler_args = dict(assumed["scheduler"])
+        self.max_fill_share = float(config["max_fill_share"])
+
+    def nodes(self) -> list:
+        out = []
+        for i in range(self.n_nodes):
+            d = substitute_index(self.node_template, i)
+            d["metadata"] = dict(d.get("metadata") or {}, name=f"node-{i}")
+            out.append(d)
+        return out
+
+    def pod(self, role: str, name: str, namespace: str) -> dict:
+        t = self.templates[role]
+        meta = dict(t.get("metadata") or {}, name=name, namespace=namespace)
+        meta.pop("generateName", None)
+        return dict(t, metadata=meta)
+
+    def namespace_walk(self, seed: int, stream: int):
+        """An endless walk over the namespaces in an order drawn from the
+        seed; `stream` separates the warm replay's walk from the window's."""
+        rng = random.Random((int(seed) << 3) ^ stream)
+        while True:
+            order = list(self.namespaces)
+            rng.shuffle(order)
+            yield from order
