@@ -2059,10 +2059,15 @@ def main() -> None:
             "name": o["name"],
             "total_s": o["total_s"],
             "steps": o["steps"],
-            "commit_share": _share(o, ("commit",)),
-            # encode + decode + deferred-readback overlap = the solve
-            # half of the step
-            "solve_share": _share(o, ("encode", "decode", "overlap")),
+            # the steps are the cycle's spans on the scheduling lane
+            # (utils/trace.py): staging + hand-off is its commit half,
+            # encode + dispatch + the exposed decode wait its solve half
+            "commit_share": _share(
+                o, ("sched.stage", "sched.wave_handoff")
+            ),
+            "solve_share": _share(
+                o, ("sched.encode", "sched.dispatch", "sched.decode_wait")
+            ),
             **o["fields"],
         }
         for o in overruns

@@ -596,6 +596,9 @@ class _NullTrace:
     def log_if_long(self):
         pass
 
+    def close(self, *_a, **_k):
+        pass
+
 
 class _StubElector:
     """Minimal leader elector for scenarios: always leading, fence

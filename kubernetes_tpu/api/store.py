@@ -81,6 +81,7 @@ from typing import (
 
 from ..analysis import ledger as _ledger
 from ..testing import faults
+from ..utils import trace
 from . import framing
 from . import types as api
 
@@ -953,10 +954,15 @@ class _StoreShard:
             return
         from . import wire
 
-        rec = {"op": op, "rv": rv, "kind": kind, "key": key}
-        if op != DELETED:
-            rec["obj"] = wire.to_wire(obj)
-        self._journal_commit([_encode_record(rec)])
+        t0 = trace.now()
+        try:
+            rec = {"op": op, "rv": rv, "kind": kind, "key": key}
+            if op != DELETED:
+                rec["obj"] = wire.to_wire(obj)
+            self._journal_commit([_encode_record(rec)])
+        finally:
+            # one a write: summed by the recorder, not a row each
+            trace.tally("store.journal", t0, trace.now())
 
     def _append_journal_wave(
         self, kind: str, records: List[Tuple[str, str, Any, int]]
@@ -1507,6 +1513,15 @@ class Store:
     # -- CRUD --------------------------------------------------------------
 
     def create(self, obj: Any) -> Any:
+        # admission, locks, publish and journal of one write: the recorder
+        # sums these per thread (utils/trace.tally), not a row a write
+        t0 = trace.now()
+        try:
+            return self._create(obj)
+        finally:
+            trace.tally("store.create", t0, trace.now())
+
+    def _create(self, obj: Any) -> Any:
         with self._write_guard():
             admitted = False
             if self._admission is not None:
@@ -1761,7 +1776,8 @@ class Store:
         )
         applied: List[str] = []
         errors: Dict[str, Exception] = {}
-        with shard._lock:
+        with trace.span("store.update_wave", len(group)) as sp, shard._lock:
+            sp.a0 = shard.index
             objs = shard._objects.get(kind, {})
             prepared: List[Tuple[str, Any]] = []   # (key, mutated copy)
             for name, namespace, mutate in group:
@@ -1809,7 +1825,9 @@ class Store:
                     applied.append(key)
                 shard._last_rv = self._rv
                 self._dispatch_wave(kind, events)
-            shard._append_journal_wave(kind, records)
+            if shard._journal is not None:
+                with trace.span("store.journal", len(records)):
+                    shard._append_journal_wave(kind, records)
         return applied, errors
 
     def _check_fence_locked(self, fence: FenceToken) -> None:

@@ -44,6 +44,7 @@ from ..ops import auction as auction_ops
 from ..ops import schema
 from ..ops.scores import DEFAULT_SCORE_CONFIG, ScoreConfig
 from ..testing import faults
+from ..utils import trace
 from .mirror import DeviceClusterMirror
 from .partials import PartialsCache
 
@@ -478,11 +479,12 @@ class DeviceSolve:
     overlap batch N's readback with its own host work (queue pop window,
     wave staging) instead of idling on the transfer."""
 
-    def __init__(self, result: Result, meta: schema.SnapshotMeta, clock=time.perf_counter):
+    def __init__(self, result: Result, meta: schema.SnapshotMeta):
         self.result = result
         self.meta = meta
-        self._clock = clock
-        self.dispatched_at = clock()
+        # the recorder's clock (utils/trace.py); schedule_pending_async
+        # replaces it by the instant its dispatch span closed
+        self.dispatched_at = trace.now()
         self._decoded = None
         # DispatchArbiter slot held for this in-flight solve (multi-lane
         # admission); released by the coalesced decode, or explicitly by
@@ -518,8 +520,6 @@ class DeviceSolve:
 
     def _decode(self):
         if self._decoded is None:
-            t0 = self._clock()
-            self.deferred_s = t0 - self.dispatched_at
             tree = {
                 "assignment": self.result.assignment,
                 "scores": getattr(self.result, "scores", None),
@@ -536,13 +536,16 @@ class DeviceSolve:
                     self.result, "carveout_fallbacks", None
                 ),
             }
-            try:
-                got = jax.device_get(tree)  # one coalesced readback
-            finally:
-                # the device finished (or failed) this program — the
-                # next lane's dispatch may proceed either way
-                self.release_slot()
-            self.decode_wait_s = self._clock() - t0
+            with trace.span("sched.decode_wait", self.meta.num_pods) as sp:
+                try:
+                    got = jax.device_get(tree)  # one coalesced readback
+                finally:
+                    # the device finished (or failed) this program — the
+                    # next lane's dispatch may proceed either way
+                    self.release_slot()
+            # the span's two reads are the timings: one measurement
+            self.deferred_s = sp.t0 - self.dispatched_at
+            self.decode_wait_s = sp.t1 - sp.t0
             assignment = np.asarray(got["assignment"])
             # health check (the circuit breaker's non-finite-score trip
             # wire): a NaN score, or a placed pod whose winning score is
@@ -1241,7 +1244,12 @@ class TPUBatchScheduler:
         waiting to land (the filters-with-nominated-pods analogue,
         runtime/framework.go:962).  The overlay is applied to the device
         copy; live state is untouched."""
-        with lock if lock is not None else contextlib.nullcontext():
+        if lock is not None:
+            # the encode holds the lock a concurrent wave commit needs:
+            # how long it waited for it is a span of its own
+            with trace.span("sched.encode.lock_wait"):
+                lock.acquire()
+        try:
             t_enc = time.perf_counter()
             snap, meta = self.builder.build_from_state(
                 self.state, pending, num_pods_hint=num_pods_hint
@@ -1338,6 +1346,9 @@ class TPUBatchScheduler:
                     cluster=jax.tree.map(np.array, snap.cluster)
                 )
                 snap = jax.device_put(snap) if self.mesh is None else snap
+        finally:
+            if lock is not None:
+                lock.release()
         if rows:
             idx = jnp.asarray(np.array(rows, dtype=np.int32))
             cluster = snap.cluster._replace(
@@ -1416,14 +1427,17 @@ class TPUBatchScheduler:
             return self._host_fallback(
                 pending, lock=lock, reservations=reservations
             )
-        t0 = time.perf_counter()
-        snap, meta = self.encode_pending(
-            pending, num_pods_hint=num_pods_hint, lock=lock,
-            reservations=reservations,
-        )
-        t1 = time.perf_counter()
+        with trace.span("sched.encode", len(pending)) as sp_enc:
+            snap, meta = self.encode_pending(
+                pending, num_pods_hint=num_pods_hint, lock=lock,
+                reservations=reservations,
+            )
+            sp_enc.a0 = trace.ROUTE_ID.get(meta.route, -1)
         try:
-            ds = self.solve_encoded_async(snap, meta)
+            with trace.span("sched.dispatch", len(pending)) as sp_run:
+                sp_run.a0 = int(snap.pods.req.shape[0])
+                sp_run.a1 = int(snap.cluster.allocatable.shape[0])
+                ds = self.solve_encoded_async(snap, meta)
         except Exception:  # noqa: BLE001 — device dispatch/compile fault
             _log.exception(
                 "device solve dispatch failed on %s [%s]; retrying once",
@@ -1453,12 +1467,15 @@ class TPUBatchScheduler:
                 return self._host_fallback(
                     pending, lock=lock, reservations=reservations
                 )
-        ds.encode_s = t1 - t0
+        # last_timings is a view of the cycle's spans: every figure
+        # below comes from the clock reads that opened and closed them
+        ds.encode_s = sp_enc.t1 - sp_enc.t0
         # trace/compile + dispatch-enqueue wall: on a first-of-a-bucket
         # batch this IS the XLA compile (jit blocks until the executable
         # exists); steady-state it is ~0 — the split the bench uses to
         # separate compile churn from real solve regressions
-        ds.dispatch_s = ds.dispatched_at - t1
+        ds.dispatch_s = sp_run.t1 - sp_run.t0
+        ds.dispatched_at = sp_run.t1
         return ds
 
     def finalize_pending(
@@ -1591,8 +1608,9 @@ class TPUBatchScheduler:
         errs schedulable-pods-safe."""
         from ..testing.oracle import Oracle
 
-        t0 = time.perf_counter()
-        with lock if lock is not None else contextlib.nullcontext():
+        sp = trace.span("sched.encode", len(pending))
+        sp.a0 = trace.ROUTE_ID["host"]
+        with sp, lock if lock is not None else contextlib.nullcontext():
             state = self.state
             nodes = [
                 state._node_objs[name]
@@ -1629,7 +1647,7 @@ class TPUBatchScheduler:
         self.breaker.record_fallback()
         self.last_result = None  # no reason tensor aligns with these names
         hs = HostSolve(names)
-        hs.encode_s = time.perf_counter() - t0
+        hs.encode_s = sp.t1 - sp.t0
         return hs
 
     def _gang_admission_retry(

@@ -35,6 +35,7 @@ from typing import Dict, List, Optional
 from ..analysis import ledger as _ledger
 from ..api import types as api
 from ..ops import assign as assign_ops
+from ..utils import trace
 
 # Event → wake-set (QueueingHints-lite, internal/queue/events.go:25-89
 # reduced to the solver's failure stages).  None = wake every reason.
@@ -198,6 +199,10 @@ class QueuedPodInfo:
     # scheduling_queue.go inFlightPods/inFlightEvents): events arriving
     # while this pod is mid-cycle are replayed when it comes back
     popped_event_seq: int = 0
+    # the pod's row in the flight recorder (utils/trace.py) and the
+    # cycle that last popped it; 0 = none
+    trace_slot: int = 0
+    trace_cycle: int = 0
 
 
 class SchedulingQueue:
@@ -294,6 +299,9 @@ class SchedulingQueue:
         self._event_seq = 0
         self._events_log: deque = deque(maxlen=512)  # (seq, wake-set|None)
         self._closed = False
+        # the accumulation window the newest pop_batch used (read by the
+        # scheduler's pop_wait span; lanes may overwrite each other's)
+        self.last_window = 0.0
 
     # -- helpers -----------------------------------------------------------
 
@@ -358,6 +366,9 @@ class SchedulingQueue:
                     pod=pod, timestamp=now, initial_attempt_timestamp=now
                 )
                 self._infos[key] = info
+                # first add only: a requeue bumps attempts, never this
+                info.trace_slot = trace.pod_slot(key)
+                trace.stamp(info.trace_slot, trace.ENQUEUED)
                 if self._window_ctl is not None:
                     # new pending pod: one arrival sample for the
                     # adaptive window's rate estimate
@@ -568,6 +579,7 @@ class SchedulingQueue:
                 window = self._batch_window
         if timeout is not None:
             window = min(window, timeout)
+        self.last_window = window
         pullable = ("active", "backoff", "unsched")
         with self._cond:
             batch: List[QueuedPodInfo] = []

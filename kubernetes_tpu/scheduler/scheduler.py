@@ -54,7 +54,7 @@ from ..models.batch_scheduler import TPUBatchScheduler, device_label
 from ..ops import assign as assign_ops
 from ..testing import faults
 from ..utils import compileclock
-from ..utils.trace import Trace
+from ..utils import trace as _trace
 from .cache import SchedulerCache
 from .config import SchedulerConfiguration
 from .framework import Framework, FrameworkRegistry
@@ -378,6 +378,8 @@ class Scheduler:
         else:
             self._lane_profiles = [None]  # one lane pops every class
         self._lane_threads: List[threading.Thread] = []
+        # a span's attribute for "which profile" (names stay a closed set)
+        self._profile_ids = {name: i for i, name in enumerate(names)}
         self.metrics.lane_count.set(float(n_lanes))
         # per-scheduling-thread in-flight cycle (lanes + direct
         # schedule_batch callers salvage their OWN cycle on faults)
@@ -951,40 +953,61 @@ class Scheduler:
         tail.  Failures split per pod back to individual requeue — a bad
         pod never takes its wave down."""
         faults.fire("binder.commit_wave", pods=len(wave))
-        t0 = self._clock()
-        binds: List[tuple] = []
-        for fwk, info, node_name, t_attempt in wave:
-            try:
-                fwk.run_pre_bind(info.pod, node_name)
-            except Exception:  # noqa: BLE001 — per-pod containment
-                self._fail_bind(fwk, info)
-                continue
-            binds.append((fwk, info, node_name, t_attempt))
-        if binds:
-            def bind_mutator(node_name: str):
-                def mutate(pod: api.Pod) -> None:
-                    if pod.spec.node_name and pod.spec.node_name != node_name:
-                        # bound-exactly-once guard: a retried wave must
-                        # never move an already-bound pod (same-node
-                        # recommit is an idempotent no-op-shaped write)
-                        raise st.Conflict(
-                            f"pod already bound to {pod.spec.node_name}"
-                        )
-                    pod.spec.node_name = node_name
-                    pod.status.phase = "Running"
-                return mutate
+        # the wave belongs to the cycle that staged it: this worker's
+        # span names that cycle, and its two clock reads are also the
+        # wave's duration below (no second pair of reads)
+        cyc = wave[0][1].trace_cycle if wave else 0
+        with _trace.span("sched.commit", len(wave), cycle=cyc, parent=cyc) as sp:
+            for _, info, _, _ in wave:
+                _trace.stamp(info.trace_slot, _trace.COMMIT_BEGIN, sp.t0)
+            self._commit_wave_spanned(wave)
+        dt = sp.t1 - sp.t0
+        self.metrics.commit_wave_duration.observe(dt)
+        self.metrics.commit_wave_size.observe(float(len(wave)))
+        if self.window_ctl is not None:
+            self.window_ctl.note_commit(len(wave), dt)
+        self.metrics.pipeline_overlap.observe(
+            self._solve_overlap(sp.t0, sp.t1)
+        )
 
-            # stale-leader write fencing: every sub-wave commits only
-            # while our lease acquisition is still current (a deposed
-            # leader's late sub-wave is rejected inside its transaction
-            # — the Fenced path below requeues; the pods belong to the
-            # successor now)
-            fence = None
-            if self.leader_elector is not None:
-                token = getattr(self.leader_elector, "fence_token", None)
-                if token is not None:
-                    fence = token()
-            failed = self._commit_subwaves(binds, bind_mutator, fence)
+    def _commit_wave_spanned(self, wave: List[tuple]) -> None:
+        binds: List[tuple] = []
+        with _trace.span("sched.commit.pre_bind", len(wave)):
+            for fwk, info, node_name, t_attempt in wave:
+                try:
+                    fwk.run_pre_bind(info.pod, node_name)
+                except Exception:  # noqa: BLE001 — per-pod containment
+                    self._fail_bind(fwk, info)
+                    continue
+                binds.append((fwk, info, node_name, t_attempt))
+        if not binds:
+            return
+
+        def bind_mutator(node_name: str):
+            def mutate(pod: api.Pod) -> None:
+                if pod.spec.node_name and pod.spec.node_name != node_name:
+                    # bound-exactly-once guard: a retried wave must
+                    # never move an already-bound pod (same-node
+                    # recommit is an idempotent no-op-shaped write)
+                    raise st.Conflict(
+                        f"pod already bound to {pod.spec.node_name}"
+                    )
+                pod.spec.node_name = node_name
+                pod.status.phase = "Running"
+            return mutate
+
+        # stale-leader write fencing: every sub-wave commits only
+        # while our lease acquisition is still current (a deposed
+        # leader's late sub-wave is rejected inside its transaction
+        # — the Fenced path below requeues; the pods belong to the
+        # successor now)
+        fence = None
+        if self.leader_elector is not None:
+            token = getattr(self.leader_elector, "fence_token", None)
+            if token is not None:
+                fence = token()
+        failed = self._commit_subwaves(binds, bind_mutator, fence)
+        with _trace.span("sched.commit.post_bind", len(binds)):
             done: List[api.Pod] = []
             for fwk, info, node_name, t_attempt in binds:
                 if pod_key(info.pod) in failed:
@@ -996,14 +1019,6 @@ class Scheduler:
                 )
             # TTL countdown for the whole wave under one lock/clock read
             self.cache.finish_binding_all(done)
-        dt = self._clock() - t0
-        self.metrics.commit_wave_duration.observe(dt)
-        self.metrics.commit_wave_size.observe(float(len(wave)))
-        if self.window_ctl is not None:
-            self.window_ctl.note_commit(len(wave), dt)
-        self.metrics.pipeline_overlap.observe(
-            self._solve_overlap(t0, self._clock())
-        )
 
     def _commit_subwaves(self, binds, bind_mutator, fence) -> set:
         """Commit one bind wave as per-store-shard SUB-waves — each an
@@ -1039,6 +1054,12 @@ class Scheduler:
                     kwargs["shard_hint"] = sid
                 _, errs = self.store.update_wave("Pod", updates, **kwargs)
                 bad = set(errs)
+                # the binds are in the store, and in the journal as
+                # journal_sync promises: one read for the sub-wave
+                t_done = _trace.now()
+                for _, info, _, _ in group:
+                    if not bad or pod_key(info.pod) not in bad:
+                        _trace.stamp(info.trace_slot, _trace.COMMITTED, t_done)
             except st.Fenced:
                 logging.getLogger(__name__).warning(
                     "bind sub-wave fenced (leadership lost since "
@@ -1095,6 +1116,7 @@ class Scheduler:
             # though the space just came back
             self.queue.move_for_event("AssignedPodDelete")
         self.metrics.schedule_attempts.inc("error")
+        self._mark_failed(info, _trace.FAIL_BIND)
         self.queue.requeue_backoff(info)
 
     def _run(self, lane_idx: int = 0) -> None:
@@ -1142,9 +1164,7 @@ class Scheduler:
                 timeout = 0.2 if cycle is None else min(
                     0.05, self.config.batch_window_seconds or 0.05
                 )
-                batch = self.queue.pop_batch(
-                    self.batch_size, timeout=timeout, profiles=profiles
-                )
+                batch, t_pop = self._pop(timeout, profiles)
             except Exception:  # noqa: BLE001
                 batch = []
             if (
@@ -1163,7 +1183,7 @@ class Scheduler:
                     self._finish_cycle(cycle)
                     cycle = None
                 if batch:
-                    cycle = self._dispatch_batch(batch)
+                    cycle = self._dispatch_batch(batch, t_pop)
             except Exception:  # noqa: BLE001 — per-cycle containment
                 # the reference contains per-cycle errors (ScheduleOne
                 # logs and returns; the wait.Until loop re-enters) — one
@@ -1192,6 +1212,7 @@ class Scheduler:
         self._inflight_set(None)
         if cycle is None:
             return
+        cycle.trace.close()
         if cycle.wave:
             staged, cycle.wave = cycle.wave, []
             for _, info, _, _ in staged:
@@ -1213,6 +1234,7 @@ class Scheduler:
                 # the dead cycle assumed it but lost it before staging
                 self.cache.forget(info.pod)
             self.metrics.schedule_attempts.inc("error")
+            self._mark_failed(info, _trace.FAIL_SALVAGED)
             self.queue.requeue_backoff(info)
 
     def _finish_contained(self, cycle: Optional["_Cycle"]) -> Optional["_Cycle"]:
@@ -1242,19 +1264,38 @@ class Scheduler:
         halves but defers the finalize across the next pop window — this
         entry point finishes the cycle in place so direct callers (tests,
         single-step drivers) keep strict pop->solve->stage semantics."""
-        batch = self.queue.pop_batch(self.batch_size, timeout=timeout)
+        batch, t_pop = self._pop(timeout)
         if not batch:
             return {"popped": 0, "scheduled": 0, "unschedulable": 0,
                     "bind_errors": 0}
         try:
-            return self._finish_cycle(self._dispatch_batch(batch))
+            return self._finish_cycle(self._dispatch_batch(batch, t_pop))
         except Exception:
             # direct callers see the error, but popped pods must not
             # strand inflight (the same salvage the hot loop runs)
             self._salvage_cycle(self._inflight_get())
             raise
 
-    def _dispatch_batch(self, batch: List[QueuedPodInfo]) -> "_Cycle":
+    def _pop(self, timeout, profiles=None) -> tuple:
+        """``pop_batch`` inside a ``sched.pop_wait`` span of no cycle:
+        its end is the start of the cycle the batch runs as, by the same
+        clock read, which is all that joins them.  Returns (batch, that
+        read)."""
+        with _trace.span("sched.pop_wait", cycle=0, parent=0) as sp:
+            batch = self.queue.pop_batch(
+                self.batch_size, timeout=timeout, profiles=profiles
+            )
+            sp.n, sp.a0 = len(batch), self.queue.last_window
+        return batch, sp.t1
+
+    def _mark_failed(self, info: QueuedPodInfo, code: int) -> None:
+        """The pod's attempt ended without a bind: stamp when and how."""
+        _trace.stamp(info.trace_slot, _trace.FAILED)
+        _trace.stamp(info.trace_slot, _trace.FAIL_CODE, code)
+
+    def _dispatch_batch(
+        self, batch: List[QueuedPodInfo], t_pop: Optional[float] = None
+    ) -> "_Cycle":
         """The dispatch half of one cycle: group the popped batch by
         profile, encode + dispatch each group's device solve.  Each group
         runs its FULL cycle (solve -> assume -> bind) before the next
@@ -1281,10 +1322,18 @@ class Scheduler:
         # slow cycles self-describe on EVERY exit path (utiltrace
         # LogIfLong, schedule_one.go:391-431); threshold is generous
         # because first-shape compiles legitimately run tens of seconds.
-        # _finish_cycle's log_if_long is the ONE emission point — the old
-        # with-block exit double-logged every over-threshold trace.
-        trace = Trace("schedule_batch", threshold=1.0, pods=len(batch))
-        cycle = _Cycle(stats, trace, reservations, batch)
+        # _finish_cycle's log_if_long is the ONE emission point.  The
+        # cycle starts where its pop ended (`t_pop`; now, for a caller
+        # that popped for itself), which is every pod's ``popped``.
+        tr = _trace.Trace("schedule_batch", threshold=1.0, span="sched.cycle",
+                          start=t_pop, pods=len(batch))
+        for info in batch:
+            slot = info.trace_slot
+            info.trace_cycle = tr.id
+            _trace.stamp(slot, _trace.POPPED, tr.start)
+            _trace.stamp(slot, _trace.CYCLE, tr.id)
+            _trace.stamp(slot, _trace.ATTEMPTS, info.attempts)
+        cycle = _Cycle(stats, tr, reservations, batch)
         self._inflight_set(cycle)
         if self._speculation_enabled and self._waves_in_flight():
             # SPECULATIVE dispatch: this batch's encode/solve runs over
@@ -1349,7 +1398,7 @@ class Scheduler:
     def _solve_group_async(self, cycle, fwk, sched_name, group):
         """Encode + dispatch one profile group; returns (fwk, name,
         group, DeviceSolve, t_solve) or None when nothing solvable."""
-        t_solve = self._clock()
+        t_solve = _trace.now()
         with self._solve_lock:
             self._solve_open = t_solve
         if cycle.spec_token is not None:
@@ -1398,7 +1447,6 @@ class Scheduler:
                         info, reason=assign_ops.REASON_UNENCODABLE
                     )
                 return None
-        cycle.trace.step(f"encode[{sched_name}]")
         return (fwk, sched_name, group, ds, t_solve)
 
     def _misspeculate_group(self, cycle, fwk, sched_name, group, ds) -> None:
@@ -1431,6 +1479,7 @@ class Scheduler:
         )
         for info in group:
             cycle.handled.add(pod_key(info.pod))
+            self._mark_failed(info, _trace.FAIL_MISSPECULATED)
             self.queue.requeue_backoff(info)
 
     def _harvest_group(self, cycle, fwk, sched_name, group, ds, t_solve):
@@ -1449,12 +1498,20 @@ class Scheduler:
         # names came from — read telemetry off the effective one, never
         # the sick original (its decode raises)
         ds = getattr(fwk.tpu, "last_solve", None) or ds
+        # one read for the group: its solve has returned names
+        now = _trace.now()
+        route = _trace.ROUTE_ID.get(
+            getattr(getattr(ds, "meta", None), "route", None),
+            _trace.ROUTE_ID["host"],
+        )
+        for info in group:
+            _trace.stamp(info.trace_slot, _trace.SOLVED, now)
+            _trace.stamp(info.trace_slot, _trace.ROUTE, route)
         lt = fwk.tpu.last_timings or {}
         encode_s = float(lt.get("encode_s", 0.0))
         compile_s = float(lt.get("compile_s", 0.0))
         decode_wait = float(lt.get("decode_wait_s", 0.0))
         overlap_s = float(lt.get("decode_overlap_s", 0.0))
-        now = self._clock()
         # overlap window = the DEVICE half only: the encode holds the
         # cache lock, which a concurrent wave commit also needs, so only
         # the device dispatch truly pipelines against commits
@@ -1512,28 +1569,28 @@ class Scheduler:
             ]
         else:
             reasons = [-1] * len(group)
-        cycle.trace.step(f"decode[{sched_name}]")
-        self._stage_group(fwk, group, names, reasons, cycle)
-        cycle.trace.step(f"commit[{sched_name}]")
+        with _trace.span("sched.stage", len(group)) as sp:
+            # which profile: an attribute, so span names stay a closed set
+            sp.a0 = self._profile_ids.get(sched_name, -1)
+            self._stage_group(fwk, group, names, reasons, cycle)
 
     def _finish_cycle(self, cycle: "_Cycle") -> Dict[str, int]:
         """The staging half: decode any deferred group, hand the bind
         wave to the binding stage, run PostFilter, emit trace/metrics."""
         if cycle.pending is not None:
-            # time since dispatch = readback/solve hidden behind host work
-            cycle.trace.step("overlap")
             pending, cycle.pending = cycle.pending, None
             self._harvest_group(cycle, *pending)
-        stats, trace = cycle.stats, cycle.trace
+        stats, tr = cycle.stats, cycle.trace
         if cycle.wave:
             # binding stage takes over: the NEXT cycle's pop+solve runs
             # while this wave commits (assume entries already bridge it)
-            self._dispatch_wave_async(cycle.wave)
-            trace.step("dispatch")
+            with _trace.span("sched.wave_handoff", len(cycle.wave)):
+                self._dispatch_wave_async(cycle.wave)
         # did the placement work block on a trace/compile?  (read
         # before the PostFilter pass, which is timed out of the ladder's
         # feed whole, its own compiles included)
-        compiled = compileclock.events() != cycle.compile_mark
+        n_compiled = compileclock.events() - cycle.compile_mark
+        compiled = n_compiled != 0
         if cycle.solved_any:
             # PostFilter: preemption for unschedulable pods, highest
             # priority first (handleSchedulingFailure ->
@@ -1551,53 +1608,53 @@ class Scheduler:
             # cluster gone idle their retries are the only cycles left
             # to bring the level down.
             cycle.failed.sort(key=lambda i: -i.pod.spec.priority)
-            t_postfilter = self._clock()
-            budget = self.max_preemptions_per_cycle
-            level = self.overload.level()
-            if level >= 2:
-                budget = 0
-            elif level == 1:
-                budget = max(1, budget // 4)
-            eligible = cycle.failed[: self.max_preemptions_per_cycle]
-            batch_infos = eligible[:budget]
-            try:
-                if batch_infos:
-                    # concurrent lanes serialize their PostFilter passes:
-                    # the evaluator's shared pass caches per-pass state
-                    # (victim tensors, priority floor) one pass at a time
-                    with self._postfilter_lock, self.preemption.shared_pass(
-                        [info.pod for info in batch_infos]
-                    ):
-                        for info in batch_infos:
-                            fwk = self.profiles.for_pod(info.pod)
-                            if fwk is not None and fwk.run_post_filter(
-                                info.pod
-                            ):
-                                stats["preempted"] = (
-                                    stats.get("preempted", 0) + 1
-                                )
-            except (faults.FaultCrash, Exception):  # noqa: BLE001
-                # preemption is background work: a crash-grade fault in
-                # the batched dry-run must not kill the scheduling
-                # thread — the failed pods stay parked and retry on a
-                # later cycle (the flush interval is the floor)
-                logging.getLogger(__name__).exception(
-                    "PostFilter preemption pass failed; continuing"
-                )
-            shed = eligible[len(batch_infos):]
-            if shed:
-                self.metrics.overload_shed_total.inc(by=float(len(shed)))
-                for info in shed:
-                    self.queue.retry_parked(info)
-            postfilter_s = self._clock() - t_postfilter
-            trace.step("postfilter")
+            with _trace.span("sched.postfilter", len(cycle.failed)) as sp_post:
+                budget = self.max_preemptions_per_cycle
+                level = self.overload.level()
+                if level >= 2:
+                    budget = 0
+                elif level == 1:
+                    budget = max(1, budget // 4)
+                eligible = cycle.failed[: self.max_preemptions_per_cycle]
+                batch_infos = eligible[:budget]
+                try:
+                    if batch_infos:
+                        # concurrent lanes serialize their PostFilter passes:
+                        # the evaluator's shared pass caches per-pass state
+                        # (victim tensors, priority floor) one pass at a time
+                        with self._postfilter_lock, self.preemption.shared_pass(
+                            [info.pod for info in batch_infos]
+                        ):
+                            for info in batch_infos:
+                                fwk = self.profiles.for_pod(info.pod)
+                                if fwk is not None and fwk.run_post_filter(
+                                    info.pod
+                                ):
+                                    stats["preempted"] = (
+                                        stats.get("preempted", 0) + 1
+                                    )
+                except (faults.FaultCrash, Exception):  # noqa: BLE001
+                    # preemption is background work: a crash-grade fault in
+                    # the batched dry-run must not kill the scheduling
+                    # thread — the failed pods stay parked and retry on a
+                    # later cycle (the flush interval is the floor)
+                    logging.getLogger(__name__).exception(
+                        "PostFilter preemption pass failed; continuing"
+                    )
+                shed = eligible[len(batch_infos):]
+                if shed:
+                    self.metrics.overload_shed_total.inc(by=float(len(shed)))
+                    for info in shed:
+                        self.queue.retry_parked(info)
+            postfilter_s = sp_post.t1 - sp_post.t0
             qs = self.queue.stats()
             for tier, v in qs.items():
                 self.metrics.pending_pods.set(v, tier)
         else:
             postfilter_s = 0.0
-        trace.log_if_long()
-        self.metrics.schedule_batch_duration.observe(trace.total)
+        total = tr.total
+        tr.log_if_long()
+        self.metrics.schedule_batch_duration.observe(total)
         # overload ladder: feed the cycle's PLACEMENT duration — the
         # PostFilter pass is excluded (see OverloadController: shedding
         # must not be driven by the work it sheds), and a cycle that
@@ -1607,7 +1664,7 @@ class Scheduler:
             level = self.overload.level()
         else:
             level = self.overload.note_cycle(
-                max(trace.total - postfilter_s, 0.0)
+                max(total - postfilter_s, 0.0)
             )
         self.metrics.overload_level.set(float(level))
         if self.window_ctl is not None:
@@ -1773,6 +1830,8 @@ class Scheduler:
                 logging.getLogger(__name__).exception(
                     "serving-plane mirror failed"
                 )
+        # the root span ends here, the per-cycle mirror above included
+        tr.close(a0=n_compiled, a1=level)
         self._inflight_set(None)
         return stats
 
@@ -1835,7 +1894,8 @@ class Scheduler:
             if sid < 0 or not entries:
                 continue
             try:
-                self._dispatch_subwave_async(entries, sid)
+                with _trace.span("sched.wave_handoff", len(entries)):
+                    self._dispatch_subwave_async(entries, sid)
                 handoffs.append(self._clock())
             except Exception:  # noqa: BLE001 — hand-off containment:
                 # staged (assumed) pods must not strand on the TTL
@@ -1871,6 +1931,7 @@ class Scheduler:
         if node_name is None:
             stats["unschedulable"] += 1
             self.metrics.schedule_attempts.inc("unschedulable")
+            self._mark_failed(info, _trace.FAIL_UNSCHEDULABLE)
             self.queue.add_unschedulable(info, reason=reason)
             self.events.eventf(
                 info.pod, "Warning", "FailedScheduling",
@@ -1885,6 +1946,7 @@ class Scheduler:
             fwk.run_unreserve(info.pod)
             stats["bind_errors"] += 1
             self.metrics.schedule_attempts.inc("error")
+            self._mark_failed(info, _trace.FAIL_ASSUME)
             self.queue.requeue_backoff(info)
             cycle.handled.add(pod_key(info.pod))
             return None
@@ -1902,6 +1964,7 @@ class Scheduler:
                 info.pod, "Warning", "FailedScheduling",
                 f"permit rejected on node {node_name}",
             )
+            self._mark_failed(info, _trace.FAIL_PERMIT)
             self.queue.requeue_backoff(info)
             cycle.handled.add(pod_key(info.pod))
             return None
@@ -2037,6 +2100,7 @@ class Scheduler:
                 if cycle is not None:
                     cycle.handled.add(pod_key(info.pod))
                 self.metrics.schedule_attempts.inc("error")
+                self._mark_failed(info, _trace.FAIL_UNENCODABLE)
                 # only a pod UPDATE (spec change) can help — no cluster
                 # event wakes this reason (queue.move_for_event)
                 self.queue.add_unschedulable(
