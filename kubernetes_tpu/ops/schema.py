@@ -27,6 +27,7 @@ solves at similar scale hit the XLA compile cache.
 from __future__ import annotations
 
 import heapq
+import itertools
 import warnings
 from dataclasses import dataclass, field
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -40,6 +41,7 @@ import numpy as np
 F32_EXACT_LIMIT = float(1 << 24) / 100.0
 
 from ..api import types as api
+from ..utils import trace
 from ..utils import vocab as vb
 
 # Resource axis layout: fixed head + discovered scalar resources.
@@ -757,13 +759,15 @@ class SnapshotBuilder:
         index_by_name = {nd.meta.name: i for i, nd in enumerate(nodes)}
         cluster = self._build_cluster(nodes, bound_pods, n, r, index_by_name)
         pods, sel, pref, sel_index = self._build_pods(pending_pods, p_dim, r)
-        bound_by_node = [
-            (p, index_by_name[p.spec.node_name])
-            for p in bound_pods
-            if p.spec.node_name in index_by_name
-        ]
-        spread, terms, prefpod = self._build_constraints(
-            pending_pods, bound_by_node, sel_index, n, p_dim
+        # the same table builder as build_from_state, fed a throw-away
+        # index (keyed by position: a bulk caller's list may repeat a pod)
+        bound = BoundPodIndex()
+        for k, p in enumerate(bound_pods):
+            row = index_by_name.get(p.spec.node_name)
+            if row is not None:
+                bound.add(k, p, row)
+        spread, terms, prefpod, _ = self._build_constraints(
+            pending_pods, bound, sel_index, n, p_dim
         )
         images = self.image_table(pending_pods, p_dim)
         pods = _refine_classes(pods, spread, terms, prefpod, images)
@@ -794,8 +798,17 @@ class SnapshotBuilder:
         """Per-batch encode against an incremental ClusterState: only the
         pending pods (and their constraint tables) are encoded; cluster
         tensors are O(1) views of the state's arrays.  The incremental
-        UpdateSnapshot analogue (cache.go:185-260) — per-batch cost is
-        O(pending + changed), not O(cluster)."""
+        UpdateSnapshot analogue (cache.go:185-260).
+
+        What a batch costs: the pending pods, plus, for each constraint
+        row the batch or a bound term owner brings (a spread constraint,
+        a required or preferred (anti-)affinity term), one Python pass
+        over the live label signatures and one numpy pass over
+        `state.bound`'s per-pod arrays.  No Python loop runs over bound
+        pods: a batch with no such row, on a cluster with no term owner,
+        reads no bound entry at all.  Span `sched.encode.constraints`
+        says which it was: n = bound entries read, a0 = entries in the
+        index, a1 = live signatures."""
         if state.builder is not self:
             raise ValueError("state was built by a different SnapshotBuilder")
         # one effective-requests derivation per pod for the whole build:
@@ -815,9 +828,12 @@ class SnapshotBuilder:
             if self.columnar
             else self._build_pods(pending_pods, p_dim, r)
         )
-        spread, terms, prefpod = self._build_constraints(
-            pending_pods, state.bound_pods(), sel_index, n, p_dim
-        )
+        bound = state.bound
+        with trace.span("sched.encode.constraints") as sp:
+            spread, terms, prefpod, sp.n = self._build_constraints(
+                pending_pods, bound, sel_index, n, p_dim
+            )
+            sp.a0, sp.a1 = len(bound), bound.live_signatures
         images = self.image_table(pending_pods, p_dim)
         pods = _refine_classes(pods, spread, terms, prefpod, images)
         meta = SnapshotMeta(
@@ -1342,55 +1358,21 @@ class SnapshotBuilder:
     def _build_constraints(
         self,
         pods: Sequence[api.Pod],
-        bound_by_node: Sequence[Tuple[api.Pod, int]],
+        bound: "BoundPodIndex",
         sel_index: Dict[tuple, int],
         n: int,
         p_dim: int,
-    ) -> Tuple[SpreadTable, TermTable]:
+    ) -> Tuple[SpreadTable, TermTable, PrefPodTable, int]:
+        """The spread, term and preferred-term tables of one batch, and
+        how many of `bound`'s entries they read.  Constraint rows match
+        against label SIGNATURES (a few hundred) instead of pods (tens of
+        thousands): real clusters have far fewer label shapes than pods.
+        The bound pods' signatures, node rows and term owners come
+        precomputed in `bound`; nothing here loops over bound pods."""
         lim = self.limits
         tk = len(lim.topology_keys)
         mc, ma = lim.max_spread_per_pod, lim.max_pod_terms
-
-        # Distinct (namespace, labels) signatures across bound + pending
-        # pods.  Constraint rows match against SIGNATURES (a few hundred)
-        # instead of pods (tens of thousands): real clusters have far
-        # fewer label shapes than pods, and the naive rows x pods Python
-        # loop was the encode bottleneck at 10k-pod batches (2M+
-        # LabelSelector.matches calls per batch).
-        sig_of: Dict[tuple, int] = {}
-        distinct_sigs: List[Tuple[str, Dict[str, str]]] = []
-
-        def sig_id(pod: api.Pod) -> int:
-            key = (pod.meta.namespace, tuple(sorted(pod.meta.labels.items())))
-            idx = sig_of.get(key)
-            if idx is None:
-                idx = len(distinct_sigs)
-                sig_of[key] = idx
-                distinct_sigs.append((pod.meta.namespace, pod.meta.labels))
-            return idx
-
-        bound_sig = np.fromiter(
-            (sig_id(q) for q, _ in bound_by_node), np.int32, len(bound_by_node)
-        )
-        bound_node = np.fromiter(
-            (ni for _, ni in bound_by_node), np.int32, len(bound_by_node)
-        )
-        pend_sig = np.fromiter((sig_id(q) for q in pods), np.int32, len(pods))
-
-        def match_sigs(sel: api.LabelSelector, namespaces) -> np.ndarray:
-            """bool[n_sigs]: which distinct signatures the row matches.
-            `namespaces` is a container or a single owner namespace."""
-            ns_set = (
-                namespaces if isinstance(namespaces, tuple) else (namespaces,)
-            )
-            return np.fromiter(
-                (
-                    ns in ns_set and sel.matches(labels)
-                    for ns, labels in distinct_sigs
-                ),
-                bool,
-                len(distinct_sigs),
-            )
+        sigs = _BatchSignatures(bound, pods)
 
         # ---- topology spread constraints --------------------------------
         # A constraint instance is owner-scoped: eligibility honours the
@@ -1462,12 +1444,9 @@ class SnapshotBuilder:
             spread.owner_sel_idx[ci] = owner_sel_row
             for k in keys:
                 spread.owner_keys[ci, self._topo_slot(k)] = True
-            match = match_sigs(sel, owner_ns)
-            if len(bound_sig):
-                m = match[bound_sig]
-                np.add.at(spread.node_matches[ci], bound_node[m], 1.0)
-            if len(pend_sig):
-                spread.pod_matches[: len(pods), ci] = match[pend_sig]
+            match = sigs.match(sel, owner_ns)
+            sigs.count_bound(spread.node_matches[ci], match)
+            spread.pod_matches[: len(pods), ci] = match[sigs.pending]
 
         # ---- inter-pod (anti-)affinity terms ----------------------------
         # A row is (topology_key slot, effective selector, namespaces);
@@ -1504,7 +1483,7 @@ class SnapshotBuilder:
         # poison every future batch encode (it was admitted by someone
         # else); its term is skipped, unlike pending pods which raise.
         bound_anti: List[Tuple[int, int]] = []  # (term row, node index)
-        for q, ni in bound_by_node:
+        for q, ni in bound.owners():
             _, anti_terms = pod_terms(q)
             for t in anti_terms:
                 try:
@@ -1528,14 +1507,11 @@ class SnapshotBuilder:
         for ti, (topo_key, sel, namespaces) in enumerate(term_rows):
             terms.valid[ti] = True
             terms.slot[ti] = self._topo_slot(topo_key)
-            match = match_sigs(sel, namespaces)
-            if len(bound_sig):
-                m = match[bound_sig]
-                np.add.at(terms.node_matches[ti], bound_node[m], 1.0)
-            if len(pend_sig):
-                terms.matches_incoming[: len(pods), ti // 32] |= (
-                    match[pend_sig].astype(np.uint32) << np.uint32(ti % 32)
-                )
+            match = sigs.match(sel, namespaces)
+            sigs.count_bound(terms.node_matches[ti], match)
+            terms.matches_incoming[: len(pods), ti // 32] |= (
+                match[sigs.pending].astype(np.uint32) << np.uint32(ti % 32)
+            )
         for ti, ni in bound_anti:
             terms.node_owners[ti, ni] += 1.0
 
@@ -1555,15 +1531,13 @@ class SnapshotBuilder:
                 for t in aff_terms
             )
 
-        prefpod = self._build_prefpod(
-            pods, bound_by_node, n, p_dim, match_sigs, bound_sig, bound_node,
-            pend_sig,
-        )
-        return spread, terms, prefpod
+        prefpod = self._build_prefpod(pods, n, p_dim, sigs)
+        # entries read: the whole index where a row counted it, else the
+        # term owners walked (every term of theirs was skipped)
+        return spread, terms, prefpod, sigs.read or bound.num_owners
 
     def _build_prefpod(
-        self, pods, bound_by_node, n, p_dim, match_sigs, bound_sig,
-        bound_node, pend_sig,
+        self, pods, n, p_dim, sigs: "_BatchSignatures"
     ) -> PrefPodTable:
         """Preferred inter-pod affinity rows (see PrefPodTable).  Rows
         from both directions share one table: incoming pods' preferred
@@ -1607,7 +1581,7 @@ class SnapshotBuilder:
         # fields on BOUND pods skip the term instead of poisoning every
         # batch encode (pending pods still raise).
         owner_entries: List[Tuple[int, int, float]] = []  # (row, node, w)
-        for q, ni in bound_by_node:
+        for q, ni in sigs.bound.owners():
             for w, t in signed_terms(q):
                 try:
                     owner_entries.append((intern(t, q), ni, float(w)))
@@ -1635,12 +1609,9 @@ class SnapshotBuilder:
         for ui, (topo_key, sel, namespaces) in enumerate(rows):
             table.valid[ui] = True
             table.slot[ui] = self._topo_slot(topo_key)
-            match = match_sigs(sel, namespaces)
-            if len(bound_sig):
-                m = match[bound_sig]
-                np.add.at(table.node_counts[ui], bound_node[m], 1.0)
-            if len(pend_sig):
-                table.matches_incoming[: len(pods), ui] = match[pend_sig]
+            match = sigs.match(sel, namespaces)
+            sigs.count_bound(table.node_counts[ui], match)
+            table.matches_incoming[: len(pods), ui] = match[sigs.pending]
         for ui, ni, w in owner_entries:
             table.owner_weight[ui, ni] += w
         return table
@@ -1922,6 +1893,184 @@ class _PodSpecStore:
         return enc
 
 
+def _label_signature(pod: api.Pod) -> tuple:
+    """What a label selector can tell of a pod: its namespace and labels."""
+    return (pod.meta.namespace, tuple(sorted(pod.meta.labels.items())))
+
+
+def _has_pod_terms(pod: api.Pod) -> bool:
+    aff = pod.spec.affinity
+    if aff is None:
+        return False
+    a, b = aff.pod_affinity, aff.pod_anti_affinity
+    return bool(
+        (a and (a.required or a.preferred)) or (b and (b.required or b.preferred))
+    )
+
+
+class _Signature:
+    """A live label signature: what a selector reads of it, the key it
+    is interned under, and how many bound pods carry it."""
+
+    __slots__ = ("namespace", "labels", "key", "refs")
+
+    def __init__(self, key: tuple) -> None:
+        self.namespace, self.labels = key[0], dict(key[1])
+        self.key = key
+        self.refs = 0
+
+
+class BoundPodIndex:
+    """What the per-batch constraint tables need of the bound pods, kept
+    as pods come and go (`ClusterState.add_pod` / `remove_pod`, like
+    `requested`) instead of derived from every bound pod every encode.
+
+      signatures  an id per live (namespace, sorted labels) signature,
+                  reference-counted: the id of a signature no bound pod
+                  carries any more is handed to the next new one, so
+                  per-pod labels (pod-template-hash, job-name) do not
+                  grow the vocabulary a row is matched against.
+      sig, node   per bound pod, its signature id and its node row, in
+                  growable arrays with swap-remove; a constraint row
+                  counts its matches in two numpy lines over them.
+      term owners the (few) bound pods that carry inter-pod
+                  (anti-)affinity terms, in the order they were added:
+                  the only bound pods a table build visits one by one.
+
+    A pod's signature is taken once, from the object given to `add`, and
+    stored by key: `remove` needs the key alone (the cache confirms a
+    bind by keeping the assumed object, and removes with whatever object
+    the informer delivered).  Node rows follow `ClusterState._move_row`
+    through `move`."""
+
+    def __init__(self) -> None:
+        self._sig_ids: Dict[tuple, int] = {}
+        # by id; None is an id waiting in _free_ids for its next signature
+        self.signatures: List[Optional[_Signature]] = []
+        self._free_ids: List[int] = []
+        self._slots: Dict[object, int] = {}     # pod key -> slot
+        self._keys: List[object] = []           # slot -> pod key
+        self._n = 0
+        self.sig = np.zeros(64, dtype=np.int32)
+        self.node = np.zeros(64, dtype=np.int32)
+        self._owners: Dict[object, api.Pod] = {}
+
+    def __len__(self) -> int:
+        return self._n
+
+    @property
+    def live_signatures(self) -> int:
+        return len(self._sig_ids)
+
+    @property
+    def num_owners(self) -> int:
+        return len(self._owners)
+
+    def add(self, key, pod: api.Pod, row: int) -> None:
+        key_sig = _label_signature(pod)
+        sid = self._sig_ids.get(key_sig)
+        if sid is None:
+            if self._free_ids:
+                sid = self._free_ids.pop()
+                self.signatures[sid] = _Signature(key_sig)
+            else:
+                sid = len(self.signatures)
+                self.signatures.append(_Signature(key_sig))
+            self._sig_ids[key_sig] = sid
+        self.signatures[sid].refs += 1
+        slot = self._n
+        if slot == len(self.sig):
+            self.sig = np.concatenate([self.sig, np.zeros_like(self.sig)])
+            self.node = np.concatenate([self.node, np.zeros_like(self.node)])
+        self.sig[slot] = sid
+        self.node[slot] = row
+        self._slots[key] = slot
+        self._keys.append(key)
+        self._n = slot + 1
+        if _has_pod_terms(pod):
+            self._owners[key] = pod
+
+    def remove(self, key) -> None:
+        slot = self._slots.pop(key)
+        sid = int(self.sig[slot])
+        signature = self.signatures[sid]
+        signature.refs -= 1
+        if signature.refs == 0:
+            del self._sig_ids[signature.key]
+            self.signatures[sid] = None
+            self._free_ids.append(sid)
+        last = self._n - 1
+        last_key = self._keys.pop()
+        if slot != last:
+            self.sig[slot] = self.sig[last]
+            self.node[slot] = self.node[last]
+            self._keys[slot] = last_key
+            self._slots[last_key] = slot
+        self._n = last
+        self._owners.pop(key, None)
+
+    def move(self, keys: Sequence, row: int) -> None:
+        """The node these pods are bound to now lives in `row`."""
+        for key in keys:
+            self.node[self._slots[key]] = row
+
+    def owners(self) -> List[Tuple[api.Pod, int]]:
+        """(pod, node row) of the bound pods that carry terms."""
+        node, slots = self.node, self._slots
+        return [(p, int(node[slots[k]])) for k, p in self._owners.items()]
+
+
+class _BatchSignatures:
+    """One batch's view of the label signatures: the bound pods' from
+    the index, the pending pods' looked up there or, where no bound pod
+    carries them, numbered after the index's for this batch alone."""
+
+    def __init__(self, bound: BoundPodIndex, pods: Sequence[api.Pod]) -> None:
+        self.bound = bound
+        self.read = 0       # entries of the index a row counted
+        ids, base = bound._sig_ids, len(bound.signatures)
+        new: Dict[tuple, int] = {}
+        self._extra: List[Tuple[str, Dict[str, str]]] = []
+
+        def sig_id(pod: api.Pod) -> int:
+            key = _label_signature(pod)
+            sid = ids.get(key)
+            if sid is None:
+                sid = new.get(key)
+                if sid is None:
+                    sid = new[key] = base + len(self._extra)
+                    self._extra.append((pod.meta.namespace, pod.meta.labels))
+            return sid
+
+        # int32[len(pods)]: the signature id of each pending pod
+        self.pending = np.fromiter((sig_id(q) for q in pods), np.int32, len(pods))
+
+    def match(self, sel: api.LabelSelector, namespaces) -> np.ndarray:
+        """bool[n signature ids]: which signatures the row matches.
+        `namespaces` is a container or a single owner namespace."""
+        ns_set = namespaces if isinstance(namespaces, tuple) else (namespaces,)
+        live = self.bound.signatures
+        return np.fromiter(
+            itertools.chain(
+                (
+                    s is not None and s.namespace in ns_set and sel.matches(s.labels)
+                    for s in live
+                ),
+                (ns in ns_set and sel.matches(lb) for ns, lb in self._extra),
+            ),
+            bool,
+            len(live) + len(self._extra),
+        )
+
+    def count_bound(self, out: np.ndarray, match: np.ndarray) -> None:
+        """out[node row] += 1 for every bound pod whose signature matches."""
+        b = self.bound
+        k = len(b)
+        if k:
+            self.read = k
+            np.add.at(out, b.node[:k][match[b.sig[:k]]], 1.0)
+
+
 class ClusterState:
     """Incremental cluster-tensor store — the tensorization of the
     reference scheduler cache's generation-tracked node bookkeeping with
@@ -1929,7 +2078,12 @@ class ClusterState:
     snapshot.go).  Node add/update/remove and pod add/remove each touch
     one row of preallocated arrays; tensors() is O(1) array slicing, so
     per-batch snapshot cost is proportional to what changed since the
-    last batch, not to cluster size.
+    last batch, not to cluster size.  That holds for the constraint
+    tables too: `bound` (a BoundPodIndex) keeps each bound pod's label
+    signature and node row and the bound term owners, updated here by
+    add_pod / remove_pod / remove_node and, where compaction moves a
+    node's row, by _move_row — so build_from_state never walks `_pods`
+    (the host fallback, preemption and the debugger still read it).
 
     The scheduler cache's assume/forget protocol maps to add_pod /
     remove_pod: an assumed pod's resources are added immediately and
@@ -1999,6 +2153,9 @@ class ClusterState:
         self._pods: Dict[str, api.Pod] = {}       # bound/assumed, by pod key
         self._pod_node: Dict[str, str] = {}
         self._pods_by_node: Dict[str, List[str]] = {}
+        # what the constraint tables read of those pods, kept as they come
+        # and go so that no encode walks them (BoundPodIndex)
+        self.bound = BoundPodIndex()
         # Generation protocol for device-resident mirrors (the
         # cache.go:185-260 snapshotGeneration analogue, per ROW and split
         # by mutation family so consumers re-upload only what moved):
@@ -2147,6 +2304,7 @@ class ClusterState:
         for pk in self._pods_by_node.pop(name, []):
             self._pods.pop(pk, None)
             self._pod_node.pop(pk, None)
+            self.bound.remove(pk)
         self._clear_row(i)
         heapq.heappush(self._free, i)
         self._free_set.add(i)
@@ -2188,6 +2346,7 @@ class ClusterState:
         name = self.node_names[src]
         self.node_names[dst] = name
         self._rows[name] = dst
+        self.bound.move(self._pods_by_node[name], dst)
         self._static_gen[dst] = self._usage_gen[dst] = self._bump()
         self._clear_row(src)
 
@@ -2274,6 +2433,7 @@ class ClusterState:
         self._pods[key] = pod
         self._pod_node[key] = node_name
         self._pods_by_node[node_name].append(key)
+        self.bound.add(key, pod, i)
 
     def remove_pod(self, pod: api.Pod) -> None:
         """Unaccount a pod (ForgetPod / RemovePod).  Port bits are
@@ -2282,6 +2442,7 @@ class ClusterState:
         key = self._pod_key(pod)
         node_name = self._pod_node.pop(key)
         self._pods.pop(key)
+        self.bound.remove(key)
         i = self._rows[node_name]
         self._pods_by_node[node_name].remove(key)
         req, nz, _ = self.builder.pod_usage(pod, self._r)
@@ -2295,12 +2456,6 @@ class ClusterState:
 
     def has_pod(self, pod: api.Pod) -> bool:
         return self._pod_key(pod) in self._pods
-
-    def bound_pods(self) -> List[Tuple[api.Pod, int]]:
-        """(pod, node row) pairs — input to per-batch constraint tables."""
-        return [
-            (p, self._rows[self._pod_node[k]]) for k, p in self._pods.items()
-        ]
 
     # -- snapshot ---------------------------------------------------------
 
