@@ -1058,8 +1058,6 @@ class TPUBatchScheduler:
         pool = self.prewarm_pool
         if pool is None or route == "auction":
             return
-        from ..utils.vocab import pad_dim
-
         p_dim = snap.pods.req.shape[0]
         n_dim = snap.cluster.allocatable.shape[0]
         mesh_key = self._mesh_size if sharded else 0
@@ -1096,7 +1094,10 @@ class TPUBatchScheduler:
             if route == "wavefront":
                 if p_variant != p_dim or wshape is None:
                     wshape = (
-                        pad_dim(max(-(-p_variant // self.wave_cap), 1), 8),
+                        assign_ops.wave_rows(
+                            p_variant, self.wave_cap,
+                            assign_ops.waves_couple(features),
+                        ),
                         self.wave_cap,
                     )
                 args_shapes = (

@@ -478,6 +478,51 @@ class DeviceClusterMirror:
             else self._rep_shardings,
         )
 
+    def _setters(self):
+        """(row scatter, axis-1 scatter) for the resident layout."""
+        if self._shardings is not None and not self._resident_sharded:
+            # replicated resident copy (bucket smaller than the mesh):
+            # the pinned-sharding scatters don't apply — use the plain
+            # ones; operands are all mesh-replicated so placement agrees
+            return _set_rows, _set_rows_ax1
+        return self._set, self._set_ax1
+
+    def warm_usage_buckets(self, max_rows: int) -> None:
+        """Build or load the usage-leaf scatter of every dirty-row bucket
+        a bind wave of up to `max_rows` pods can leave behind (1, 2, 4,
+        ... rows), against the resident tensors: Scheduler.warmup's
+        share of the mirror, so that the first wave of each size does
+        not pay for an executable inside a cycle.  The results are
+        dropped: nothing resident changes.  Caller holds the cache lock
+        and has synced (a mirror without residents has nothing to
+        warm)."""
+        dev = self._dev
+        if dev is None:
+            return
+        host = self.state.tensors()
+        set_rows, _ = self._setters()
+        n = host.allocatable.shape[0]
+        # larger deltas re-upload whole (sync), so no scatter exists
+        top = min(vb.pad_dim(max(max_rows, 1), 1), int(self.FULL_SYNC_FRACTION * n))
+        bucket = 1
+        while bucket <= top:
+            self._scatter_usage(
+                dev, host, np.zeros(bucket, dtype=np.int32), set_rows
+            )
+            bucket *= 2
+
+    def _scatter_usage(self, base, host, pidx: np.ndarray, set_rows) -> dict:
+        """{leaf: resident leaf with the host's rows `pidx` written in}
+        for the usage family."""
+        idx_dev = self._put_small(pidx)
+        return {
+            leaf: set_rows(
+                getattr(base, leaf), idx_dev,
+                self._put_small(np.asarray(getattr(host, leaf))[pidx]),
+            )
+            for leaf in _USAGE_LEAVES
+        }
+
     def _apply_deltas(
         self,
         host: schema.ClusterTensors,
@@ -487,13 +532,7 @@ class DeviceClusterMirror:
         dev = self._dev
         self.delta_syncs += 1
         self.delta_rows_total += int(static_idx.shape[0] + usage_idx.shape[0])
-        if self._shardings is not None and not self._resident_sharded:
-            # replicated resident copy (bucket smaller than the mesh):
-            # the pinned-sharding scatters don't apply — use the plain
-            # ones; operands are all mesh-replicated so placement agrees
-            set_rows, set_ax1 = _set_rows, _set_rows_ax1
-        else:
-            set_rows, set_ax1 = self._set, self._set_ax1
+        set_rows, set_ax1 = self._setters()
         updates = {}
         if static_idx.shape[0]:
             bucket = vb.pad_dim(static_idx.shape[0], 1)
@@ -508,10 +547,8 @@ class DeviceClusterMirror:
             )
         if usage_idx.shape[0]:
             bucket = vb.pad_dim(usage_idx.shape[0], 1)
-            pidx = _pad_idx(usage_idx, bucket)
-            idx_dev = self._put_small(pidx)
             base = dev._replace(**updates) if updates else dev
-            for leaf in _USAGE_LEAVES:
-                vals = self._put_small(np.asarray(getattr(host, leaf))[pidx])
-                updates[leaf] = set_rows(getattr(base, leaf), idx_dev, vals)
+            updates.update(self._scatter_usage(
+                base, host, _pad_idx(usage_idx, bucket), set_rows
+            ))
         return dev._replace(**updates) if updates else dev

@@ -939,6 +939,33 @@ class WavePlan(NamedTuple):
     n_waves: int         # real (non-empty) wave count
 
 
+def waves_couple(features: FeatureFlags) -> bool:
+    """True when members of one wave can conflict (host ports, spread
+    rows, inter-pod terms): how many waves a batch then splits into is
+    its composition's, anything from P/K to one wave a pod."""
+    return bool(
+        features.ports or features.spread or features.soft_spread
+        or features.interpod
+    )
+
+
+def wave_rows(p: int, wave_cap: int, coupled: bool) -> int:
+    """Rows W of the i32[W, K] wave plan of a p-pod bucket — a shape of
+    the wavefront executable, so a function of the bucket and the
+    feature set, never of the batch's composition.  Uncoupled batches
+    fill every wave to the cap: ceil(p/K) rows, floored at 8.  Coupled
+    ones get one row a pod, the most any partition needs: rows past the
+    real waves are all -1 and the scan skips them (a cond on
+    ``mvalid.any()``), so the width costs a predicate a row.  An
+    uncoupled batch that headroom splits past its rows takes the
+    coupled shape (plan_waves)."""
+    from ..utils.vocab import pad_dim
+
+    if coupled:
+        return pad_dim(p, 8)
+    return pad_dim(max(-(-p // wave_cap), 1), 8)
+
+
 def _pack_idx_rows(idx: np.ndarray, dim: int) -> np.ndarray:
     """i32[P, M] index lists (-1 pad) -> packed u32[P, words] membership."""
     p = idx.shape[0]
@@ -979,8 +1006,6 @@ def plan_waves(  # graftlint: disable=purity -- host-side prep: the wave partiti
     The partition is a pure performance hint: wavefront_assign re-checks
     coupling on device and serializes unsafe waves, so any output of this
     function yields placements identical to the scan."""
-    from ..utils.vocab import pad_dim
-
     if features is None:
         features = features_of(snapshot)
     pods = snapshot.pods
@@ -1068,7 +1093,12 @@ def plan_waves(  # graftlint: disable=purity -- host-side prep: the wave partiti
     close()
 
     n_waves = len(waves)
-    w_pad = pad_dim(max(n_waves, 1), 8)
+    w_pad = wave_rows(p, wave_cap, waves_couple(features))
+    if n_waves > w_pad:
+        # headroom splits of an uncoupled batch (a cluster near full)
+        # passed the rows its bucket always gets: the coupled shape
+        # then, so a bucket still has two plans at most
+        w_pad = wave_rows(p, wave_cap, True)
     members = np.full((w_pad, wave_cap), -1, dtype=np.int32)
     for wi, wv in enumerate(waves):
         members[wi, : len(wv)] = wv

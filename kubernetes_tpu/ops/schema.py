@@ -182,7 +182,13 @@ class PodBatch(NamedTuple):
     (cons_rep, one row per distinct service-shaped constraint set).
     joint_spec/joint_cons map each joint class to its factors, so the
     joint-axis combine is pure gathers + elementwise — 200 services × 5
-    pod shapes costs 205 heavy rows, not 1000."""
+    pod shapes costs 205 heavy rows, not 1000.
+
+    The class dims are shapes of the solve's executables, so they follow
+    the workload and not a batch's composition: without constraints
+    C = Cs = pad_dim(spec classes + the pad rows' slot, 1) and Cc = 1;
+    a batch split by constraint identity pads all three by the
+    constraint rows' rule (vocab.pad_constraint_dim, floor 32)."""
 
     valid: np.ndarray        # bool[P]
     req: np.ndarray          # f32[P, R]
@@ -808,7 +814,10 @@ class SnapshotBuilder:
         pods: a batch with no such row, on a cluster with no term owner,
         reads no bound entry at all.  Span `sched.encode.constraints`
         says which it was: n = bound entries read, a0 = entries in the
-        index, a1 = live signatures."""
+        index, a1 = live signatures.  Span `sched.encode.classes`
+        times the class split: n = spread rows, a0 = live constraint
+        classes (1 where the batch has no constraint: the trivial one),
+        a1 = the padded class dim the solve's statics take."""
         if state.builder is not self:
             raise ValueError("state was built by a different SnapshotBuilder")
         # one effective-requests derivation per pod for the whole build:
@@ -835,7 +844,11 @@ class SnapshotBuilder:
             )
             sp.a0, sp.a1 = len(bound), bound.live_signatures
         images = self.image_table(pending_pods, p_dim)
-        pods = _refine_classes(pods, spread, terms, prefpod, images)
+        with trace.span("sched.encode.classes") as sp:
+            pods = _refine_classes(pods, spread, terms, prefpod, images)
+            sp.n = int(spread.valid.sum())
+            sp.a0 = int((pods.cons_rep >= 0).sum())
+            sp.a1 = pods.class_rep.shape[0]
         meta = SnapshotMeta(
             num_nodes=state._high,
             num_pods=len(pending_pods),
@@ -2649,19 +2662,24 @@ def _refine_classes(
         np.stack([pods.class_id.view(np.uint32), cons_id.view(np.uint32)], axis=1)
     )
     class_id, reps = _first_seen_unique(joint_sig)
-    c_dim = vb.pad_dim(len(reps), 1)
-    class_rep = np.full(c_dim, -1, dtype=np.int32)
-    class_rep[: len(reps)] = reps
-    joint_spec = np.zeros(c_dim, dtype=np.int32)
-    joint_spec[: len(reps)] = pods.class_id[reps]
-    joint_cons = np.zeros(c_dim, dtype=np.int32)
-    joint_cons[: len(reps)] = cons_id[reps]
-    cc_dim = vb.pad_dim(len(cons_reps), 1)
-    cons_rep = np.full(cc_dim, -1, dtype=np.int32)
-    cons_rep[: len(cons_reps)] = cons_reps
+
+    # every class dim of a split batch pads by the constraint rows' rule
+    # (floor 32): which of the workload's classes a batch happens to
+    # hold — one namespace's pods or sixteen's, pad rows or none — then
+    # picks no executable (vb.pad_constraint_dim)
+    def padded(vals, fill: int = -1) -> np.ndarray:
+        out = np.full(vb.pad_constraint_dim(len(vals)), fill, dtype=np.int32)
+        out[: len(vals)] = vals
+        return out
+
+    class_rep = padded(reps)
+    joint_spec = padded(pods.class_id[reps], 0)
+    joint_cons = padded(cons_id[reps], 0)
+    cons_rep = padded(cons_reps)
+    spec_rep = padded(pods.class_rep)
     return pods._replace(
         class_id=class_id, class_rep=class_rep,
-        spec_rep=pods.class_rep, joint_spec=joint_spec,
+        spec_rep=spec_rep, joint_spec=joint_spec,
         cons_rep=cons_rep, joint_cons=joint_cons,
     )
 
@@ -2738,7 +2756,10 @@ def _pod_classes(
             index[key] = c
             reps.append(i)
         class_id[i] = c
-    c_dim = vb.pad_dim(len(reps), 1)
+    # the pad rows' class (valid=False) has its slot whether or not this
+    # batch has pad rows: a batch that fills its pod bucket keeps the
+    # class dim, and so the executable, of one that does not
+    c_dim = vb.pad_dim(len(reps) + int(bool(valid.all())), 1)
     class_rep = np.full(c_dim, -1, dtype=np.int32)
     class_rep[: len(reps)] = np.asarray(reps, dtype=np.int32)
     return class_id, class_rep

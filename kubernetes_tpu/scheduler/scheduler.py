@@ -34,6 +34,7 @@ TPUBatchScheduler (models/batch_scheduler.py).
 
 from __future__ import annotations
 
+import copy
 import logging
 import threading
 import time
@@ -1541,9 +1542,14 @@ class Scheduler:
             # a real trace/compile, not dispatch-enqueue noise
             self.metrics.solve_compile_duration.observe(compile_s)
         if ds.wave_count is not None:
+            # read back with the names, in the one coalesced device_get
             self.metrics.solve_wave_count.observe(float(ds.wave_count))
             self.metrics.solve_wave_fallbacks.observe(
                 float(ds.wave_fallbacks or 0)
+            )
+            _trace.event(
+                "sched.solve.waves", now, now, ds.wave_count,
+                a0=float(ds.wave_fallbacks or 0),
             )
         if ds.frag_score is not None:
             # slice-family solve: mirror the carve-out telemetry (same
@@ -2132,13 +2138,28 @@ class Scheduler:
         the workload's.  Combined with the persistent compilation cache
         (utils/compilecache.py) later processes warm in milliseconds.
 
-        Two rounds per bucket: round A against the current (typically
-        bound-pod-free) cluster, round B with one template pod assumed —
+        What it enumerates is the executable key set of the templates
+        (docs/scheduler_loop.md, "What makes an executable new"): every
+        shape but the pod bucket is a function of the deployment — the
+        class dims and row dims floor at 32 (vocab.pad_constraint_dim),
+        a coupled batch's wave plan has one row a pod
+        (ops.assign.wave_rows), topo_z and the slot tuples come from
+        the cluster and the templates — so a bucket solved here is the
+        executable a live batch of that bucket asks for, whatever its
+        composition.
+
+        Round A solves every bucket against the current (typically
+        bound-pod-free) cluster.  Then one template pod is assumed:
         the bound_* FeatureFlags flip once the first batch binds, which
-        is a NEW executable; without round B the second measured batch
-        of a constraint workload would compile mid-window.  For
-        constraint-free workloads round B is a jit-cache hit and costs
-        an encode (~ms).
+        is a NEW executable, so templates with spread constraints or
+        inter-pod terms get round B, every bucket again (for
+        constraint-free pods the count tables have no rows and the
+        bits cannot flip).  With the pod still assumed, every template
+        set warms what only a cluster that changes between solves
+        meets: a batch that leaves pad rows (a first-seen class: the
+        PartialsCache's insert path), one dirty row (its refresh path)
+        and the mirror's usage scatter at every dirty-row bucket a wave
+        of up to a full batch can leave (mirror.warm_usage_buckets).
 
         Returns seconds spent.  Never raises: a bucket that fails to
         encode (cap overflow) is skipped — the real cycle handles those
@@ -2157,10 +2178,10 @@ class Scheduler:
             b *= 2
         log = logging.getLogger(__name__)
 
-        def warm_bucket(bucket: int) -> None:
+        def warm_batch(bucket: int, n_pods: int) -> None:
             try:
                 fwk.tpu.schedule_pending(
-                    pods[:bucket], num_pods_hint=bucket, lock=self.cache.lock,
+                    pods[:n_pods], num_pods_hint=bucket, lock=self.cache.lock,
                 )
             except Exception:
                 # device compile/runtime faults were already contained
@@ -2170,7 +2191,7 @@ class Scheduler:
                     "warmup skipped on %s: pod bucket %d over %d nodes "
                     "(%d template pods) failed to encode",
                     device_label(), bucket, len(self.tpu.state._rows),
-                    len(pods[:bucket]),
+                    len(pods[:n_pods]),
                 )
 
         def warm_all() -> None:
@@ -2180,10 +2201,8 @@ class Scheduler:
             from concurrent.futures import ThreadPoolExecutor
 
             with ThreadPoolExecutor(max_workers=4) as ex:
-                list(ex.map(warm_bucket, reversed(buckets)))
+                list(ex.map(lambda b: warm_batch(b, b), reversed(buckets)))
 
-        # constraint-free pods can never flip the bound_* feature flags
-        # (their count tables have no rows), so one round suffices
         needs_bound_round = any(
             p.spec.topology_spread_constraints
             or (p.spec.affinity and (p.spec.affinity.pod_affinity
@@ -2191,27 +2210,29 @@ class Scheduler:
             for p in pods
         )
         warm_all()
-        if needs_bound_round:
-            # round B: one template pod assumed on a live node flips
-            # bound_spread/bound_terms/bound_pref — a NEW executable the
-            # second measured batch would otherwise compile mid-window
-            import copy
-
-            clone = copy.deepcopy(pods[0])
-            clone.meta.name = "warmup-bound-pod"
-            clone.meta.namespace = pods[0].meta.namespace or "default"
-            node0 = next(iter(self.tpu.state._rows))
-            try:
-                self.cache.assume(clone, node0)  # graftlint: disable=obligations -- the warm_all finally forgets the clone; if THAT forget fails it is logged and cleanup_expired retires the synthetic assume by TTL
-            except Exception:
-                return self._clock() - t0  # no usable node; round A ran
-            try:
+        clone = copy.deepcopy(pods[0])
+        clone.meta.name = "warmup-bound-pod"
+        clone.meta.namespace = pods[0].meta.namespace or "default"
+        node0 = next(iter(self.tpu.state._rows))
+        try:
+            self.cache.assume(clone, node0)  # graftlint: disable=obligations -- the finally below forgets the clone; if THAT forget fails it is logged and cleanup_expired retires the synthetic assume by TTL
+        except Exception:
+            return self._clock() - t0  # no usable node; round A ran
+        try:
+            if needs_bound_round:
                 warm_all()
-            finally:
-                try:
-                    self.cache.forget(clone)
-                except Exception:
-                    log.exception("warmup: forgetting the bound clone failed")
+            warm_batch(buckets[0], max(buckets[0] - 1, 1))
+            mirror = fwk.tpu._mirror if fwk.tpu.use_mirror else None
+            if mirror is not None:
+                with self.cache.lock:
+                    mirror.warm_usage_buckets(cap)
+        except Exception:
+            log.exception("warmup: warming the delta paths failed")
+        finally:
+            try:
+                self.cache.forget(clone)
+            except Exception:
+                log.exception("warmup: forgetting the bound clone failed")
         return self._clock() - t0
 
     # -- test/bench convenience -------------------------------------------

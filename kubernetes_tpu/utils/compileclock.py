@@ -12,6 +12,12 @@ so a caller brackets its own work with two reads of :func:`events` and
 compares.  Compiles on other threads (the prewarm pool, the parallel
 warm-up) never count against the reader.
 
+Each build or cache load also goes to the flight recorder as a span
+``sched.compile`` (``a0`` = the seconds JAX reports for the backend
+step) under the cycle the paying thread has open, so a slow cycle's
+log line names its own compile (docs/scheduler_loop.md, "Reading a
+slow cycle").
+
 A count, not seconds to subtract: the durations JAX reports leave out
 work it does around the three timed steps, which grows with the
 compile.  On a v5e chip 0.3-1.4 s of a compiling cycle stayed
@@ -26,18 +32,25 @@ import threading
 
 import jax.monitoring
 
+from . import trace
+
 _EVENTS = frozenset({
     "/jax/core/compile/jaxpr_trace_duration",
     "/jax/core/compile/jaxpr_to_mlir_module_duration",
     "/jax/core/compile/backend_compile_duration",
 })
 
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+
 _seen = threading.local()
 
 
-def _on_duration(event: str, _secs: float, **_kw) -> None:
+def _on_duration(event: str, secs: float, **_kw) -> None:
     if event in _EVENTS:
         _seen.n = getattr(_seen, "n", 0) + 1
+        if event == _BACKEND:
+            t1 = trace.now()
+            trace.event("sched.compile", t1 - secs, t1, 1, a0=secs)
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_duration)

@@ -235,6 +235,22 @@ def span(name: str, n: int = 0, cycle: Optional[int] = None,
     return sp
 
 
+def event(name: str, t0: float, t1: float, n: float = 0,
+          a0: float = 0.0, a1: float = 0.0) -> None:
+    """A closed row for something whose interval the caller already
+    holds (a compile that JAX timed, a count read back with a solve):
+    a child of the span, and of the cycle, this thread has open (of
+    none where none is open), with no CPU reading and no annotation:
+    the cost is the row."""
+    i = _open_row(name, t0, _NAN, getattr(_tls, "cycle", 0),
+                  getattr(_tls, "cur", 0), n)
+    base = (i % SPAN_ROWS) * _SW
+    mv = _smv
+    mv[base + _END] = t1
+    mv[base + _A0] = a0
+    mv[base + _A1] = a1
+
+
 def tally(name: str, t0: float, t1: float) -> None:
     """Add the interval ``[t0, t1)`` to this thread's running row of
     `name`: one row per tenth of a second, not one per interval."""
@@ -483,7 +499,8 @@ class Trace:
     @property
     def steps(self) -> list:
         """(name, seconds) of the root's direct children on its own
-        thread, in starting order."""
+        thread, in starting order (a zero-length row is a count that
+        rode on the cycle, not a step of it)."""
         import numpy as np
 
         t = _table(_smv, _SW, SPAN_ROWS, _span_ctr)[0]
@@ -491,9 +508,20 @@ class Trace:
         if root[_ID] != self.id:
             return []
         kids = t[(t[:, _PARENT] == self.id) & (t[:, _ID] > self.id)
-                 & (t[:, _TID] == root[_TID]) & (t[:, _END] == t[:, _END])]
+                 & (t[:, _TID] == root[_TID]) & (t[:, _END] > t[:, _START])]
         kids = kids[np.argsort(kids[:, _START], kind="stable")]
         return [(_names[int(k[_NAME])], float(k[_END] - k[_START])) for k in kids]
+
+    def _compiles(self) -> str:
+        """The executables this cycle's thread built or loaded under it
+        (``sched.compile`` rows, utils/compileclock.py): they lie inside
+        a step, so the steps alone would not name them."""
+        t = _table(_smv, _SW, SPAN_ROWS, _span_ctr)[0]
+        rows = t[(t[:, _CYCLE] == self.id) & (t[:, _ID] > self.id)
+                 & (t[:, _NAME] == _name_ids.get("sched.compile", -1))]
+        if not len(rows):
+            return ""
+        return f"; of which sched.compile x{len(rows)}: {rows[:, _A0].sum() * 1e3:.1f}ms"
 
     def _meanwhile(self) -> str:
         """What ran beside this cycle: the other threads' spans that
@@ -566,8 +594,9 @@ class Trace:
         tags = ",".join(f"{k}={v}" for k, v in self.fields.items())
         parts = "; ".join(f"{w}: {dt * 1e3:.1f}ms" for w, dt in self.steps)
         logger.warning(
-            "trace %s (%s) took %.1fms (threshold %.0fms): %s%s",
+            "trace %s (%s) took %.1fms (threshold %.0fms): %s%s%s",
             self.name, tags, total * 1e3, limit * 1e3, parts,
+            self._compiles(),
             self._meanwhile() if self._clock is time.perf_counter else "",
         )
         _overran.append((self, total, limit))

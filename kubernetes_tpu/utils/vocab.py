@@ -164,20 +164,26 @@ def is_pad_bucket(n: int, minimum: int = 1) -> bool:
 
 
 def is_constraint_bucket(n: int) -> bool:
-    """True when n is a value pad_constraint_dim can produce: 1 (no
-    rows) or a power of two floored at 32."""
+    """True when n is a value pad_constraint_dim can produce: 1 (none)
+    or a power of two floored at 32."""
     return n == 1 or (n >= 32 and is_pad_bucket(n))
 
 
 def pad_constraint_dim(n: int) -> int:
-    """Constraint-table row dims (selector/spread/term/preferred rows).
-    Zero rows stay at dim 1 — the feature flags gate the whole family
-    off and the [1, N] zero table costs one cached fill.  NONZERO rows
-    floor at 32: straggler batches (retries, late arrivals) carry
-    arbitrary subsets of the main batch's constraint classes, and
-    per-power-of-two row dims would compile a fresh executable for
-    nearly every straggler composition — the dominant in-window compile
-    source for constraint workloads."""
+    """The one padding rule of everything a batch's constraints size:
+    the tables' row dims (selector/spread/term/preferred rows) AND the
+    class dims of a batch split by constraint identity
+    (schema._refine_classes: joint, spec and constraint classes, which
+    shape the warm solve's [C, N] statics).  None stays at dim 1 — the
+    feature flags gate the whole family off and the [1, N] zero table
+    costs one cached fill.  ANY floor at 32: straggler batches (retries,
+    late arrivals, a cycle cut short) carry arbitrary subsets of the
+    workload's rows and classes, and per-power-of-two dims would ask for
+    a fresh executable for nearly every composition — the dominant
+    in-window compile source for constraint workloads.  With the floor
+    the dims are a function of the deployment (how many distinct rows
+    and classes it can bring at all), which Scheduler.warmup can
+    enumerate from its templates."""
     if n == 0:
         return 1
     return pad_dim(n, 32)

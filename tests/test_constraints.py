@@ -230,3 +230,71 @@ def test_randomized_parity_with_constraints(seed):
         pods.append(pw.obj())
     got, want = run_both(nodes, pods)
     assert got == want
+
+
+# ---------------------------------------------------------------------------
+# Class dims padded by the constraint rows' rule (vocab.pad_constraint_dim)
+# ---------------------------------------------------------------------------
+
+
+def _tight_classes(snap):
+    """The same batch with every class dim cut back to pad_dim(n, 1), the
+    shape a split batch had before its class dims got the floor of 32."""
+    from kubernetes_tpu.utils import vocab as vb
+
+    pods = snap.pods
+
+    def cut(reps, *per_class):
+        n = int((reps >= 0).sum())
+        dim = vb.pad_dim(max(n, 1), 1)
+        return [a[:dim] for a in (reps, *per_class)]
+
+    class_rep, joint_spec, joint_cons = cut(
+        pods.class_rep, pods.joint_spec, pods.joint_cons
+    )
+    (spec_rep,), (cons_rep,) = cut(pods.spec_rep), cut(pods.cons_rep)
+    return snap._replace(pods=pods._replace(
+        class_rep=class_rep, joint_spec=joint_spec, joint_cons=joint_cons,
+        spec_rep=spec_rep, cons_rep=cons_rep,
+    ))
+
+
+@pytest.mark.parametrize("route", ["greedy", "wavefront"])
+@pytest.mark.parametrize("seed", range(4))
+def test_padded_class_dims_place_as_unpadded_and_as_the_oracle(seed, route):
+    """scheduler_perf's TopologySpreading pods over a seeded subset of 16
+    namespaces, against a cluster that already holds some of them: the
+    padded class rows are masked out, so placements equal the oracle's
+    whatever the class dims."""
+    rng = np.random.default_rng(700 + seed)
+    nodes = [
+        make_node(f"n{i}").capacity(cpu_milli=4000, mem=32 * GI, pods=110)
+        .zone(f"zone-{i % 8}").obj()
+        for i in range(24)
+    ]
+    nss = [f"team-{i}" for i in rng.choice(16, size=int(rng.integers(1, 17)), replace=False)]
+
+    def blue(name):
+        return (
+            make_pod(name, str(rng.choice(nss))).labels(color="blue")
+            .req(cpu_milli=100, mem=500 * MI)
+            .spread(5, api.LABEL_ZONE, "DoNotSchedule", {"color": "blue"}).obj()
+        )
+
+    bound = []
+    for i in range(int(rng.integers(0, 40))):
+        p = blue(f"b{i}")
+        p.spec.node_name = nodes[int(rng.integers(0, 6))].meta.name   # skewed on purpose
+        bound.append(p)
+    pods = [blue(f"p{i}") for i in range(int(rng.integers(9, 70)))]
+
+    snap, meta = schema.SnapshotBuilder().build(nodes, pods, bound_pods=bound)
+    tight = _tight_classes(snap)
+    assert snap.pods.class_rep.shape[0] == 32 >= tight.pods.class_rep.shape[0]
+    solve = (
+        assign.greedy_assign_jit() if route == "greedy" else assign.wavefront_assign_jit()
+    )
+    got = [np.asarray(solve(s).assignment)[: len(pods)] for s in (snap, tight)]
+    assert (got[0] == got[1]).all()
+    want = Oracle(nodes, bound_pods=bound).schedule(pods)
+    assert [meta.node_name(int(i)) for i in got[0]] == want
