@@ -475,9 +475,11 @@ class DeviceSolve:
     deferred until `names()`/`reasons()` is first called, and then runs
     as ONE coalesced device_get of every array the caller will need —
     the previous path paid separate blocking np.asarray round-trips for
-    assignment and reasons.  Deferral is what lets the scheduling thread
-    overlap batch N's readback with its own host work (queue pop window,
-    wave staging) instead of idling on the transfer."""
+    assignment and reasons.  Deferral lets a caller put host work of its
+    own between the dispatch and the decode; `deferred_s` is that gap.
+    The scheduling loop has none to put there (collecting arrivals costs
+    the lane nothing): it decodes at once and sleeps in the device_get,
+    interpreter lock released, for as long as the device takes."""
 
     def __init__(self, result: Result, meta: schema.SnapshotMeta):
         self.result = result
@@ -1418,8 +1420,8 @@ class TPUBatchScheduler:
         """Encode + dispatch one batch without blocking on the device.
         Returns None for an empty batch.  The caller finishes the step
         with finalize_pending() once it wants the names — anything it
-        does in between (queue pop window, wave staging) overlaps the
-        device solve and the readback."""
+        does in between overlaps the device solve and the readback (the
+        scheduling loop does nothing in between: it finishes in place)."""
         if not pending:
             return None
         if not self.breaker.allow_device():

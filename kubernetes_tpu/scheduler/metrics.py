@@ -216,9 +216,10 @@ class Registry:
         self.solve_compile_duration = Histogram(
             "scheduler_solve_compile_duration_seconds"
         )
-        # seconds of device solve + readback hidden behind host work
-        # (the pop window) per group — the realized solve-side overlap;
-        # a healthy pipeline keeps this close to the device solve time
+        # seconds between a group's dispatch and the start of its decode:
+        # what the caller hid of the device solve behind work of its own.
+        # About 0 in the scheduling loop, which decodes at once (the
+        # device's time shows in the decode wait instead)
         self.decode_overlap = Histogram(
             "scheduler_decode_overlap_seconds"
         )

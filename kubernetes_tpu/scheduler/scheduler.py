@@ -1121,12 +1121,15 @@ class Scheduler:
         self.queue.requeue_backoff(info)
 
     def _run(self, lane_idx: int = 0) -> None:
-        # The solve-side pipeline: the LAST profile group of cycle N stays
-        # a device future (DeviceSolve) while the next pop's accumulation
-        # window runs — the device solves and the readback transfers while
-        # the host collects arrivals, instead of the host idling inside
-        # np.asarray.  The deferred group is decoded and staged BEFORE the
-        # next batch encodes, so snapshots still see every assume.
+        # Each pass is one whole cycle: pop -> _dispatch_batch ->
+        # _finish_cycle, the halves schedule_batch() calls back to back.
+        # The lane blocks in the decode for as long as the device and the
+        # readback take, stages, hands the wave to the commit workers, and
+        # only then goes back to the queue: a solved batch never waits
+        # out the next batch's accumulation window.  The window opens
+        # when the dispatch returns (`window_end`), so the device's time
+        # and the finish are charged to it, not added: the pop takes only
+        # what is left, and the cycle's period grows by neither.
         #
         # Each profile LANE runs this loop over its own disjoint pod
         # classes (multi-profile configs); lane 0 is the LEAD lane —
@@ -1134,11 +1137,13 @@ class Scheduler:
         # only, once per pass, never once per lane.
         lead = lane_idx == 0
         profiles = self._lane_profiles[lane_idx]
-        cycle: Optional[_Cycle] = None
+        window = min(0.05, self.config.batch_window_seconds or 0.05)
+        window_end: Optional[float] = None
         while not self._stop.is_set():
+            # only the pass right after a dispatch pops against its window
+            pop_by, window_end = window_end, None
             self._ensure_binder()
             if self.leader_elector and not self.leader_elector.is_leader():
-                cycle = self._finish_contained(cycle)
                 time.sleep(0.05)
                 continue
             if self._reconcile_needed.is_set():
@@ -1146,7 +1151,6 @@ class Scheduler:
                     # reconciliation is in flight on the lead lane: a
                     # follower lane must not dispatch over un-reconciled
                     # caches — wait for the lead to clear the flag
-                    cycle = self._finish_contained(cycle)
                     time.sleep(0.01)
                     continue
                 # first pass after start or (re)acquired leadership:
@@ -1159,11 +1163,11 @@ class Scheduler:
                         "leadership reconcile failed; continuing"
                     )
             try:
-                # with a solve in flight, the pop is the OVERLAP window —
-                # bound it by the accumulation window so staging of the
-                # deferred group never waits the full idle timeout
-                timeout = 0.2 if cycle is None else min(
-                    0.05, self.config.batch_window_seconds or 0.05
+                # bounded by what is left of the window, so the adaptive
+                # controller's wider answers (up to 0.25 s) never stretch
+                # a busy lane's period; an idle lane polls at 0.2 s
+                timeout = 0.2 if pop_by is None else max(
+                    0.0, pop_by - self._clock()
                 )
                 batch, t_pop = self._pop(timeout, profiles)
             except Exception:  # noqa: BLE001
@@ -1180,11 +1184,13 @@ class Scheduler:
                     self.queue.requeue_backoff(info)
                 batch = []
             try:
-                if cycle is not None:
-                    self._finish_cycle(cycle)
-                    cycle = None
                 if batch:
                     cycle = self._dispatch_batch(batch, t_pop)
+                    window_end = self._clock() + window
+                    self._finish_cycle(cycle)
+                    # a speculative cycle bookmarks the mirror's device
+                    # tensors: keep them no longer than the cycle itself
+                    del cycle
             except Exception:  # noqa: BLE001 — per-cycle containment
                 # the reference contains per-cycle errors (ScheduleOne
                 # logs and returns; the wait.Until loop re-enters) — one
@@ -1193,7 +1199,6 @@ class Scheduler:
                 # dead cycle never dispositioned go back to the queue
                 # instead of stranding in the 'inflight' tier.
                 self._salvage_cycle(self._inflight_get())
-                cycle = None
                 logging.getLogger(__name__).exception(
                     "schedule_batch cycle failed; continuing"
                 )
@@ -1201,7 +1206,6 @@ class Scheduler:
                 for pod in self.cache.cleanup_expired():
                     # binding never confirmed: give the pod another chance
                     self.queue.add(pod)
-        self._finish_contained(cycle)
 
     def _salvage_cycle(self, cycle: Optional["_Cycle"]) -> None:
         """A cycle died mid-flight: dispatch whatever bind-wave entries
@@ -1238,17 +1242,6 @@ class Scheduler:
             self._mark_failed(info, _trace.FAIL_SALVAGED)
             self.queue.requeue_backoff(info)
 
-    def _finish_contained(self, cycle: Optional["_Cycle"]) -> Optional["_Cycle"]:
-        if cycle is not None:
-            try:
-                self._finish_cycle(cycle)
-            except Exception:  # noqa: BLE001
-                self._salvage_cycle(self._inflight_get())
-                logging.getLogger(__name__).exception(
-                    "deferred cycle finalize failed"
-                )
-        return None
-
     # -- the batched scheduling cycle -------------------------------------
 
     def schedule_batch(self, timeout: Optional[float] = None) -> Dict[str, int]:
@@ -1261,10 +1254,8 @@ class Scheduler:
         splits that pod back to requeue (metrics record it as an error).
         Callers that need the binds durable call flush_binds().
 
-        The hot loop (_run) uses the same _dispatch_batch/_finish_cycle
-        halves but defers the finalize across the next pop window — this
-        entry point finishes the cycle in place so direct callers (tests,
-        single-step drivers) keep strict pop->solve->stage semantics."""
+        The hot loop (_run) runs the same _dispatch_batch/_finish_cycle
+        halves, back to back as here: strict pop->solve->stage."""
         batch, t_pop = self._pop(timeout)
         if not batch:
             return {"popped": 0, "scheduled": 0, "unschedulable": 0,
@@ -1302,8 +1293,8 @@ class Scheduler:
         runs its FULL cycle (solve -> assume -> bind) before the next
         group solves — assume lands the placements in the shared state,
         so a later profile's snapshot sees them; only the LAST group's
-        decode+staging is left pending for _finish_cycle (the readback
-        the hot loop overlaps with the next pop window)."""
+        decode+staging is left pending for _finish_cycle, which every
+        caller runs next."""
         stats = {"popped": len(batch), "scheduled": 0, "unschedulable": 0,
                  "bind_errors": 0}
         if not self._speculation_enabled:
@@ -1521,8 +1512,9 @@ class Scheduler:
         )
         # one device dispatch solved len(group) pods.  batch_solve
         # observes the EXPOSED solve cost — encode + compile + the decode
-        # wait the host actually blocked on; readback hidden behind the
-        # pop window shows up in decode_overlap instead.  The
+        # wait the host actually blocked on (in the loop: the device's
+        # whole solve and the readback); what a caller hid behind work of
+        # its own before decoding shows up in decode_overlap instead.  The
         # reference-named per-pod algorithm metric gets the per-pod share
         # so harness percentiles stay comparable with the reference's
         # per-ScheduleOne numbers.
@@ -1581,7 +1573,7 @@ class Scheduler:
             self._stage_group(fwk, group, names, reasons, cycle)
 
     def _finish_cycle(self, cycle: "_Cycle") -> Dict[str, int]:
-        """The staging half: decode any deferred group, hand the bind
+        """The staging half: decode the cycle's last group, hand the bind
         wave to the binding stage, run PostFilter, emit trace/metrics."""
         if cycle.pending is not None:
             pending, cycle.pending = cycle.pending, None

@@ -1,5 +1,6 @@
-"""Solve-side pipeline: wavefront routing, deferred readback through the
-hot loop, the prewarm pool, and the new solve metrics."""
+"""Solve-side pipeline: wavefront routing, the coalesced lazy readback
+(`DeviceSolve`), the hot loop end to end, the prewarm pool, and the solve
+metrics."""
 
 import time
 
@@ -114,8 +115,9 @@ def test_gang_retry_reuses_full_batch_bucket():
 
 
 def test_hot_loop_pipeline_end_to_end():
-    """The deferred-readback hot loop: pods created through the store
-    bind correctly, and the overlap metric records the hidden readback."""
+    """The hot loop finishes each cycle in place: pods created through
+    the store bind correctly, and the overlap metric, one reading a
+    solved group, holds about nothing — no decode waits out a pop."""
     store = st.Store()
     sched = Scheduler(store, batch_size=256)
     for nd in mk_nodes(8):
@@ -138,8 +140,10 @@ def test_hot_loop_pipeline_end_to_end():
             time.sleep(0.05)
         assert bound == 80
         assert sched.flush_binds(timeout=10)
-        assert sched.metrics.decode_overlap.n >= 1
-        assert sched.metrics.batch_solve_duration.n >= 1
+        overlap = sched.metrics.decode_overlap
+        assert overlap.n == sched.metrics.batch_solve_duration.n >= 1
+        # a decode left behind a pop would read that pop's window (50 ms)
+        assert overlap.total / overlap.n < 0.025
         # 80 pods routed wavefront -> wave metrics observed
         assert sched.metrics.solve_wave_count.n >= 1
     finally:
