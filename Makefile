@@ -5,7 +5,7 @@
 
 PY ?= python
 
-.PHONY: test chaos chaos-restart chaos-serving bench lint lint-shapes \
+.PHONY: test chaos chaos-restart chaos-serving audit lint lint-shapes \
 	lint-coherence lint-obligations multichip race native-ext test-journal \
 	proto rehearse
 
@@ -101,12 +101,18 @@ multichip:
 	XLA_FLAGS="--xla_force_host_platform_device_count=8" JAX_PLATFORMS=cpu \
 		$(PY) -m pytest tests/ -q -m multichip -p no:cacheprovider
 
-# the BENCH_STRICT gates on the CPU platform: parity, recompile and
-# leak COUNTS are valid from any platform; its times and rates are not
-# device figures (the chip is reached with `python chip_smoke.py` through
-# the builder's chip tool — .claude/skills/verify/SKILL.md)
-bench:
-	JAX_PLATFORMS=cpu BENCH_STRICT=1 $(PY) bench.py
+# the audited count run: the served-path tests (a Scheduler over a Store)
+# with the obligation ledger, the epoch auditor and the retrace tracker
+# armed for the session.  tests/conftest.py arms them and fails the
+# session on a leak, a double discharge, a coherence violation or an
+# executable traced twice; tests/test_executable_keys.py holds "nothing
+# built after warm-up".  Counts only: valid from any platform.
+audit:
+	GRAFTLINT_OBLIGATIONS=1 GRAFTLINT_COHERENCE=1 GRAFTLINT_SHAPES=1 \
+		JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_scheduler_loop.py \
+		tests/test_finish_at_ready.py tests/test_executable_keys.py \
+		tests/test_metrics_sources.py -q -m 'not slow and not chaos' \
+		-p no:cacheprovider
 
 # regenerate the committed protobuf gencode after editing the contract
 # (nothing regenerates it at import time)

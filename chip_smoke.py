@@ -15,10 +15,9 @@ CPU-only sandbox and says in its output that it is not a chip result.
            bursts from perf/config/ -> wavefront; bulk + gang burst ->
            auction); flush, stop, close, recover a fresh Store from the
            journal.
-  stage 2  the paper's size: BASELINE.json config 5 (bench.config5's
-           generator) — 50,000 nodes / 10,000 pods in 100 gangs, solved
-           on the device at the 65,536 x 16,384 buckets; peak device
-           bytes printed.
+  stage 2  the paper's size: BASELINE.json config 5 — 50,000 nodes /
+           10,000 pods in 100 gangs, solved on the device at the
+           65,536 x 16,384 buckets; peak device bytes printed.
   stage 3  kernel census at the 8,192-row node bucket: the batched
            PostFilter dry-run (high-priority pods into a full pool), a
            slice carve-out (a shaped gang on labelled slice nodes) and
@@ -613,8 +612,7 @@ def stage2(smoke: Smoke) -> None:
     z = smoke.z
     t0 = time.perf_counter()
     smoke.phase = "stage2"
-    # bench.config5's generator (bench._mk_nodes + its `mk` closure),
-    # restated because bench.py keeps it local to the cell
+    # BASELINE.json config 5's generator
     nodes = [
         make_node(f"node-{i}")
         .capacity(cpu_milli=32000, mem=64 * GI, pods=110)

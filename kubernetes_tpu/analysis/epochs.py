@@ -40,11 +40,12 @@ Usage (scoped, mirroring analysis/retrace.py)::
 
 Under pytest, set ``GRAFTLINT_COHERENCE=1`` to arm the auditor for the
 whole session (tests/conftest.py wires the fixture, exactly like
-GRAFTLINT_LOCK_ORDER / GRAFTLINT_SHAPES); bench.py arms it per run and
-``BENCH_STRICT=1`` fails on any violation.  The scheduler mirrors
-:func:`audits_total` / :func:`violations_total` into the
-``scheduler_coherence_audits_total`` /
-``scheduler_coherence_violations_total`` gauges each cycle.
+GRAFTLINT_LOCK_ORDER / GRAFTLINT_SHAPES; ``make audit`` arms it over
+the served-path tests) and the session fails on any violation.  The
+scheduler's ``scheduler_coherence_audits_total`` /
+``scheduler_coherence_violations_total`` gauges are bound to
+:func:`audits_total` / :func:`violations_total` and read them when they
+are read.
 
 This module is import-light (no JAX): stamps are plain ints/tuples and
 the hooks never touch device array contents.

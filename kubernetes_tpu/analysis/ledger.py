@@ -60,13 +60,14 @@ Usage (scoped, mirroring analysis/epochs.py)::
 
 Under pytest, set ``GRAFTLINT_OBLIGATIONS=1`` to arm the ledger for
 the whole session (tests/conftest.py wires the fixture, exactly like
-GRAFTLINT_COHERENCE); bench.py arms it per run and ``BENCH_STRICT=1``
-fails on any leak or double-discharge.  The scheduler mirrors
-:func:`tracked_total` / :func:`leaks_total` /
-:func:`double_discharge_total` into the
+GRAFTLINT_COHERENCE; ``make audit`` arms all three over the
+served-path tests) and the session fails on any leak or
+double-discharge.  The scheduler's
 ``scheduler_obligations_tracked_total`` /
 ``scheduler_obligation_leaks_total`` /
-``scheduler_obligation_double_discharge_total`` gauges each cycle.
+``scheduler_obligation_double_discharge_total`` gauges are bound to
+:func:`tracked_total` / :func:`leaks_total` /
+:func:`double_discharge_total` and read them when they are read.
 
 This module is import-light (stdlib only): hooks cost one module-global
 None check when disarmed.

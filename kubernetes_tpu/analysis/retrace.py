@@ -12,8 +12,8 @@ static pass cannot:
     called :func:`mark_steady`)?  A steady-state trace means a kernel
     argument escaped the pad-bucket lattice and ate a 10-40 s XLA
     compile on the hot path — the exact failure mode the pad buckets
-    (utils.vocab.pad_dim) exist to prevent.  bench.py gates on this
-    under ``BENCH_STRICT=1``.
+    (utils.vocab.pad_dim) exist to prevent.
+    tests/test_metrics_sources.py holds the served loop to it.
 
 The solver jit wrappers (ops/assign.py ``greedy_assign_jit`` /
 ``wavefront_assign_jit``, ops/auction.py ``auction_assign_jit``) call

@@ -1333,7 +1333,7 @@ def _check_mesh_kernels(byclass, findings: List[Finding]) -> None:
     the counted single-chip fallback, not a compile surface).
 
     The mesh uses the largest power-of-two device count available
-    (capped at 8): under the forced-host-platform test/bench
+    (capped at 8): under the forced-host-platform test
     environment that is a real 8-way mesh; a bare 1-device run still
     exercises the shard_map signatures."""
     import jax

@@ -676,8 +676,8 @@ class APIServerReplicaSet:
     death would (client watch streams see dropped sockets and fail over
     to another replica); ``restart()`` brings a fresh instance up on a
     new port.  The scheduler feeds ``note_scheduler`` each cycle via the
-    ``store.serving_plane`` weakref and mirrors ``serving_stats()`` into
-    its Registry."""
+    ``store.serving_plane`` weakref; its Registry's serving gauges read
+    ``serving_stats()`` when they are read."""
 
     GUARDED_FIELDS = {
         "_servers": "_lock",
@@ -720,7 +720,7 @@ class APIServerReplicaSet:
         self._servers: List[Optional[APIServer]] = [
             self._spawn() for _ in range(replicas)
         ]
-        # the scheduler's per-cycle mirror hook (weak: the replica set's
+        # how the scheduler finds the plane (weak: the replica set's
         # lifetime belongs to whoever built it, not to the store)
         store.serving_plane = weakref.ref(self)
 
@@ -784,7 +784,7 @@ class APIServerReplicaSet:
         )
 
     def serving_stats(self) -> dict:
-        """The four serving-plane gauges the scheduler mirrors
+        """The four serving-plane gauges the scheduler exposes
         (Registry names scheduler_apf_* / scheduler_server_* /
         scheduler_replica_*).  Stall counts are cumulative across killed
         instances."""

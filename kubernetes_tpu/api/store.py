@@ -1046,8 +1046,8 @@ class Store:
     replaying that shard's whole journal; ``update_wave`` records
     replay atomically per shard.  Recovery observability:
     ``recovery_duration_ms`` / ``snapshot_records`` /
-    ``journal_suffix_records`` (summed across shards), mirrored into
-    the scheduler Registry."""
+    ``journal_suffix_records`` (summed across shards), which the
+    scheduler Registry's gauges read."""
 
     # graftlint guarded-by declarations: the rv counter, the global
     # event ring, the watcher registry and its counters all share the
@@ -1120,8 +1120,8 @@ class Store:
         # never performs them, so churn benches assert this stays 0
         self.watchers_terminated = 0
         self.terminated_by_kind: Dict[str, int] = {}    # bounded: one key/kind
-        # overload-protection observability (mirrored into the scheduler
-        # Registry as scheduler_watch_* each cycle):
+        # overload-protection observability (what the scheduler
+        # Registry's scheduler_watch_* gauges read):
         #   expired — watchers converted to bookmark+relist after their
         #       coalescing buffer overflowed (or a replay overflowed);
         #   coalesced (closed) — compacted-event counts folded in from
@@ -1133,8 +1133,8 @@ class Store:
         # no longer matched the Lease (a deposed leader's late wave)
         self.fenced_writes_total = 0
         # batched fan-out accounting: chunks handed to watchers and the
-        # events they carried (mean = fanout chunk size — mirrored into
-        # the Registry's scheduler_fanout_chunk_size)
+        # events they carried (mean = fanout chunk size — what the
+        # Registry's scheduler_fanout_chunk_size reads)
         self.fanout_chunks = 0
         self.fanout_chunk_events = 0
         # optional api.admission.AdmissionChain: mutate-then-validate on
@@ -2027,9 +2027,9 @@ class Store:
     def watch_stats(self) -> Dict[str, int]:
         """Fan-out observability snapshot: deepest per-watcher pending
         backlog, fan-out dispatch backlog, total compacted events,
-        expiries, and (legacy) destructive terminations — mirrored into
-        the scheduler Registry as scheduler_watch_* gauges every
-        cycle."""
+        expiries, and (legacy) destructive terminations — what the
+        scheduler Registry's scheduler_watch_* gauges read, on the
+        reader's thread (it takes the watchers' locks)."""
         dispatch_depth = self.dispatch_depth()
         with self._rv_lock:
             depth = 0

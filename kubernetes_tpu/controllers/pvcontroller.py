@@ -31,8 +31,19 @@ class PersistentVolumeController(Controller):
         )
 
     def _on_pv(self, typ: str, pv, old) -> None:
-        if typ != st.DELETED:
-            self.queue.add(f"pv||{pv.meta.name}")
+        if typ == st.DELETED:
+            return
+        self.queue.add(f"pv||{pv.meta.name}")
+        if not pv.spec.claim_ref and pv.status.phase == api.PV_AVAILABLE:
+            # a volume a waiting claim may have been created before:
+            # nothing else would sync that claim again
+            for pvc in self.informers.informer(
+                "PersistentVolumeClaim"
+            ).list():
+                if not pvc.spec.volume_name:
+                    self.queue.add(
+                        f"pvc|{pvc.meta.namespace}|{pvc.meta.name}"
+                    )
 
     def _on_pvc(self, typ: str, pvc, old) -> None:
         if typ == st.DELETED:
@@ -106,10 +117,14 @@ class PersistentVolumeController(Controller):
     def _match(self, pvc, claim_key: str) -> Optional[api.PersistentVolume]:
         """findMatchingVolume: smallest Available PV satisfying class,
         modes, and size (or one already claimRef'd to this PVC — the
-        half-bound repair)."""
+        half-bound repair).  Read from the STORE, not the volume
+        informer: the claim's event can overtake the events of volumes
+        created before it, and a match over the volumes the informer
+        has seen so far binds a larger volume than the smallest, for
+        good."""
         want_modes = set(pvc.spec.access_modes)
         best = None
-        for pv in self.informers.informer("PersistentVolume").list():
+        for pv in self.store.list("PersistentVolume")[0]:
             if pv.spec.claim_ref == claim_key:
                 return pv  # finish the half-bound pair
             if pv.spec.claim_ref or pv.status.phase != api.PV_AVAILABLE:

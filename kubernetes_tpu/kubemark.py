@@ -16,13 +16,13 @@ Two layers:
                  instead of O(batch) single-object writes, and the tick
                  is jittered so a 100k-node fleet doesn't monopolize the
                  Node shard in phase-locked bursts.
-  NodeGroupScaler  the autoscaler-in-the-loop half (bench
-                 ``c12_autoscale_churn``): a named node group scaled
+  NodeGroupScaler  the autoscaler-in-the-loop half (chip_smoke.py
+                 stage 3): a named node group scaled
                  up/down through the API (or replayed as a frozen
                  trace), with a cluster-autoscaler-shaped reconcile
                  policy — the sustained node add/remove stream the
                  elastic node axis exists to absorb.
-  FleetHarness   the first-class fleet driver (bench ``c8_store_100k``):
+  FleetHarness   the first-class fleet driver (tests/test_kubemark.py):
                  registers up to 100k hollow nodes, runs a SUSTAINED
                  pod-lifecycle soak (create → bind via per-shard
                  update_wave sub-waves committed concurrently → hollow
@@ -32,8 +32,8 @@ Two layers:
                  lost/double-bound counts.
 
 This drives the FULL store/watch/journal path at fleet scale — the
-thing the solver bench can't see (VERDICT missing #10; ROADMAP's
-"heavy traffic from millions of users" axis)."""
+thing a solver-only run can't see (ROADMAP's "heavy traffic from
+millions of users" axis)."""
 
 from __future__ import annotations
 
@@ -215,7 +215,7 @@ class NodeGroupScaler:
     frozen-trace harness can replay the exact churn against a solver
     pair; with a Store attached the membership changes also commit
     through the API (create/delete → informers → scheduler cache), the
-    live-loop shape bench c12 drives.
+    live-loop shape chip_smoke.py stage 3 drives.
 
     `reconcile` is the bundled scale policy (the CA loop's core):
     scale UP by ceil(pending / pods_per_node) when pods are pending,
@@ -247,7 +247,7 @@ class NodeGroupScaler:
         self._size = 0
         self._next_id = 0
         self._members: List[str] = []  # creation order; drain from the tail
-        # observability (bench c12 reports them)
+        # observability
         self.scale_ups = 0
         self.scale_downs = 0
         self.nodes_added = 0
@@ -549,170 +549,3 @@ class FleetHarness:
             "heartbeat_waves": self.hollow.heartbeat_waves,
             "heartbeats": self.hollow.heartbeats,
         }
-
-    # -- the serving-plane phase (bench c13_serving_fleet) ----------------
-
-    def serve(
-        self,
-        replicas: int = 2,
-        informers: int = 1000,
-        soak_pods: int = 2048,
-        round_pods: int = 512,
-        sample: int = 32,
-        cpu_milli: int = 50,
-        kill_replica: bool = True,
-        sync_timeout: float = 60.0,
-        round_timeout: float = 60.0,
-        recovery_budget_s: float = 30.0,
-    ) -> Dict[str, object]:
-        """Fleet-scale serving soak: `informers` multiplexed HTTP watch
-        streams over a `replicas`-wide :class:`APIServerReplicaSet`,
-        pods created THROUGH the HTTP path (round-robin across
-        replicas) and bound via the store's wave path, with a mid-soak
-        replica kill + restart.  Reports p99 watch-delivery latency
-        (create-call → event delivery on the sampled informers),
-        failover/recovery health (no wedged watcher, recovery within
-        budget) and the serving-plane gauges.
-
-        The latency sample covers the first `sample` informers: the
-        mux delivers every event to every informer, but recording
-        per-event timestamps across thousands of caches would measure
-        the recorder, not the plane."""
-        assert self.audit is not None, "start() the harness first"
-        from .api.server import APIServerReplicaSet
-        from .client.rest import RestClient
-        from .client.watchmux import HttpWatchMux
-
-        plane = APIServerReplicaSet(self.store, replicas=replicas)
-        commit_at: Dict[str, float] = {}
-        latencies: List[float] = []
-
-        def observer(typ, obj, rv, recv_ts):
-            if typ != "ADDED":
-                return
-            t0 = commit_at.get(f"{obj.meta.namespace}/{obj.meta.name}")
-            if t0 is not None:
-                latencies.append(recv_ts - t0)
-
-        mux = HttpWatchMux(plane.urls())
-        infs = [
-            mux.add_informer(
-                "Pod", on_event=observer if i < sample else None
-            )
-            for i in range(informers)
-        ]
-        mux.start()
-        recovery_ms: Optional[float] = None
-        wedged = 0
-        created = 0
-        rounds = 0
-        try:
-            deadline = time.monotonic() + sync_timeout
-            while time.monotonic() < deadline and not all(
-                i.synced for i in infs
-            ):
-                time.sleep(0.02)
-            unsynced = sum(1 for i in infs if not i.synced)
-            clients = [RestClient(u) for u in plane.urls()]
-            kill_at = soak_pods // 2
-            watched = infs[: max(1, sample)]
-            t0 = time.perf_counter()
-            while created < soak_pods:
-                n = min(round_pods, soak_pods - created)
-                keys = []
-                for k in range(n):
-                    i = created + k
-                    ns = self.namespaces[i % len(self.namespaces)]
-                    name = f"serve-{i}"
-                    pod = (
-                        make_pod(name)
-                        .req(cpu_milli=cpu_milli, mem=8 * MI)
-                        .obj()
-                    )
-                    pod.meta.namespace = ns
-                    commit_at[f"{ns}/{name}"] = time.monotonic()
-                    clients[i % len(clients)].create(pod)
-                    keys.append((name, ns))
-                created += n
-                rounds += 1
-                self._bind_round(keys)
-                # the round's events must reach the sampled informers
-                # before the next round floods in (bounded, not exact:
-                # stragglers show up in the latency tail / lost count)
-                rdl = time.monotonic() + round_timeout
-                want = {f"{ns}/{name}" for name, ns in keys}
-                while time.monotonic() < rdl and any(
-                    not want <= set(w.cache) for w in watched
-                ):
-                    time.sleep(0.01)
-                if kill_replica and recovery_ms is None and (
-                    created >= kill_at
-                ):
-                    # mid-soak replica death: every stream on the dead
-                    # replica must fail over and converge on a marker
-                    # pod created after the kill, within budget
-                    t_kill = time.monotonic()
-                    plane.kill(0)
-                    clients = [RestClient(u) for u in plane.urls()]
-                    marker = (
-                        make_pod("serve-marker")
-                        .req(cpu_milli=cpu_milli, mem=8 * MI)
-                        .obj()
-                    )
-                    marker.meta.namespace = self.namespaces[0]
-                    mkey = f"{self.namespaces[0]}/serve-marker"
-                    commit_at[mkey] = time.monotonic()
-                    clients[0].create(marker)
-                    rdl = time.monotonic() + recovery_budget_s
-                    while time.monotonic() < rdl and any(
-                        mkey not in i.cache for i in infs
-                    ):
-                        time.sleep(0.02)
-                    wedged = sum(1 for i in infs if mkey not in i.cache)
-                    recovery_ms = (time.monotonic() - t_kill) * 1000
-                    plane.restart(0)
-                    mux.set_urls(plane.urls())
-                    clients = [RestClient(u) for u in plane.urls()]
-            wall = time.perf_counter() - t0
-            # lost = created pods a sampled informer never delivered
-            lost = sum(
-                1 for key in commit_at if key not in watched[0].cache
-            )
-            pct = percentiles(latencies)
-            report: Dict[str, object] = {
-                "replicas": replicas,
-                "informers": informers,
-                "serve_pods": created,
-                "serve_rounds": rounds,
-                "serve_wall_s": round(wall, 4),
-                "watch_events_delivered": sum(
-                    i.events_delivered for i in infs
-                ),
-                "watch_p50_ms": round(pct["p50"] * 1000, 2),
-                "watch_p90_ms": round(pct["p90"] * 1000, 2),
-                "watch_p99_ms": round(pct["p99"] * 1000, 2),
-                "rv_violations": len(mux.violations()),
-                "informer_failovers": sum(i.failovers for i in infs),
-                "informer_relists": sum(i.relists for i in infs),
-                "unsynced_informers": unsynced,
-                "recovery_ms": (
-                    round(recovery_ms, 1) if recovery_ms is not None
-                    else None
-                ),
-                "wedged_watchers": wedged,
-                "lost_watch_pods": lost,
-                "double_bound_pods": len(self.audit.double_bound()),
-            }
-            report.update(plane.serving_stats())
-            return report
-        finally:
-            mux.stop()
-            plane.stop()
-            # the serving round's pods leave the store (the soak halves
-            # share the harness; growth here would skew a later phase)
-            for key in list(commit_at):
-                ns, _, name = key.partition("/")
-                try:
-                    self.store.delete("Pod", name, ns)
-                except st.NotFound:
-                    pass

@@ -134,7 +134,7 @@ class SolveCircuitBreaker:
         self.probes = 0      # half-open device attempts
 
     def state_code(self) -> float:
-        # the metrics mirror reads this off the scheduling thread while
+        # the metrics reader is another thread (a /metrics scrape) while
         # dispatch threads transition the breaker — take the lock (the
         # unlocked read was a graftlint guarded-by finding)
         with self._lock:
@@ -789,8 +789,8 @@ class TPUBatchScheduler:
             )
         self.carveout_policy = carveout_policy
         # throughput of the most recent snapshot encode (pods/s over the
-        # build_from_state wall time) — mirrored into the Registry's
-        # scheduler_encode_rows_per_s each cycle
+        # build_from_state wall time) — what the Registry's
+        # scheduler_encode_rows_per_s reads
         self.last_encode_rows_per_s = 0.0
         self._greedy = assign_ops.greedy_assign_jit(score_config)
         self._wavefront = assign_ops.wavefront_assign_jit(score_config)
@@ -846,8 +846,8 @@ class TPUBatchScheduler:
             self._mesh_size = 0
             self._put = jax.device_put
         # batches a configured mesh could not solve sharded (padded node
-        # bucket smaller than the mesh) — mirrored into
-        # scheduler_sharded_solve_fallbacks
+        # bucket smaller than the mesh) — what
+        # scheduler_sharded_solve_fallbacks reads
         self.sharded_fallbacks = 0
         self._mirror = DeviceClusterMirror(self.state, mesh=mesh)
         self.use_mirror = use_mirror
@@ -890,8 +890,8 @@ class TPUBatchScheduler:
 
     @property
     def shard_count(self) -> int:
-        """Mesh size the solver shards over (0 = single chip) —
-        mirrored into scheduler_solve_shard_count."""
+        """Mesh size the solver shards over (0 = single chip) — what
+        scheduler_solve_shard_count reads."""
         return self._mesh_size
 
     # -- incremental cluster state ---------------------------------------
@@ -1475,8 +1475,8 @@ class TPUBatchScheduler:
         ds.encode_s = sp_enc.t1 - sp_enc.t0
         # trace/compile + dispatch-enqueue wall: on a first-of-a-bucket
         # batch this IS the XLA compile (jit blocks until the executable
-        # exists); steady-state it is ~0 — the split the bench uses to
-        # separate compile churn from real solve regressions
+        # exists); steady-state it is ~0 — the split that separates
+        # compile churn from real solve regressions
         ds.dispatch_s = sp_run.t1 - sp_run.t0
         ds.dispatched_at = sp_run.t1
         return ds
@@ -1667,7 +1667,7 @@ class TPUBatchScheduler:
         members of every gang interleave onto the same nodes and every
         gang comes back incomplete.  The live scheduler eventually
         self-heals through staggered backoff retries; one-shot callers
-        (proto service, extender, bench bursts) would return zero.  The
+        (proto service, extender) would return zero.  The
         fix exploits monotonicity — if the k highest-priority gangs
         don't fit, k+1 don't either — so a binary search over the
         priority-ordered gang prefix finds the maximal admissible set in

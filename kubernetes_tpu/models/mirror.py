@@ -40,9 +40,9 @@ first dirty row (duplicate scatter-set of identical values is a
 no-op), so the jit cache stays small and stable.
 
 `resync_total` / `delta_rows_total` / `delta_syncs` count full uploads
-and real (unbucketed) scattered rows — the scheduler mirrors them into
-`scheduler_mirror_resync_total` / `scheduler_mirror_delta_rows`, and
-bench's c7 gates on steady-state transfer being O(changed rows).
+and real (unbucketed) scattered rows — `scheduler_mirror_resync_total`
+/ `scheduler_mirror_delta_rows` read them through `stats()`;
+tests/test_mirror.py holds steady-state transfer to O(changed rows).
 """
 
 from __future__ import annotations
@@ -161,8 +161,8 @@ class DeviceClusterMirror:
         # graftcoh true positive, regression-pinned in
         # tests/test_coherence.py)
         self._inval_gen = 0
-        # transfer accounting (read by the scheduler's metric mirror and
-        # bench c7's O(changed-rows) gate); mutated under the cache lock
+        # transfer accounting (read by the scheduler's gauges through
+        # stats()); mutated under the cache lock
         # — sync() is called inside encode_pending's locked section
         self.resync_total = 0      # full uploads (first sync included)
         self.delta_rows_total = 0  # real dirty rows scattered
@@ -176,7 +176,7 @@ class DeviceClusterMirror:
         self.grow_rows_total = 0   # axis rows added without a re-upload
         # safety valve: False restores the pre-elastic behavior — every
         # shape change performs the full (RESHARDED under a mesh)
-        # re-upload; the parity oracle tests and bench c12 drive it
+        # re-upload; the parity oracle tests drive it
         self.incremental_grow = True
         # whether the resident copy is node-axis sharded (False when no
         # mesh, or when the padded bucket doesn't split across it — the

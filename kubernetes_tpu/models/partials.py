@@ -101,9 +101,9 @@ class PartialsCache:
     # repeating the first index), so each cache compiles exactly ONE
     # refresh and ONE insert executable per (cap, n, r) instead of
     # walking a delta-size bucket ladder with a ~1s XLA compile on the
-    # hot path at every first-seen bucket (a bench c6 trace-overrun
-    # finding).  A 3-row delta evaluating 256 padded rows costs ~cap*256
-    # elementwise ops — noise next to one solve.
+    # hot path at every first-seen bucket.  A 3-row delta evaluating
+    # 256 padded rows costs ~cap*256 elementwise ops — noise next to
+    # one solve.
     ROW_CHUNK = 256
     MISS_CHUNK = 8
 
@@ -131,8 +131,8 @@ class PartialsCache:
         # rollback-resurrection hazard the fence closes)
         self._epoch: Optional[epochs.EpochStamp] = None
         self._inval_gen = 0
-        # counters (mirrored into scheduler_partials_* each cycle and
-        # read by bench's hit-rate reporting); mutated under the cache
+        # counters (read by the scheduler_partials_* gauges through
+        # stats()); mutated under the cache
         # lock — sync() runs inside encode_pending's locked section
         self.hit_rows_total = 0         # [class, row] entries served warm
         self.recomputed_rows_total = 0  # node rows re-evaluated
@@ -286,7 +286,7 @@ class PartialsCache:
         count, so the label pairs every autoscaled node interns (its
         hostname, fresh zone values under unreferenced keys) do NOT
         flush warm rows — sustained node churn keeps the cache hot (the
-        elastic-node-axis contract; bench c12 gates it).  Toleration
+        elastic-node-axis contract; tests/test_elastic_axis.py).  Toleration
         re-expansions are self-keying (the expanded bitset bytes are
         part of the class key), so the taint vocab is not watermarked."""
         return self.state.builder.expansion_watermark()

@@ -2154,8 +2154,8 @@ class ClusterState:
         self._bucket = vb.pad_dim(0, lim.min_nodes)
         self._dwell = 0
         self._dwell_gen = 0
-        # compaction observability (mirrored into scheduler_compactions_
-        # total / scheduler_compaction_moved_rows each cycle)
+        # compaction observability (read by scheduler_compactions_total
+        # / scheduler_compaction_moved_rows)
         self.compactions_total = 0
         self.compaction_moved_rows_total = 0
         self.node_names: List[Optional[str]] = []
@@ -2479,7 +2479,7 @@ class ClusterState:
     @property
     def node_axis_bucket(self) -> int:
         """The pad bucket tensors() currently exposes (post-hysteresis)
-        — mirrored into scheduler_node_axis_bucket each cycle."""
+        — what scheduler_node_axis_bucket reads."""
         return min(self._bucket, self._cap)
 
     def tensors(self, pad: bool = True) -> ClusterTensors:

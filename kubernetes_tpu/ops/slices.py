@@ -321,8 +321,8 @@ def fragmentation(
 
 def fragmentation_report(cluster: ClusterTensors) -> dict:
     """Host convenience: derive the static capacities from the (host or
-    device) cluster tensors and return plain numbers — what bench c10
-    and tests read."""
+    device) cluster tensors and return plain numbers — what tests
+    read."""
     import numpy as np
 
     from ..utils.vocab import pad_dim

@@ -1,6 +1,6 @@
 """scheduler_perf runner: executes workloads against the HOST scheduler
 through the store — the full informer/cache/queue/solve/bind path, not
-the solver directly (the round-2 bench's shortcut).
+the solver directly.
 
 Reference: mustSetupCluster + runWorkload
 (test/integration/scheduler_perf/{util.go:82,scheduler_perf.go:700ish}):

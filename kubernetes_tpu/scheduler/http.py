@@ -43,8 +43,7 @@ def render_prometheus(registry: Registry) -> str:
         elif isinstance(metric, (Counter, Gauge)):
             kind = "counter" if isinstance(metric, Counter) else "gauge"
             lines.append(f"# TYPE {name} {kind}")
-            with metric._lock:
-                items = dict(metric._v)
+            items = metric.values()
             if not items:
                 lines.append(f"{name} 0")
             for labels, v in sorted(items.items()):

@@ -7,8 +7,9 @@ the scheduler_perf collectors scrape the same series the reference's do.
 from __future__ import annotations
 
 import bisect
+import logging
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 # the reference's scheduling-latency bucket layout (metrics.go:92:
 # ExponentialBuckets(0.001, 2, 15))
@@ -113,6 +114,10 @@ class Counter:
         with self._lock:
             return self._v.get(labels, 0.0)
 
+    def values(self) -> Dict[Tuple[str, ...], float]:
+        with self._lock:
+            return dict(self._v)
+
     @property
     def total(self) -> float:
         with self._lock:
@@ -120,26 +125,61 @@ class Counter:
 
 
 class Gauge:
+    """A value that is either SET (event-driven: `set()` stores it) or
+    BOUND once to its owner (`bind()`: every read asks the source).  A
+    bound source is a zero-argument callable returning a number, or a
+    `{label: number}` mapping for a labelled gauge; None means "no
+    reading" and the stored value stands.  The source runs on the
+    READER's thread and outside the gauge's lock, so it may take its
+    owner's locks; if it raises, the last good value is served and the
+    failure is logged once — a scrape never fails because an owner is
+    mid-failover."""
+
     def __init__(self, name: str):
         self.name = name
         self._v: Dict[Tuple[str, ...], float] = {}
         self._lock = threading.Lock()
+        self._source: Optional[Callable[[], object]] = None
+        self._source_failed = False
 
     def set(self, value: float, *labels: str) -> None:
         with self._lock:
             self._v[labels] = value
 
-    def get(self, *labels: str) -> float:
+    def bind(self, source: Callable[[], object]) -> None:
+        self._source = source
+
+    def values(self) -> Dict[Tuple[str, ...], float]:
+        """Every label tuple's current value, the source asked first."""
+        fresh: Dict[Tuple[str, ...], float] = {}
+        source = self._source
+        if source is not None:
+            try:
+                got = source()
+                if isinstance(got, dict):
+                    fresh = {(str(k),): float(v) for k, v in got.items()}
+                elif got is not None:
+                    fresh = {(): float(got)}
+            except Exception:  # noqa: BLE001 — a read must not fail
+                if not self._source_failed:
+                    self._source_failed = True
+                    logging.getLogger(__name__).exception(
+                        "source of gauge %s failed; serving the last "
+                        "good value", self.name,
+                    )
         with self._lock:
-            return self._v.get(labels, 0.0)
+            self._v.update(fresh)
+            return dict(self._v)
+
+    def get(self, *labels: str) -> float:
+        return self.values().get(labels, 0.0)
 
     @property
     def total(self) -> float:
         """Sum over every label tuple — equal to the bare value for
         unlabeled gauges; the cross-tier total for labeled ones (what
         the perf collectors report for pending_pods)."""
-        with self._lock:
-            return sum(self._v.values())
+        return sum(self.values().values())
 
 
 class Registry:
@@ -236,7 +276,7 @@ class Registry:
         # circuit-breaker state: 0 closed, 1 half-open, 2 open
         self.solve_breaker_state = Gauge("scheduler_solve_breaker_state")
         # running total of batches solved on the host fallback path
-        # (mirrored from the breaker each cycle — monotonic)
+        # (the breaker's own count — monotonic)
         self.solve_fallback_total = Gauge("scheduler_solve_fallback_total")
         # binding-worker restarts by the watchdog (binder supervision)
         self.binder_restarts = Counter("scheduler_binder_restarts_total")
@@ -244,14 +284,14 @@ class Registry:
         self.binder_poison_waves = Counter(
             "scheduler_binder_poison_waves_total"
         )
-        # corrupt journal records replay survived (mirrored from the
+        # corrupt journal records replay survived (read from the
         # store: skipped mid-file lines + truncated torn tails)
         self.journal_recovered_records = Gauge(
             "scheduler_journal_recovered_records"
         )
         # -- crash-restart recovery surface (docs/robustness.md) ----------
         # wall time the store's last recovery took (snapshot load +
-        # journal suffix replay), mirrored from the store
+        # journal suffix replay), read from the store
         self.store_recovery_duration_ms = Gauge(
             "scheduler_store_recovery_duration_ms"
         )
@@ -280,20 +320,20 @@ class Registry:
         )
         # XLA traces of the solver executables observed by the
         # recompile-discipline runtime tracker (analysis/retrace.py),
-        # mirrored each cycle when the tracker is armed (bench runs,
-        # GRAFTLINT_SHAPES=1 test sessions); steady-state increments
+        # 0 unless the tracker is armed (GRAFTLINT_SHAPES=1 test
+        # sessions, make audit); steady-state increments
         # mean a kernel argument escaped the pad-bucket lattice
         self.solve_retrace_total = Gauge("scheduler_solve_retrace_total")
         # -- sharded-solve surface (docs/scheduler_loop.md mesh mode) ------
         # mesh size the solver shards the node axis over (0 single-chip)
         self.solve_shard_count = Gauge("scheduler_solve_shard_count")
         # full mirror re-uploads (struct-generation changes, shape
-        # changes, over-fraction deltas) — mirrored from
+        # changes, over-fraction deltas) — read from
         # DeviceClusterMirror; steady state should not move
         self.mirror_resync_total = Gauge("scheduler_mirror_resync_total")
         # real dirty rows scattered by mirror delta syncs (running
         # total) — per-batch host→device transfer is O(this delta), not
-        # O(N); bench c7 gates on it
+        # O(N)
         self.mirror_delta_rows = Gauge("scheduler_mirror_delta_rows")
         # batches a configured mesh could not solve sharded (padded node
         # bucket smaller than the mesh) and routed single-chip instead
@@ -307,7 +347,7 @@ class Registry:
         self.mirror_grow_total = Gauge("scheduler_mirror_grow_total")
         # node-axis rows added by in-place grows (running total): the
         # bucket-crossing transfer is O(this delta + dirty rows), not
-        # O(N) — bench c12 gates on it
+        # O(N)
         self.mirror_grow_rows = Gauge("scheduler_mirror_grow_rows")
         # the pad bucket ClusterState currently exposes (post-hysteresis:
         # rises eagerly, falls only after bucketShrinkDwell generations)
@@ -321,8 +361,8 @@ class Registry:
         )
         # -- incremental-solve surface (docs/scheduler_loop.md) ------------
         # [class, node-row] partials entries served from the resident
-        # cache instead of re-evaluated (running total, mirrored from
-        # the PartialsCache each cycle)
+        # cache instead of re-evaluated (running total, summed over
+        # every profile's PartialsCache)
         self.partials_hit_rows = Gauge("scheduler_partials_hit_rows")
         # node rows re-evaluated by the warm path: dirty-row refreshes
         # plus full rows for first-seen classes — per-batch recompute is
@@ -341,19 +381,19 @@ class Registry:
         self.partials_rollbacks = Gauge(
             "scheduler_partials_rollbacks_total"
         )
-        # graftcoh runtime epoch auditor (analysis/epochs.py), mirrored
-        # each cycle when GRAFTLINT_COHERENCE=1 arms it (0 disarmed):
+        # graftcoh runtime epoch auditor (analysis/epochs.py), 0 unless
+        # GRAFTLINT_COHERENCE=1 arms it:
         # consume-time resident-epoch audits performed and violations
-        # recorded — chaos and BENCH_STRICT runs gate violations == 0
+        # recorded — chaos and audit runs gate violations == 0
         # with audits > 0
         self.coherence_audits = Gauge("scheduler_coherence_audits_total")
         self.coherence_violations = Gauge(
             "scheduler_coherence_violations_total"
         )
         # graftobl runtime exactly-once ledger (analysis/ledger.py),
-        # mirrored each cycle when GRAFTLINT_OBLIGATIONS=1 arms it (all
-        # 0 disarmed): obligations tracked, leaked past discharge, and
-        # double-discharged — chaos and BENCH_STRICT runs gate leaks ==
+        # all 0 unless GRAFTLINT_OBLIGATIONS=1 arms it: obligations
+        # tracked, leaked past discharge, and
+        # double-discharged — chaos and audit runs gate leaks ==
         # double-discharges == 0
         self.obligations_tracked = Gauge(
             "scheduler_obligations_tracked_total"
@@ -363,16 +403,16 @@ class Registry:
             "scheduler_obligation_double_discharge_total"
         )
         # -- overload-protection surface (docs/robustness.md) -------------
-        # deepest per-watcher coalescing backlog at the last cycle mirror
+        # deepest per-watcher coalescing backlog (Store.watch_stats)
         self.watch_queue_depth = Gauge("scheduler_watch_queue_depth")
         # events compacted away by per-watcher coalescing (latest-wins
-        # MODIFIED runs + ADDED/DELETED annihilation), store mirror
+        # MODIFIED runs + ADDED/DELETED annihilation), Store.watch_stats
         self.watch_coalesced_total = Gauge("scheduler_watch_coalesced_total")
         # watchers expired (bookmark rv + forced relist) after their
         # coalescing buffer overflowed — the survivable-overload path
         self.watch_expired_total = Gauge("scheduler_watch_expired_total")
         # legacy destructive slow-watcher kills, labeled per kind; the
-        # backpressured fan-out never performs them (benches assert 0)
+        # backpressured fan-out never performs them (tests assert 0)
         self.watch_terminated_total = Gauge("scheduler_watch_terminated_total")
         # the adaptive accumulation window currently in force
         self.batch_window_ms = Gauge("scheduler_batch_window_ms")
@@ -455,20 +495,22 @@ class Registry:
         # the sustained-rate budget)
         self.encode_rows_per_s = Gauge("scheduler_encode_rows_per_s")
         # running bytes of framed journal writes (one serialization +
-        # one crc + one write/fsync per commit sub-wave), store mirror
+        # one crc + one write/fsync per commit sub-wave), read from the
+        # store
         self.journal_frame_bytes = Gauge("scheduler_journal_frame_bytes")
         # mean events per watch fan-out chunk (batched per-watcher
-        # hand-off under one publish-lock hold), store mirror
+        # hand-off under one publish-lock hold), read from the store
         self.fanout_chunk_size = Gauge("scheduler_fanout_chunk_size")
         # the c6s ramp hunt's capacity knee: highest arrival rate whose
-        # backlog stayed bounded (0 until a ramp-mode bench run sets it)
+        # backlog stayed bounded.  Kept for the series' name: nothing in
+        # the tree sets it since the CPU bench went (ROADMAP D4)
         self.c6s_arrival_knee = Gauge(
             "scheduler_c6s_arrival_knee_pods_per_s"
         )
         # -- serving plane (docs/robustness.md serving-plane section) ------
         # effective APF seats across all priority levels (shrinks under
-        # adaptive pressure, recovers with hysteresis) — mirrored from
-        # the replica set's shared gate each cycle
+        # adaptive pressure, recovers with hysteresis) — read from the
+        # replica set's shared gate (APIServerReplicaSet.serving_stats)
         self.apf_seats_current = Gauge("scheduler_apf_seats_current")
         # requests shed by APF across all levels (429 + Retry-After)
         self.apf_rejected_total = Gauge("scheduler_apf_rejected_total")

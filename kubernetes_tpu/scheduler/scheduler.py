@@ -75,7 +75,7 @@ class OverloadController:
     they shed — counting the pass made one expensive preemption round
     trip the ladder to level 2, which deferred preemption, which left
     no cycles to decay the average: preemption froze exactly when the
-    backlog needed it (the self-inhibition bench c9 exposed).  A cycle
+    backlog needed it (self-inhibition).  A cycle
     that had to BUILD OR LOAD AN EXECUTABLE is not fed at all
     (utils/compileclock): a first-of-a-bucket cycle blocks for seconds
     in trace + XLA compile — or the persistent cache's load — and says
@@ -276,6 +276,7 @@ class Scheduler:
             window_ctl=self.window_ctl,
         )
         self.metrics = Registry()
+        self._bind_gauges()
         # pods parked at Permit (waiting_pods_map.go); coscheduling-style
         # plugins Allow/Reject through this map
         self.waiting = WaitingPodsMap()
@@ -442,6 +443,112 @@ class Scheduler:
         )
         self._bind_thread.start()
         self._wire_handlers()
+
+    def _bind_gauges(self) -> None:
+        """Bind each operator gauge to its owner, once.  A gauge reads
+        what its owner reports WHEN SOMEONE READS IT (/metrics, a
+        collector, a test); the scheduling loop pushes none.  Sources
+        run on the reader's thread: plain counters are read as they are,
+        locked state through its owner's locked reader.  They go through
+        `self.tpu` and `self.profiles`, so a swapped solver is followed.
+        Timing is not here: it lives in the recorder (utils/trace.py)."""
+        m, store = self.metrics, self.store
+        m.pending_pods.bind(self.queue.stats)  # by tier
+        m.overload_level.bind(self.overload.level)
+        if self.window_ctl is not None:
+            m.batch_window_ms.bind(
+                lambda: self.window_ctl.window() * 1000.0
+            )
+        # degraded mode: the device-solve breaker
+        m.solve_breaker_state.bind(lambda: self.tpu.breaker.state_code())
+        m.solve_fallback_total.bind(
+            lambda: self.tpu.breaker.fallback_count()
+        )
+        # the runtime auditors' totals (0 unless a GRAFTLINT_* armed them)
+        m.solve_retrace_total.bind(_retrace.total)
+        m.coherence_audits.bind(_epochs.audits_total)
+        m.coherence_violations.bind(_epochs.violations_total)
+        m.obligations_tracked.bind(_ledger.tracked_total)
+        m.obligation_leaks.bind(_ledger.leaks_total)
+        m.obligation_double_discharge.bind(_ledger.double_discharge_total)
+        # sharded solve, device mirror, elastic node axis
+        m.solve_shard_count.bind(lambda: self.tpu.shard_count)
+        m.sharded_solve_fallbacks.bind(lambda: self.tpu.sharded_fallbacks)
+        for gauge, key in (
+            (m.mirror_resync_total, "resync_total"),
+            (m.mirror_delta_rows, "delta_rows_total"),
+            (m.mirror_grow_total, "grow_syncs"),
+            (m.mirror_grow_rows, "grow_rows_total"),
+        ):
+            gauge.bind(lambda key=key: self.tpu._mirror.stats()[key])
+        for gauge, attr in (
+            (m.node_axis_bucket, "node_axis_bucket"),
+            (m.compactions_total, "compactions_total"),
+            (m.compaction_moved_rows, "compaction_moved_rows_total"),
+        ):
+            gauge.bind(lambda attr=attr: getattr(self.tpu.state, attr))
+        # incremental solve: summed over every profile's cache (profiles
+        # sync independently, the surface is one control plane)
+        for gauge, key in (
+            (m.partials_hit_rows, "hit_rows_total"),
+            (m.partials_recomputed_rows, "recomputed_rows_total"),
+            (m.partials_full_recomputes, "full_recomputes"),
+            (m.partials_rollbacks, "rollbacks"),
+        ):
+            gauge.bind(lambda key=key: sum(
+                fwk.tpu._partials.stats()[key]
+                for fwk in self.profiles
+                if fwk.tpu._partials is not None
+            ))
+        # the most recent snapshot build's encode rate (the max over
+        # profiles: summed would double-count the shared builder)
+        m.encode_rows_per_s.bind(lambda: max(
+            fwk.tpu.last_encode_rows_per_s for fwk in self.profiles
+        ))
+        # the store: journal framing, the last recovery's cost split,
+        # checkpoints, fenced late-leader waves, fan-out chunking
+        for gauge, attr in (
+            (m.journal_frame_bytes, "journal_frame_bytes"),
+            (m.journal_recovered_records, "journal_recovered_records"),
+            (m.store_recovery_duration_ms, "recovery_duration_ms"),
+            (m.store_snapshot_records, "snapshot_records"),
+            (m.store_journal_suffix_records, "journal_suffix_records"),
+            (m.store_checkpoints_total, "checkpoints_total"),
+            (m.store_shard_count, "shard_count"),
+            (m.fenced_writes_total, "fenced_writes_total"),
+        ):
+            gauge.bind(lambda attr=attr: getattr(store, attr))
+        m.fanout_chunk_size.bind(
+            lambda: store.fanout_chunk_events / max(store.fanout_chunks, 1)
+        )
+        # watch fan-out health (watch_stats takes the watchers' locks:
+        # on the reader's thread, not the loop's); its keys, and
+        # serving_stats' below, are the series' names less the prefix
+        for gauge in (m.watch_queue_depth, m.watch_coalesced_total,
+                      m.watch_expired_total):
+            key = gauge.name[len("scheduler_"):]
+            gauge.bind(lambda key=key: store.watch_stats()[key])
+        m.watch_terminated_total.bind(  # by kind
+            lambda: dict(store.terminated_by_kind)
+        )
+
+        # serving plane: None (no reading) until a replica set is there
+        def serving(key: str) -> Optional[float]:
+            plane = self._serving_plane()
+            return None if plane is None else plane.serving_stats()[key]
+
+        for gauge in (m.apf_seats_current, m.apf_rejected_total,
+                      m.server_watch_write_stalls_total,
+                      m.replica_failovers_total):
+            key = gauge.name[len("scheduler_"):]
+            gauge.bind(lambda key=key: serving(key))
+
+    def _serving_plane(self):
+        """The APIServerReplicaSet serving this store, or None: it
+        announces itself on the store by a weakref (possibly after this
+        scheduler was built) and its lifetime is its builder's."""
+        ref = getattr(self.store, "serving_plane", None)
+        return ref() if ref is not None else None
 
     # -- event wiring (eventhandlers.go:287) ------------------------------
 
@@ -1645,9 +1752,6 @@ class Scheduler:
                     for info in shed:
                         self.queue.retry_parked(info)
             postfilter_s = sp_post.t1 - sp_post.t0
-            qs = self.queue.stats()
-            for tier, v in qs.items():
-                self.metrics.pending_pods.set(v, tier)
         else:
             postfilter_s = 0.0
         total = tr.total
@@ -1656,179 +1760,29 @@ class Scheduler:
         # overload ladder: feed the cycle's PLACEMENT duration — the
         # PostFilter pass is excluded (see OverloadController: shedding
         # must not be driven by the work it sheds), and a cycle that
-        # compiled is no reading of load at all — publish the level,
-        # and let the adaptive window react (level 2 pins it wide)
+        # compiled is no reading of load at all — and let the adaptive
+        # window react (level 2 pins it wide)
         if compiled:
             level = self.overload.level()
         else:
             level = self.overload.note_cycle(
                 max(total - postfilter_s, 0.0)
             )
-        self.metrics.overload_level.set(float(level))
         if self.window_ctl is not None:
             self.window_ctl.set_overload(level)
-            self.metrics.batch_window_ms.set(
-                self.window_ctl.window() * 1000.0
-            )
-        # degraded-mode observability: mirror the breaker and journal
-        # recovery state into the registry every cycle (cheap gauge sets)
-        breaker = getattr(self.tpu, "breaker", None)
-        if breaker is not None:
-            self.metrics.solve_breaker_state.set(breaker.state_code())
-            self.metrics.solve_fallback_total.set(
-                float(breaker.fallback_count())
-            )
-        # solver executable traces, when the recompile-discipline
-        # runtime tracker is armed (bench / GRAFTLINT_SHAPES=1 runs)
-        self.metrics.solve_retrace_total.set(float(_retrace.total()))
-        # graftcoh resident-epoch audits, when the coherence auditor is
-        # armed (bench / GRAFTLINT_COHERENCE=1 runs; 0 disarmed)
-        self.metrics.coherence_audits.set(float(_epochs.audits_total()))
-        self.metrics.coherence_violations.set(
-            float(_epochs.violations_total())
-        )
-        # graftobl exactly-once ledger, when armed (bench /
-        # GRAFTLINT_OBLIGATIONS=1 runs; all 0 disarmed)
-        self.metrics.obligations_tracked.set(
-            float(_ledger.tracked_total())
-        )
-        self.metrics.obligation_leaks.set(float(_ledger.leaks_total()))
-        self.metrics.obligation_double_discharge.set(
-            float(_ledger.double_discharge_total())
-        )
-        # sharded-solve surface: mesh size in use, device-mirror
-        # host→device transfer accounting, and single-chip fallbacks
-        self.metrics.solve_shard_count.set(
-            float(getattr(self.tpu, "shard_count", 0))
-        )
-        self.metrics.sharded_solve_fallbacks.set(
-            float(getattr(self.tpu, "sharded_fallbacks", 0))
-        )
-        mirror = getattr(self.tpu, "_mirror", None)
-        if mirror is not None:
-            self.metrics.mirror_resync_total.set(float(mirror.resync_total))
-            self.metrics.mirror_delta_rows.set(
-                float(mirror.delta_rows_total)
-            )
-            # elastic node axis: in-place resident resizes vs re-uploads
-            self.metrics.mirror_grow_total.set(float(mirror.grow_syncs))
-            self.metrics.mirror_grow_rows.set(
-                float(mirror.grow_rows_total)
-            )
-        est = getattr(self.tpu, "state", None)
-        if est is not None:
-            self.metrics.node_axis_bucket.set(float(est.node_axis_bucket))
-            self.metrics.compactions_total.set(float(est.compactions_total))
-            self.metrics.compaction_moved_rows.set(
-                float(est.compaction_moved_rows_total)
-            )
-        # incremental-solve surface: resident-partials hit/recompute
-        # accounting across every profile's cache (summed — profiles
-        # sync independently, the surface is one control plane)
-        p_stats = [
-            fwk.tpu._partials.stats()
-            for fwk in self.profiles
-            if getattr(fwk.tpu, "_partials", None) is not None
-        ]
-        if p_stats:
-            self.metrics.partials_hit_rows.set(
-                float(sum(s["hit_rows_total"] for s in p_stats))
-            )
-            self.metrics.partials_recomputed_rows.set(
-                float(sum(s["recomputed_rows_total"] for s in p_stats))
-            )
-            self.metrics.partials_full_recomputes.set(
-                float(sum(s["full_recomputes"] for s in p_stats))
-            )
-            self.metrics.partials_rollbacks.set(
-                float(sum(s["rollbacks"] for s in p_stats))
-            )
-        # columnar host plane: encode throughput of the most recent
-        # snapshot build (summed across profiles would double-count the
-        # shared builder — the max is the live figure), framed journal
-        # bytes and mean fan-out chunk size mirrored from the store
-        enc = max(
-            (
-                getattr(fwk.tpu, "last_encode_rows_per_s", 0.0)
-                for fwk in self.profiles
-            ),
-            default=0.0,
-        )
-        if enc:
-            self.metrics.encode_rows_per_s.set(float(enc))
-        frame_bytes = getattr(self.store, "journal_frame_bytes", None)
-        if frame_bytes is not None:
-            self.metrics.journal_frame_bytes.set(float(frame_bytes))
-        chunks = getattr(self.store, "fanout_chunks", 0)
-        if chunks:
-            self.metrics.fanout_chunk_size.set(
-                float(self.store.fanout_chunk_events) / float(chunks)
-            )
-        recovered = getattr(self.store, "journal_recovered_records", None)
-        if recovered is not None:
-            self.metrics.journal_recovered_records.set(float(recovered))
-        # crash-restart recovery surface: the store's last recovery cost
-        # split, checkpoint count, and fenced late-leader waves
-        for attr, gauge in (
-            ("recovery_duration_ms", self.metrics.store_recovery_duration_ms),
-            ("snapshot_records", self.metrics.store_snapshot_records),
-            (
-                "journal_suffix_records",
-                self.metrics.store_journal_suffix_records,
-            ),
-            ("checkpoints_total", self.metrics.store_checkpoints_total),
-            ("shard_count", self.metrics.store_shard_count),
-            ("fenced_writes_total", self.metrics.fenced_writes_total),
-        ):
-            v = getattr(self.store, attr, None)
-            if v is not None:
-                gauge.set(float(v))
-        # watch fan-out health: mirror the store's backpressure counters
-        # (depth / coalesced / expired) and any legacy terminations
-        watch_stats = getattr(self.store, "watch_stats", None)
-        if watch_stats is not None:
-            ws = watch_stats()
-            self.metrics.watch_queue_depth.set(
-                float(ws["watch_queue_depth"])
-            )
-            self.metrics.watch_coalesced_total.set(
-                float(ws["watch_coalesced_total"])
-            )
-            self.metrics.watch_expired_total.set(
-                float(ws["watch_expired_total"])
-            )
-            for kind, n in dict(
-                getattr(self.store, "terminated_by_kind", {})
-            ).items():
-                self.metrics.watch_terminated_total.set(float(n), kind)
         # serving plane: feed the adaptive APF ladder (overload level +
-        # store depths) and mirror the fleet-wide serving gauges.  The
-        # store carries a weakref to the replica set (set by
-        # APIServerReplicaSet); exception-contained — serving-plane
-        # trouble must never take the scheduling loop down with it.
-        plane_ref = getattr(self.store, "serving_plane", None)
-        plane = plane_ref() if plane_ref is not None else None
+        # store depths); exception-contained — serving-plane trouble
+        # must never take the scheduling loop down with it.
+        plane = self._serving_plane()
         if plane is not None:
             try:
                 plane.note_scheduler(level, self.store)
-                sp = plane.serving_stats()
-                self.metrics.apf_seats_current.set(
-                    float(sp["apf_seats_current"])
-                )
-                self.metrics.apf_rejected_total.set(
-                    float(sp["apf_rejected_total"])
-                )
-                self.metrics.server_watch_write_stalls_total.set(
-                    float(sp["server_watch_write_stalls_total"])
-                )
-                self.metrics.replica_failovers_total.set(
-                    float(sp["replica_failovers_total"])
-                )
-            except Exception:  # noqa: BLE001 — mirror-only containment
+            except Exception:  # noqa: BLE001 — containment
                 logging.getLogger(__name__).exception(
-                    "serving-plane mirror failed"
+                    "serving-plane pressure note failed"
                 )
-        # the root span ends here, the per-cycle mirror above included
+        # the root span ends here (operator gauges are not fed from the
+        # loop: _bind_gauges)
         tr.close(a0=n_compiled, a1=level)
         self._inflight_set(None)
         return stats
@@ -2227,7 +2181,7 @@ class Scheduler:
                 log.exception("warmup: forgetting the bound clone failed")
         return self._clock() - t0
 
-    # -- test/bench convenience -------------------------------------------
+    # -- test convenience -------------------------------------------------
 
     def wait_for_idle(self, timeout: float = 30.0) -> bool:
         """True once no pending pods remain in active/backoff/inflight

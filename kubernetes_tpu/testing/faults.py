@@ -8,9 +8,7 @@ of watch streams, the list/relist path, the device solve, the binder
 commit, lease renewal)
 and each point consults the armed registry through one module-level
 indirection.  Disarmed — the production state — the check
-is a single global load and an early return, so the hot path pays
-nothing measurable (BENCH_STRICT budgets hold with the points in
-place).
+is a single global load and an early return.
 
 Schedules are bounded and seeded: a `FaultRegistry(seed=N)` draws every
 probabilistic decision from its own `random.Random(N)`, so a failing

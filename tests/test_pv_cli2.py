@@ -99,6 +99,28 @@ def test_immediate_claim_binds_smallest_fit_and_reclaims():
         mgr.stop()
 
 
+def test_claim_created_before_its_volume_binds_when_the_volume_arrives():
+    store = st.Store()
+    mgr = ControllerManager(
+        store, controllers=[PersistentVolumeController]
+    ).start()
+    try:
+        store.create(_pvc("early", size_gi=5))
+        store.create(_pv("tiny", size_gi=1))    # too small: still waiting
+        assert not _wait(
+            lambda: store.get("PersistentVolumeClaim", "early").spec.volume_name,
+            timeout=0.3,
+        )
+        store.create(_pv("late", size_gi=10))
+        assert _wait(
+            lambda: store.get("PersistentVolumeClaim", "early").spec.volume_name
+            == "late"
+        )
+        assert store.get("PersistentVolume", "late").spec.claim_ref == "default/early"
+    finally:
+        mgr.stop()
+
+
 def test_half_bound_repair_and_wfc_left_alone():
     store = st.Store()
     # crash artifact: PV claims the PVC, PVC side never written
