@@ -1911,7 +1911,13 @@ class Store:
         in the cut), and the defensive deep copies happen OUTSIDE the
         lock — committed objects are immutable, an update replaces the
         map entry — so the snapshot path no longer blocks writers for
-        the O(items) copy cost."""
+        the O(items) copy cost.
+
+        `selector` is called on the STORED objects, before any copy is
+        made, and only what it accepts is copied.  It must neither
+        mutate nor keep them.  A selector that accepts nothing therefore
+        reads the kind by reference at no copy (the event recorder's
+        resync does)."""
         if faults._registry is not None:
             # relist-storm chaos: injected list latency models a control
             # plane whose snapshot path is the contended resource

@@ -804,6 +804,9 @@ class Scheduler:
             breaker = getattr(tpu, "breaker", None)
             if breaker is not None:
                 breaker.reset()
+        # the predecessor's Events are this leader's to expire now: its
+        # recorder enters what the store holds (by reference, no copy)
+        self.events.resync()
         self.metrics.leader_reconcile_total.inc()
         if requeued:
             log.info(
