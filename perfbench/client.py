@@ -21,11 +21,13 @@ class WatchClient:
         self.bound: dict = {}        # (ns, name) -> (t, node, rv)
         self.bind_log: list = []     # (t, rv, ns, name, node) in arrival order
         self.rebound: list = []      # (key, first node, other node)
+        self.gone: dict = {}         # (ns, name) -> (t, rv): bound pods seen deleted
         self.rv_regressions = 0
         self.events = 0
         self.expired = 0
         self.relists: list = []      # (t0, t1, learned)
         self._last_rv: dict = {}     # namespace -> newest rv seen
+        self._max_rv = 0             # newest rv seen on the watch at all
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._run, name="perfbench-watch", daemon=True)
 
@@ -41,11 +43,17 @@ class WatchClient:
     # -- reads (any thread) --------------------------------------------------
 
     def n_bound(self) -> int:
+        """Pods ever seen bound, the deleted among them."""
         return len(self.bound)
 
-    def bind_log_since(self, i: int) -> list:
+    def n_live(self) -> int:
+        """Pods seen bound and not seen deleted."""
+        return len(self.bound) - len(self.gone)
+
+    def bind_log_since(self, i: int, n: int | None = None) -> list:
+        """The log from entry `i` on, or its next `n` entries."""
         with self._mu:
-            return self.bind_log[i:]
+            return self.bind_log[i:] if n is None else self.bind_log[i:i + n]
 
     # -- the watch thread ----------------------------------------------------
 
@@ -67,20 +75,29 @@ class WatchClient:
                 if w.expired:
                     self.expired += 1
                     t0 = self.clock()
-                    listed = w.relist()
+                    listed, list_rv = w.relist()
                     t1 = self.clock()
                     learned = 0
+                    # a bind learned here happened at or before the list's
+                    # rv, a deletion after the last event read: the replay
+                    # then frees no room too late and none too early
                     for (ns, name), node in listed.items():
                         if (ns, name) not in self.bound:
                             learned += 1
-                        self._learn(t1, 0, ns, name, node)
+                        self._learn(t1, list_rv, ns, name, node)
+                    for key in self.bound:
+                        if key not in listed and key not in self.gone:
+                            self.gone[key] = (t1, self._max_rv)
                     self._last_rv.clear()
                     self.relists.append((t0, t1, learned))
                 continue
-            _, ns, name, node, rv = ev
+            kind, ns, name, node, rv = ev
             self.events += 1
             if rv <= self._last_rv.get(ns, 0):
                 self.rv_regressions += 1
             self._last_rv[ns] = rv
+            self._max_rv = max(self._max_rv, rv)
             if node:
                 self._learn(self.clock(), rv, ns, name, node)
+                if kind == "DELETED":
+                    self.gone.setdefault((ns, name), (self.clock(), rv))

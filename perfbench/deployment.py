@@ -60,7 +60,16 @@ class Deployment:
             "measure": load_template(config, config["measure_pod_template"]),
         }
         assumed = config["assumed"]
-        self.namespaces = [f"team-{i}" for i in range(int(assumed["namespaces"]))]
+        # a count (team-0 ... as PR 24 made them) or the names themselves; and,
+        # where upstream's ops put each role's pods in a namespace of its own,
+        # the names by role
+        names = assumed["namespaces"]
+        self.namespaces = (
+            [f"team-{i}" for i in range(names)] if isinstance(names, int) else list(names)
+        )
+        self.role_namespaces = {
+            role: list(ns) for role, ns in (assumed.get("role_namespaces") or {}).items()
+        }
         self.store_args = dict(assumed["store"])
         self.scheduler_args = dict(assumed["scheduler"])
         self.max_fill_share = float(config["max_fill_share"])
@@ -79,11 +88,13 @@ class Deployment:
         meta.pop("generateName", None)
         return dict(t, metadata=meta)
 
-    def namespace_walk(self, seed: int, stream: int):
-        """An endless walk over the namespaces in an order drawn from the
-        seed; `stream` separates the warm replay's walk from the window's."""
+    def namespace_walk(self, seed: int, stream: int, role: str = "measure"):
+        """An endless walk over the role's namespaces (all of them, where the
+        configuration names none by role) in an order drawn from the seed;
+        `stream` separates the warm replay's walk from the window's."""
         rng = random.Random((int(seed) << 3) ^ stream)
+        names = self.role_namespaces.get(role, self.namespaces)
         while True:
-            order = list(self.namespaces)
+            order = list(names)
             rng.shuffle(order)
             yield from order

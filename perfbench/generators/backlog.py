@@ -14,6 +14,13 @@ that the seed alone decides; `creators` threads make the calls, as a
 controller's workers do.  (One caller alone gives up the interpreter at every
 journal write and waits to get it back, and so creates more slowly than the
 scheduler binds.)
+
+With `live_pods` the pods complete, as a Job's do: once more than that many
+are bound and not deleted, the same loop hands the same worker threads the
+longest-bound ones to delete (set-up's pods first: they are the oldest), a
+chunk at a time.  The population the scheduler places into then stays at
+`live_pods` however long the run.  A deletion is load, not result: throughput
+is still binds over whole waves.  Without the parameter nothing is deleted.
 """
 
 from __future__ import annotations
@@ -34,9 +41,13 @@ class Generator:
         self.rec = recorder
         self.target = int(params["backlog_pods"])
         self.chunk = int(params.get("topup_chunk", 64))
+        self.live_pods = params.get("live_pods")    # None: pods never complete
         self.primed = False          # the backlog has been full once
         self.created: list = []      # (ns, name, role, t_issued, due)
         self.depth: list = []        # (t, created - bound) samples
+        self.deleted: list = []      # (ns, name, t_acknowledged)
+        self.live: list = []         # (t, bound and not handed out for deletion, bound and not seen deleted)
+        self._doomed = 0             # pods of the client's bind log handed out for deletion
         self._walk = deployment.namespace_walk(seed, 1)
         self._window_walk = deployment.namespace_walk(seed, 2)
         self._prefix = "warm"
@@ -76,6 +87,8 @@ class Generator:
         while not self._stop.is_set():
             bound = self.client.n_bound() - self._bound0
             self.depth.append((clock(), len(self.created) - bound))
+            if self.live_pods is not None:
+                self._complete(clock())
             need = self.target - (self._issued - bound)
             if need < self.chunk:
                 self.primed = self.primed or need <= 0
@@ -83,19 +96,34 @@ class Generator:
                 continue
             while need >= self.chunk:
                 prefix, base, walk = self._prefix, self._issued, self._walk
-                self._tasks.put([
+                self._tasks.put(("create", [
                     self.dep.pod("measure", f"{prefix}-{base + i}", next(walk))
                     for i in range(self.chunk)
-                ])
+                ]))
                 self._issued += self.chunk
                 need -= self.chunk
+
+    def _complete(self, now: float) -> None:
+        """Hand out the longest-bound pods while more than `live_pods` live."""
+        live = self.client.n_bound() - self._doomed
+        while live - int(self.live_pods) >= self.chunk:
+            oldest = self.client.bind_log_since(self._doomed, self.chunk)
+            self._tasks.put(("delete", [(ns, name) for _, _, ns, name, _ in oldest]))
+            self._doomed += self.chunk
+            live -= self.chunk
+        self.live.append((now, live, self.client.n_live()))
 
     def _create(self) -> None:
         clock, system, created = self.rec.clock, self.system, self.created
         while not self._stop.is_set():
             try:
-                pods = self._tasks.get(timeout=0.05)
+                what, pods = self._tasks.get(timeout=0.05)
             except queue.Empty:
+                continue
+            if what == "delete":
+                for ns, name in pods:
+                    system.delete(ns, name)
+                    self.deleted.append((ns, name, clock()))
                 continue
             with self.rec.span("store_create", len(pods)):
                 for d in pods:

@@ -17,9 +17,10 @@ import tempfile
 import time
 
 from . import compare as compare_mod
-from . import tracered, waves
+from . import rules, tracered, waves
 from .client import WatchClient
 from .deployment import Deployment
+from .manifest import ManifestError
 from .probes import Recorder
 
 QUIET_CYCLES = 3    # whole solves since the last build before the replay counts as quiet
@@ -89,6 +90,7 @@ class Setup:
         self.toy = toy
         self.config = manifest.config(cell["config"])
         self.dep = Deployment(self.config, toy=toy)
+        rules.require_claimed(self.dep.templates)   # before any load: no rule unchecked
         self.params = traffic_params(manifest.traffic(cell["traffic"]), toy, overrides)
         self.rec = Recorder()
         self.rec.install_compile_listener()
@@ -122,8 +124,11 @@ class Setup:
 
         t = clock()
         walk = self.dep.namespace_walk(seed, 0)
+        # one walk for init and measured pods alike, unless the roles have
+        # namespaces of their own
+        init_walk = self.dep.namespace_walk(seed, 0, "init") if self.dep.role_namespaces else walk
         for i in range(self.dep.n_init_pods):
-            d = self.dep.pod("init", f"init-{i}", next(walk))
+            d = self.dep.pod("init", f"init-{i}", next(init_walk))
             self.system.create(d, "init")
             self.init_created.append((d["metadata"]["namespace"], d["metadata"]["name"], "init"))
         if not wait_for(lambda: self.client.n_bound() >= len(self.init_created), 600.0):
@@ -171,6 +176,19 @@ def warm_replay(setup: Setup, gen, seconds: float) -> dict:
     min_pods = int(p.get("replay_pods", 0))
     room = setup.dep.max_fill_share * setup.dep.capacity_pods
     backlog = float(p.get("backlog_pods", 0))
+    live_pods = p.get("live_pods")
+    if live_pods is not None:
+        # where pods complete, the population is the mix's own number and not
+        # a function of the run's length: a wave may land whole before its
+        # completions do, so it peaks a chunk and a backlog over live_pods (or
+        # at what set-up left), with another backlog pending
+        peak = max(float(live_pods) + float(p.get("topup_chunk", 64)) + backlog,
+                   float(setup.client.n_live())) + backlog
+        if peak > room:
+            raise ManifestError(
+                f"the mix holds up to {peak:.0f} pods at once, the deployment has room for "
+                f"{room:.0f} (max_fill_share x capacity_pods)"
+            )
     t0 = clock()
     n0 = setup.client.n_bound()
     gen.start()
@@ -193,7 +211,7 @@ def warm_replay(setup: Setup, gen, seconds: float) -> dict:
         bound = setup.client.n_bound()
         rate = (bound - n0) / ran if ran > 1.0 else 0.0
         # what the window, its closing wave and the drain will still add
-        if bound + backlog + rate * (seconds + 5.0) > room:
+        if live_pods is None and bound + backlog + rate * (seconds + 5.0) > room:
             why = "fill"
             break
     ran = clock() - t0
@@ -255,16 +273,32 @@ def run_cell(manifest, cell: dict, seed: int, seconds: float, trace: bool, toy: 
             )
             if not seen:
                 log("no wave began after the window closed within drain_s")
+        # the load comes off before the trace is written out: the profiler
+        # takes a minute and more over a busy 10 s slice, and a generator left
+        # running through it sends three windows' worth of pods nobody measures
+        gen.stop()
+        t_off = clock()
         if trace:
             jax.profiler.stop_trace()
-        gen.stop()
 
-        # -- the drain: every pod is followed to its bind or the deadline ----
+        # -- the drain: every pod is followed to its bind or the deadline, ----
+        # drain_s after the load came off (counted from the window's close, a
+        # slow stop_trace used the whole of it up and the run's last pods read
+        # as unbound)
         created = setup.init_created + [c[:3] for c in gen.created]
         drained = wait_for(
             lambda: client.n_bound() >= len(created),
-            max(0.0, t_close + float(p["drain_s"]) - clock()),
+            max(0.0, t_off + float(p["drain_s"]) - clock()),
         )
+        # where pods complete, every acknowledged deletion is followed to the
+        # client's watch as well, within the same deadline
+        deleted = None
+        if p.get("live_pods") is not None:
+            deleted = [d[:2] for d in gen.deleted]
+            wait_for(
+                lambda: len(client.gone) >= len(deleted),
+                max(0.0, t_off + float(p["drain_s"]) - clock()),
+            )
         t_drained = clock()
         peak = peak_device_bytes()
         system.stop()
@@ -277,7 +311,7 @@ def run_cell(manifest, cell: dict, seed: int, seconds: float, trace: bool, toy: 
         recovered = system.recover()
         solves = [c["keys"] for c in sorted(rec.cycles, key=lambda c: c.get("t_decode1", 0.0))
                   if "keys" in c]
-        verdict = compare_mod.compare(setup.dep, created, client, recovered, solves)
+        verdict = compare_mod.compare(setup.dep, created, client, recovered, solves, deleted)
         compare_s = clock() - t_cmp
 
         tr = None
@@ -295,6 +329,8 @@ def run_cell(manifest, cell: dict, seed: int, seconds: float, trace: bool, toy: 
             "replay": replay, "t_warmup_end": setup.t_warmup_end,
             "bind_log": list(client.bind_log), "bound": dict(client.bound),
             "created": list(gen.created), "depth": list(gen.depth),
+            "deleted": list(getattr(gen, "deleted", ())), "gone": dict(client.gone),
+            "live": list(getattr(gen, "live", ())),
             "due": gen.due_in_window(t_open, t_close),
             "client": {
                 "events": client.events, "expired": client.expired,

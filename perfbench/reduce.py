@@ -67,6 +67,21 @@ def lateness(rec) -> list:
 
 # -- cycles, spans, counters -------------------------------------------------------
 
+def live_range(rec, t0: float, t1: float):
+    """Where pods complete: the least, the median and the most that the
+    generator's samples between two instants read of (pods bound and not handed
+    out for deletion, pods bound and not seen deleted).  None where nothing
+    completes."""
+    inside = [s for s in rec["live"] if t0 <= s[0] < t1]
+    if not inside:
+        return None
+    return {
+        name: [min(v), percentile(v, 50), max(v)]
+        for name, v in (("not_handed_out", [s[1] for s in inside]),
+                        ("not_seen_deleted", [s[2] for s in inside]))
+    }
+
+
 def cycles(rec) -> list:
     e = edges(rec)
     if e is None:

@@ -2,16 +2,18 @@
 down without the program.
 
 It imports nothing of ``kubernetes_tpu`` and takes nothing the program made
-except its answers (the binds the client saw, and what a recovered store reads
-back).  Capacities, requests, zones and the spread rule come from the
-benchmark's own copy of the templates.
+except its answers (the binds and deletions the client saw, and what a
+recovered store reads back).  The deployment's hard rules (allocatable, a zone
+spread, a pod anti-affinity) are one file each under ``rules/``, read off the
+benchmark's own copy of the templates; none of their arithmetic is here.
 
-Two uses.  ``Ledger`` replays the binds of a run and counts every breach of a
-guarantee; the comparison that decides ``correct`` holds those counts to their
-limits.  ``System`` is a straightforward sequential scheduler with a journal
-behind the same client interface as the system under test; put in the
-program's place, whole, it has to come out correct, and with one guarantee
-broken (the control) it has to come out not correct.
+Two uses.  ``Ledger`` replays the binds and deletions of a run against the
+rules that apply and counts every breach of a guarantee; the comparison that
+decides ``correct`` holds those counts to their limits.  ``System`` is a
+straightforward sequential scheduler with a journal behind the same client
+interface as the system under test; put in the program's place, whole, it has
+to come out correct, and with one guarantee broken (the control) it has to
+come out not correct.
 """
 
 from __future__ import annotations
@@ -22,119 +24,48 @@ import os
 import threading
 import time
 
-ZONE_KEY = "topology.kubernetes.io/zone"
-
-_BIN = {"Ki": 2**10, "Mi": 2**20, "Gi": 2**30, "Ti": 2**40}
-_DEC = {"k": 10**3, "M": 10**6, "G": 10**9, "T": 10**12}
-
-
-def quantity(v, cpu: bool = False) -> int:
-    """A Kubernetes quantity as an integer: millicores for cpu, else units."""
-    s = str(v).strip()
-    if cpu:
-        return int(s[:-1]) if s.endswith("m") else int(round(float(s) * 1000))
-    for suf, mul in _BIN.items():
-        if s.endswith(suf):
-            return int(float(s[: -len(suf)]) * mul)
-    for suf, mul in _DEC.items():
-        if s.endswith(suf):
-            return int(float(s[: -len(suf)]) * mul)
-    return int(float(s))
-
-
-def pod_requests(template: dict) -> dict:
-    req = {"cpu": 0, "memory": 0, "pods": 1}
-    for c in (template.get("spec") or {}).get("containers") or []:
-        r = ((c.get("resources") or {}).get("requests")) or {}
-        req["cpu"] += quantity(r.get("cpu", 0), cpu=True)
-        req["memory"] += quantity(r.get("memory", 0))
-    return req
-
-
-def node_allocatable(node: dict) -> dict:
-    st = node.get("status") or {}
-    a = st.get("allocatable") or st.get("capacity") or {}
-    return {
-        "cpu": quantity(a.get("cpu", 0), cpu=True),
-        "memory": quantity(a.get("memory", 0)),
-        "pods": quantity(a.get("pods", 110)),
-    }
-
-
-def spread_rule(template: dict):
-    """(topology key, maxSkew, selector labels) of the template's hard spread
-    constraint, or None.  The templates here carry at most one.  As in
-    Kubernetes, the selector counts matching pods of the incoming pod's own
-    namespace only: the rule is held namespace by namespace."""
-    for c in (template.get("spec") or {}).get("topologySpreadConstraints") or []:
-        if c.get("whenUnsatisfiable", "DoNotSchedule") == "DoNotSchedule":
-            sel = ((c.get("labelSelector") or {}).get("matchLabels")) or {}
-            return c["topologyKey"], int(c.get("maxSkew", 1)), dict(sel)
-    return None
-
-
-def _matches(template: dict, selector: dict) -> bool:
-    labels = (template.get("metadata") or {}).get("labels") or {}
-    return all(labels.get(k) == v for k, v in selector.items())
+from . import rules
 
 
 class Ledger:
-    """The cluster as the configuration defines it, and a replay of binds
-    against it."""
+    """The cluster as the configuration defines it: the rules that apply to
+    its templates, and a replay of binds and deletions against them.  `held`
+    names the rules a replay drives, by when they are read ("every_bind",
+    "whole_solves"); None drives them all."""
 
     def __init__(self, nodes: list, templates: dict):
-        self.alloc = {n["metadata"]["name"]: node_allocatable(n) for n in nodes}
-        self.zone = {
-            n["metadata"]["name"]: (n["metadata"].get("labels") or {}).get(ZONE_KEY)
-            for n in nodes
-        }
-        self.req = {role: pod_requests(t) for role, t in templates.items()}
-        self.rule = None
-        for t in templates.values():
-            self.rule = self.rule or spread_rule(t)
-        # which roles' pods the spread selector counts
-        self.counted = {
-            role: self.rule is not None and _matches(t, self.rule[2])
-            for role, t in templates.items()
-        }
-        self.used = {name: {"cpu": 0, "memory": 0, "pods": 0} for name in self.alloc}
-        self.zones = sorted({z for z in self.zone.values() if z is not None})
-        self.zone_count: dict = {}      # namespace -> {zone: counted pods}
-        self.unknown_node = 0
-        self.max_skew_seen = 0
+        self.nodes = {n["metadata"]["name"] for n in nodes}
+        self.rules = rules.applicable(nodes, templates)
 
-    def counts(self, namespace: str) -> dict:
-        """The counted pods of one namespace, zone by zone (every zone of the
-        cluster is a domain, an empty one too)."""
-        c = self.zone_count.get(namespace)
-        if c is None:
-            c = self.zone_count[namespace] = dict.fromkeys(self.zones, 0)
-        return c
+    def _held(self, held):
+        return [r for r in self.rules if held is None or r.held == held]
 
-    def bind(self, role: str, node: str, namespace: str) -> None:
-        used = self.used.get(node)
-        if used is None:
-            self.unknown_node += 1
-            return
-        for k, v in self.req[role].items():
-            used[k] += v
-        if self.counted[role] and self.zone[node] is not None:
-            self.counts(namespace)[self.zone[node]] += 1
+    def bind(self, role: str, node: str, namespace: str, held=None) -> None:
+        if node in self.nodes:
+            for r in self._held(held):
+                r.bind(role, node, namespace)
+
+    def unbind(self, role: str, node: str, namespace: str, held=None) -> None:
+        if node in self.nodes:
+            for r in self._held(held):
+                r.unbind(role, node, namespace)
 
     def mark_wave_end(self) -> None:
-        for c in self.zone_count.values():
-            self.max_skew_seen = max(self.max_skew_seen, max(c.values()) - min(c.values()))
+        for r in self.rules:
+            r.mark_wave_end()
 
-    def overcommitted(self) -> list:
-        return [
-            name for name, used in self.used.items()
-            if any(used[k] > self.alloc[name][k] for k in used)
-        ]
+    def checks(self) -> dict:
+        """Every rule's numbers, each beside its limit."""
+        out = {}
+        for r in self.rules:
+            out.update(r.checks())
+        return out
 
 
 # -- the reference put in the program's place ------------------------------------
 
-BREAKS = ("capacity", "skew", "durability", "once")
+# the controls that break no rule of placement but the store's own guarantees
+STORE_BREAKS = ("durability", "delete_durability", "delete_lost", "once")
 
 
 class _Watch:
@@ -155,34 +86,37 @@ class _Watch:
             return self._q.popleft() if self._q else None
 
     def relist(self):
-        return {}
+        return {}, 0
 
     def stop(self) -> None:
         pass
 
 
 class System:
-    """A sequential scheduler over the Ledger's own arithmetic: pods in
-    arrival order, each to the next node (round robin) where it fits and,
-    for a pod under the spread rule, whose zone keeps the skew of its
-    namespace's counted pods within maxSkew.  Binds are journaled as JSON lines and flushed on close.
+    """A sequential scheduler over the rules' own arithmetic: pods in arrival
+    order, each to the next node (round robin) that every rule of the
+    deployment admits; a pod that no node admits waits for the next cycle.
+    Binds and deletions are journaled as JSON lines and flushed on close.
 
-    `broken` names the guarantee a control run breaks: "capacity" ignores
-    allocatable and stacks pods on a thousandth of the nodes; "skew" ignores the
-    spread rule and uses one zone's nodes; "durability" leaves every 97th
-    acknowledged bind out of the journal; "once" later moves every 101st
-    bound pod to another node.
+    `broken` names the guarantees a control run breaks (one, or several with
+    commas between).  A rule's own control ignores that rule and picks from
+    the nodes the rule file names, so that the breach is sure: "capacity"
+    stacks pods on a thousandth of the nodes, "skew" uses one zone's nodes,
+    "antiaffinity" a tenth of the nodes.  The store's: "durability" leaves
+    every 97th acknowledged bind out of the journal, "delete_durability" every
+    97th acknowledged deletion; "delete_lost" acknowledges every 97th deletion
+    and does nothing (no event, no journal line, the pod lives on); "once"
+    later moves every 101st bound pod to another node.
     """
 
     def __init__(self, deployment, workdir: str, recorder, broken: str | None = None):
-        if broken is not None and broken not in BREAKS:
-            raise ValueError(f"unknown control {broken!r}; one of {BREAKS}")
         self.dep = deployment
         self.rec = recorder
-        self.broken = broken
+        self.broken = frozenset(broken.split(",")) if broken else frozenset()
         self.journal = os.path.join(workdir, "reference.jsonl")
         self._pending = collections.deque()
         self._cv = threading.Condition()
+        self._mu = threading.Lock()     # the ledger, the journal file, who is where
         self._watches: list = []
         self._stop = threading.Event()
         self._rv = 0
@@ -191,9 +125,25 @@ class System:
     def start(self) -> None:
         self._nodes = self.dep.nodes()
         self._ledger = Ledger(self._nodes, self.dep.templates)
+        known = set(STORE_BREAKS) | {r.control for r in self._ledger.rules}
+        if self.broken - known:
+            raise ValueError(
+                f"unknown control {sorted(self.broken - known)}; this deployment has {sorted(known)}"
+            )
         self._names = [n["metadata"]["name"] for n in self._nodes]
+        # the nodes each role's pods are picked from: all, but for a control
+        self._from = {}
+        for r in self._ledger.rules:
+            if r.control not in self.broken:
+                continue
+            for role in self.dep.templates:
+                names = r.control_nodes(self._names, role)
+                if names is not None and len(names) < len(self._from.get(role, self._names)):
+                    self._from[role] = names
+        self._where: dict = {}      # (namespace, name) -> (role, node) of the live bound pods
         self._cursor = 0
         self._bound = 0
+        self._deleted = 0
         self._f = open(self.journal, "w")
         self._thread.start()
 
@@ -216,33 +166,35 @@ class System:
             self._pending.append((m["namespace"], m["name"], role))
             self._cv.notify()
 
+    def delete(self, namespace: str, name: str) -> None:
+        """A bound pod completes: its room is free from here on."""
+        with self._mu:
+            self._deleted += 1
+            if "delete_lost" in self.broken and self._deleted % 97 == 0:
+                return      # acknowledged, and lost whole
+            role, node = self._where.pop((namespace, name))
+            self._ledger.unbind(role, node, namespace)
+            if not ("delete_durability" in self.broken and self._deleted % 97 == 0):
+                self._f.write(json.dumps([namespace, name, None]) + "\n")
+            with self._cv:
+                self._rv += 1
+                self._emit(("DELETED", namespace, name, node, self._rv))
+
     def _emit(self, ev) -> None:
         for w in self._watches:
             w.put(ev)
 
     def _fits(self, role: str, node: str, namespace: str) -> bool:
-        led = self._ledger
-        if self.broken != "capacity":
-            used, alloc = led.used[node], led.alloc[node]
-            if any(used[k] + v > alloc[k] for k, v in led.req[role].items()):
-                return False
-        if led.counted[role] and self.broken != "skew":
-            c = led.counts(namespace)
-            if c[led.zone[node]] + 1 - min(c.values()) > led.rule[1]:
-                return False
-        return True
+        return all(
+            r.admits(role, node, namespace)
+            for r in self._ledger.rules if r.control not in self.broken
+        )
 
     def _pick(self, role: str, namespace: str):
-        names = self._names
-        n = len(names)
-        if self.broken == "capacity":
-            n = max(1, n // 1000)
+        names = self._from.get(role, self._names)
         for _ in range(len(names)):
-            node = names[self._cursor % n]
+            node = names[self._cursor % len(names)]
             self._cursor += 1
-            if self.broken == "skew" and self._ledger.counted[role] \
-                    and self._ledger.zone[node] != self._ledger.zone[names[0]]:
-                continue
             if self._fits(role, node, namespace):
                 return node
         return None
@@ -262,27 +214,32 @@ class System:
                 "keys": [(ns, name) for ns, name, _ in batch], "pods": len(batch),
                 "t_dispatch0": now, "t_decode1": now, "route": "reference",
             })
-            for ns, name, role in batch:
-                node = self._pick(role, ns)
-                if node is None:
-                    continue    # stays unbound: the cluster is full
-                self._ledger.bind(role, node, ns)
-                self._bound += 1
-                if not (self.broken == "durability" and self._bound % 97 == 0):
-                    lines.append(json.dumps([ns, name, node]))
-                if self.broken == "once" and self._bound % 101 == 0:
-                    moved.append((ns, name, role, node))
-                with self._cv:
-                    self._rv += 1
-                    self._emit(("MODIFIED", ns, name, node, self._rv))
-            for ns, name, role, node in moved:
-                other = self._names[(self._names.index(node) + 1) % len(self._names)]
-                lines.append(json.dumps([ns, name, other]))
-                with self._cv:
-                    self._rv += 1
-                    self._emit(("MODIFIED", ns, name, other, self._rv))
-            moved.clear()
-            self._f.write("\n".join(lines) + "\n")
+            with self._mu:
+                for ns, name, role in batch:
+                    node = self._pick(role, ns)
+                    if node is None:
+                        with self._cv:      # no room now: a completion may make some
+                            self._pending.append((ns, name, role))
+                        continue
+                    self._ledger.bind(role, node, ns)
+                    self._where[(ns, name)] = (role, node)
+                    self._bound += 1
+                    if not ("durability" in self.broken and self._bound % 97 == 0):
+                        lines.append(json.dumps([ns, name, node]))
+                    if "once" in self.broken and self._bound % 101 == 0:
+                        moved.append((ns, name, role, node))
+                    with self._cv:
+                        self._rv += 1
+                        self._emit(("MODIFIED", ns, name, node, self._rv))
+                for ns, name, role, node in moved:
+                    other = self._names[(self._names.index(node) + 1) % len(self._names)]
+                    lines.append(json.dumps([ns, name, other]))
+                    with self._cv:
+                        self._rv += 1
+                        self._emit(("MODIFIED", ns, name, other, self._rv))
+                moved.clear()
+                if lines:
+                    self._f.write("\n".join(lines) + "\n")
             # the next batch: binds reach the client in waves, at most a
             # thousand pods a second, so that no run of it fills the cluster
             time.sleep(max(0.06, 0.001 * len(batch)))
@@ -300,5 +257,8 @@ class System:
             for line in f:
                 if line.strip():
                     ns, name, node = json.loads(line)
-                    out[(ns, name)] = node
+                    if node is None:
+                        out.pop((ns, name), None)
+                    else:
+                        out[(ns, name)] = node
         return out

@@ -145,6 +145,8 @@ def details(record: dict) -> dict:
                 reduce.gc_seconds_between(record, record["t_open"], record["t_close"], gen),
             ] for gen in (0, 1, 2)
         },
+        "deleted": len(record["deleted"]),
+        "live_in_window": reduce.live_range(record, record["t_open"], record["t_close"]),
         "pods_bound_at_open": sum(1 for b in record["bind_log"] if b[0] < record["t_open"]),
         "counters_open": record["counters_open"], "counters_close": record["counters_close"],
         "trace_modules": (record["trace"] or {}).get("modules"),

@@ -35,7 +35,8 @@ class Watch:
 
     def relist(self):
         """What a client does on 410: list, then watch from the list's rv.
-        Returns {(namespace, name): node} of the pods bound at the cut."""
+        Returns {(namespace, name): node} of the pods bound at the cut, and
+        the cut's resourceVersion."""
         from kubernetes_tpu.api import store as st
 
         while True:
@@ -47,7 +48,7 @@ class Watch:
             return {
                 (p.meta.namespace, p.meta.name): p.spec.node_name
                 for p in pods if p.spec.node_name
-            }
+            }, rv
 
     def stop(self) -> None:
         self._w.stop()
@@ -87,6 +88,9 @@ class System:
 
     def create(self, pod: dict, role: str) -> None:
         self.store.create(self._kubeyaml.pod_from_dict(pod))
+
+    def delete(self, namespace: str, name: str) -> None:
+        self.store.delete("Pod", name, namespace)
 
     def watch(self) -> Watch:
         return Watch(self.store)

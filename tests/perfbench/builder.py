@@ -3,16 +3,19 @@
 
     python3 tests/perfbench/builder.py --workload <cell> --seed <n> --seconds <s>
         [--trace 1] [--rehearse-cpu] [--dump FILE]
-        [--system reference [--control capacity|skew|durability|once]]
+        [--system reference [--control NAME[,NAME]]]
         [--set KEY=JSON ...] [--gc-freeze] [--sweep r1,r2,... [--hold SECONDS]]
+        [--as-cell LISTED_CELL]
 
 drives the functions ``perfbench/run.py`` drives, with what a cell's proof
 needs besides: the plain reference (whole, or with one guarantee broken: the
-control) in the program's place, a parameter of the traffic file overridden, a
+control, which is a rule file's own (`capacity`, `skew`, `antiaffinity`) or the
+store's (`durability`, `delete_durability`, `delete_lost`, `once`)) in the program's place, a parameter of the traffic file overridden, a
 run's details written to a file, ``gc.freeze()`` after set-up (an experiment
 on the program's behalf that the benchmark itself never makes), and the rate
 sweep that finds an open loop's knee.  ``--workload`` may also be ``<configuration>:<mix>`` for a cell that
-BENCHMARK.json does not list yet.
+BENCHMARK.json does not list yet; no metric lists such a cell, so ``--as-cell`` names a listed one whose
+metrics' readers are run over its record (a hand run's numbers, under no cell's name in any ledger).
 """
 
 from __future__ import annotations
@@ -47,6 +50,7 @@ def main(argv=None) -> int:
     ap.add_argument("--gc-freeze", action="store_true")
     ap.add_argument("--sweep", default=None, help="comma-separated rates, pods/s")
     ap.add_argument("--hold", type=float, default=10.0, help="seconds per sweep step")
+    ap.add_argument("--as-cell", default=None, help="read this listed cell's metrics")
     args = ap.parse_args(argv)
 
     from perfbench import harness
@@ -82,8 +86,9 @@ def main(argv=None) -> int:
         plant=freeze if args.gc_freeze else None,
         overrides={k: json.loads(v) for k, v in (kv.split("=", 1) for kv in args.set)},
     )
-    if cell["name"] in {w["name"] for w in manifest.doc["workloads"]}:
-        line = bench.result_line(manifest, cell, record, bool(args.trace))
+    read_as = manifest.cell(args.as_cell) if args.as_cell else cell
+    if read_as["name"] in {w["name"] for w in manifest.doc["workloads"]}:
+        line = bench.result_line(manifest, read_as, record, bool(args.trace))
     else:       # no metrics are listed for it yet: the comparison alone
         line = {"correct": bool(record["verdict"]["correct"]), "setup_s": record["setup_s"],
                 "checks": record["verdict"]["checks"]}
@@ -91,7 +96,7 @@ def main(argv=None) -> int:
     if args.dump:
         os.makedirs(os.path.dirname(os.path.abspath(args.dump)), exist_ok=True)
         with open(args.dump, "w") as f:
-            both = {g: bench.read_metrics(manifest, cell["name"], g, record)
+            both = {g: bench.read_metrics(manifest, read_as["name"], g, record)
                     for g in ("end_to_end", "per_layer")}
             json.dump({"result": line, **both, "details": extra}, f)
     bench.report(line, extra)

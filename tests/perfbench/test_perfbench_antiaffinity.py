@@ -1,0 +1,192 @@
+"""The next deployment's files, unlisted: ``sched-perf-5000n-antiaffinity``
+under ``closed256-live2000``, as a later PR would list it.  The whole reference
+comes out correct there, each control not correct by its own number, the served
+program runs it at toy size, and the copies equal the port's.  And the three
+cells that are listed keep their checks: the same names, limits and order."""
+
+import json
+import os
+import sys
+import time
+
+import pytest
+import yaml
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from perfbench import harness, reduce  # noqa: E402
+from perfbench.manifest import Manifest  # noqa: E402
+
+DOC = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+UPSTREAM = os.path.join(ROOT, "kubernetes_tpu", "perf", "config")
+CONFIG, MIX = "sched-perf-5000n-antiaffinity", "closed256-live2000"
+CELL = {"name": "perf5k-antiaffinity-closed256-live2000", "config": CONFIG, "traffic": MIX,
+        "chips": 1}
+BASIC_LIVE = {"name": "basic-live", "config": "sched-perf-5000n", "traffic": MIX, "chips": 1}
+FAST = {"replay_walk": [8], "replay_cap_s": 2.0, "drain_s": 6.0}
+ALWAYS = ["unbound", "bound_twice", "stray_binds", "overcommitted_nodes", "journal_diff",
+          "rv_regressions"]
+DELETES = ["deletions_lost", "deletions_unasked"]      # where the mix deletes, and last
+
+
+def run(cell, seed, system="reference", control=None, seconds=3.0):
+    return harness.run_cell(
+        Manifest(), cell, seed, seconds, False, True, system_name=system, control=control,
+        t_start=time.perf_counter(), overrides=FAST,
+    )
+
+
+def failing(verdict):
+    return {k for k, (v, lim) in verdict["checks"].items() if v > lim}
+
+
+# -- the files ---------------------------------------------------------------------
+
+def test_the_files_are_in_the_tree_and_no_entry_names_them_yet():
+    m = Manifest()
+    assert CONFIG not in {c["name"] for c in DOC["configs"]}
+    assert MIX not in {w["traffic"] for w in DOC["workloads"]}
+    doc = m.config(CONFIG)          # found by its name alone
+    assert doc["name"] == CONFIG and "SchedulingPodAntiAffinity/5000Nodes" in doc["source"]
+    assert set(doc["reduced"]) == {"measurePods"}
+    assert doc["capacity_pods"] == 5000 and "200,000" in doc["capacity_note"]
+    assert set(doc["guarantees"]) == {"bound_exactly_once", "fits", "durable", "rv_monotone",
+                                      "anti_affinity"}
+    assert doc["assumed"]["scheduler"] == {"batch_size": 1024} and doc["assumed"]["pods_complete"]
+    assert doc["assumed"]["store"] == m.config("sched-perf-5000n")["assumed"]["store"]
+
+
+def test_the_mix_is_closed256_and_a_population():
+    m = Manifest()
+    mine, theirs = m.traffic(MIX), m.traffic("closed256")
+    assert mine["live_pods"] == 2000
+    # the traced slice is halved, with the reading that says why beside it
+    same = set(theirs) - {"what", "toy", "trace_seconds"}
+    assert {k: mine[k] for k in same} == {k: theirs[k] for k in same}
+    assert set(mine) - set(theirs) == {"live_pods", "trace_seconds_why"}
+    assert mine["trace_seconds"] == theirs["trace_seconds"] / 2 and mine["trace_seconds_why"]
+
+
+def test_the_template_copy_equals_the_ports():
+    name = "pod-with-pod-anti-affinity.yaml"
+    with open(os.path.join(ROOT, "perfbench", "configs", "templates", name)) as f:
+        mine = yaml.safe_load(f)
+    with open(os.path.join(UPSTREAM, name)) as f:
+        theirs = yaml.safe_load(f)
+    assert mine == theirs
+    term = mine["spec"]["affinity"]["podAntiAffinity"][
+        "requiredDuringSchedulingIgnoredDuringExecution"][0]
+    assert term["namespaces"] == ["sched-1", "sched-0"]
+
+
+def test_the_test_case_equals_the_ports_and_the_namespaces_are_its_ops():
+    doc = Manifest().config(CONFIG)
+    with open(os.path.join(UPSTREAM, "performance-config.yaml")) as f:
+        cases = {c["name"]: c for c in yaml.safe_load(f)}
+    mine, theirs = doc["test_case"], cases["SchedulingPodAntiAffinity"]
+    assert mine["workloadTemplate"] == theirs["workloadTemplate"]
+    assert mine["defaultPodTemplatePath"] == theirs["defaultPodTemplatePath"]
+    assert mine["workloads"] == [w for w in theirs["workloads"] if w["name"] == "5000Nodes"]
+    ops = [op for op in mine["workloadTemplate"] if op["opcode"] == "createPods"]
+    assert doc["assumed"]["role_namespaces"] == {
+        "init": [ops[0]["namespace"]], "measure": [ops[1]["namespace"]]}
+
+
+# -- the proof, at toy size --------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def whole():
+    return run(CELL, 2**31 + 31)
+
+
+def test_the_whole_reference_comes_out_correct(whole):
+    v = whole["verdict"]
+    assert v["correct"], v["checks"]
+    assert list(v["checks"]) == ALWAYS + ["colocated_pods"] + DELETES
+    assert all(pair == [0, 0] for pair in v["checks"].values())
+
+
+def test_pods_complete_and_the_population_is_held(whole):
+    p = whole["params"]
+    assert len(whole["deleted"]) > 100 and len(whole["gone"]) >= len(whole["deleted"]) - 1
+    live = reduce.live_range(whole, whole["t_open"], whole["t_close"])
+    lo, _, hi = live["not_handed_out"]
+    assert p["live_pods"] <= lo and hi < p["live_pods"] + p["topup_chunk"]
+    # what the client saw alive: a whole wave may land before its completions do
+    assert live["not_seen_deleted"][2] <= p["live_pods"] + p["topup_chunk"] + p["backlog_pods"]
+    # namespaces by role: set-up's init pods in sched-0, every measured pod in sched-1
+    assert {ns for ns, name, _, _, _ in whole["created"]} == {"sched-1"}
+    assert {ns for ns, name in whole["bound"] if name.startswith("init-")} == {"sched-0"}
+    assert reduce.live_range({"live": []}, 0.0, 1.0) is None
+
+
+@pytest.mark.parametrize("cell,control,number", [
+    (CELL, "antiaffinity", "colocated_pods"),
+    (CELL, "delete_durability", "journal_diff"),
+    (CELL, "delete_lost", "deletions_lost"),
+    (BASIC_LIVE, "delete_lost", "deletions_lost"),
+    (CELL, "capacity,antiaffinity", "overcommitted_nodes"),
+    (CELL, "once", "bound_twice"),
+    (BASIC_LIVE, "capacity", "overcommitted_nodes"),
+    (BASIC_LIVE, "durability", "journal_diff"),
+])
+def test_a_broken_guarantee_comes_out_not_correct_with_deletions_on(cell, control, number):
+    v = run(cell, 2**31 + 32, control=control)["verdict"]
+    assert not v["correct"]
+    assert number in failing(v), v["checks"]
+    if control == "antiaffinity":
+        assert failing(v) == {"colocated_pods"}      # allocatable still holds them
+    if control == "delete_durability":
+        assert failing(v) == {"journal_diff"}        # the client saw every one of them
+    if control == "delete_lost":
+        # no event and no journal line: the pod lives on for every rule, and
+        # only what the harness asked for tells; it reads back, too
+        assert failing(v) == {"deletions_lost", "journal_diff"}
+        assert v["checks"]["deletions_lost"] == v["checks"]["journal_diff"]
+
+
+def test_a_freed_slot_is_reused_and_only_after_its_delete():
+    # the basic deployment under completions: nodes refill through the run,
+    # and the whole reference never puts a pod where the last one still lives
+    rec = run(BASIC_LIVE, 2**31 + 33)
+    assert rec["verdict"]["correct"], rec["verdict"]["checks"]
+    assert len(rec["deleted"]) > 100
+    assert list(rec["verdict"]["checks"]) == ALWAYS + DELETES
+
+
+def test_an_unknown_control_is_refused_with_the_deployments_own():
+    with pytest.raises(ValueError, match="skew"):
+        run(CELL, 1, control="skew")
+
+
+def test_the_served_program_runs_the_deployment_at_toy_size():
+    rec = run(CELL, 2**31 + 34, system="served")
+    v = rec["verdict"]
+    assert v["correct"], v["checks"]
+    assert v["checks"]["colocated_pods"] == [0, 0] and len(rec["deleted"]) > 0
+
+
+# -- the three cells that are listed keep their checks -----------------------------
+
+EXPECTED = {
+    "perf5k-basic-closed256": {n: [0, 0] for n in ALWAYS},
+    "perf5k-basic-steady": {n: [0, 0] for n in ALWAYS},
+    "perf5k-spread-closed256": dict({n: [0, 0] for n in ALWAYS}, max_zone_skew=[None, 5]),
+}
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in DOC["workloads"]])
+def test_a_listed_cell_keeps_its_checks_names_limits_and_order(cell):
+    rec = run(Manifest().cell(cell), 2**31 + 35)
+    v = rec["verdict"]
+    want = EXPECTED[cell]
+    assert list(v["checks"]) == list(want)
+    assert {k: lim for k, (_, lim) in v["checks"].items()} == \
+        {k: lim for k, (_, lim) in want.items()}
+    # the verdict the parent gave on this seeded run: every exact number 0,
+    # the skew within its 5
+    assert v["correct"] is True
+    assert all(v["checks"][k] == pair for k, pair in want.items() if pair[0] is not None)
+    assert rec["deleted"] == [] and rec["gone"] == {} and rec["live"] == []

@@ -123,7 +123,7 @@ class SeenBy:
 
     def __init__(self, binds):
         self.bound = {key: (0.0, node, i) for i, (key, node) in enumerate(binds.items())}
-        self.rebound, self.rv_regressions = [], 0
+        self.rebound, self.rv_regressions, self.gone = [], 0, {}
 
 
 def spread_verdict(per_namespace_zone_counts):
@@ -159,7 +159,7 @@ def test_the_reference_scheduler_holds_the_rule_namespace_by_namespace():
     for _ in range(5):
         ledger.bind("measure", "node-0", "team-0")      # zone 0, team-0: at the limit
     sysm = reference.System.__new__(reference.System)
-    sysm._ledger, sysm.broken = ledger, None
+    sysm._ledger, sysm.broken = ledger, frozenset()
     assert not sysm._fits("measure", "node-8", "team-0")    # a sixth in zone 0
     assert sysm._fits("measure", "node-1", "team-0")        # zone 1 is open
     assert sysm._fits("measure", "node-8", "team-1")        # team-1 counts its own

@@ -63,13 +63,29 @@ def test_the_roofline_of_the_plain_solve_is_not_read_in_this_cell():
                 "constraint_encode_ms_per_cycle.backlog"} & control
 
 
+# under the suite's load a toy run builds its executables slowly: the replay
+# may wait for them and the drain for the window's closing wave, so that the
+# 3 s window holds whole waves and no build
+STEADY = dict(by_hand.FAST, replay_cap_s=20.0, drain_s=30.0)
+
+
 @pytest.fixture(scope="module")
 def toy_record():
+    """The module's one toy record, read by every case; what the program's
+    recorder holds of it is read here, before any other run in this process."""
+    from perfbench import programtrace, reduce
+
     m = Manifest()
-    return harness.run_cell(
-        m, m.cell(CELL), 2**31 + 27, 3.0, False, True,
-        t_start=time.perf_counter(), overrides=by_hand.FAST,
-    )
+    # a 3 s window that held no whole wave (a build inside it): the next run finds it built
+    for _ in range(3):
+        rec = harness.run_cell(
+            m, m.cell(CELL), 2**31 + 27, 3.0, False, True,
+            t_start=time.perf_counter(), overrides=STEADY,
+        )
+        if reduce.edges(rec) is not None:
+            break
+    programtrace.load(rec)
+    return rec
 
 
 @pytest.mark.parametrize(
