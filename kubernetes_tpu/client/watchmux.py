@@ -228,7 +228,10 @@ class _MuxLoop:
         host, port, _ = self.mux._target(stream)
         inf = stream.informer
         path = f"/api/v1/watch/{inf.kind}"
-        if inf.last_rv:
+        if inf.synced:
+            # also from rv 0: a list of an empty store reads rv 0, and a
+            # watch "from now" after it would lose what is written
+            # between the list and the server's taking the watch
             path += f"?from_rv={inf.last_rv}"
         req = (
             f"GET {path} HTTP/1.1\r\nHost: {host}:{port}\r\n"

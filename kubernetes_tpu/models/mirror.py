@@ -487,23 +487,25 @@ class DeviceClusterMirror:
             return _set_rows, _set_rows_ax1
         return self._set, self._set_ax1
 
-    def warm_usage_buckets(self, max_rows: int) -> None:
+    def warm_usage_buckets(self) -> None:
         """Build or load the usage-leaf scatter of every dirty-row bucket
-        a bind wave of up to `max_rows` pods can leave behind (1, 2, 4,
-        ... rows), against the resident tensors: Scheduler.warmup's
-        share of the mirror, so that the first wave of each size does
-        not pay for an executable inside a cycle.  The results are
-        dropped: nothing resident changes.  Caller holds the cache lock
-        and has synced (a mirror without residents has nothing to
-        warm)."""
+        the delta path serves (1, 2, 4, ... rows, up to the share of the
+        cluster past which sync() re-uploads whole), against the
+        resident tensors: Scheduler.warmup's share of the mirror, so
+        that the first delta of each size does not pay for an executable
+        inside a cycle.  Not only up to a batch: a bind wave dirties at
+        most a batch of rows, but pods that LEAVE between two encodes
+        (completions, evictions; ClusterState.remove_pod) dirty a row
+        each however many they are, and their scatter is this one.  The
+        results are dropped: nothing resident changes.  Caller holds the
+        cache lock and has synced (a mirror without residents has
+        nothing to warm)."""
         dev = self._dev
         if dev is None:
             return
         host = self.state.tensors()
         set_rows, _ = self._setters()
-        n = host.allocatable.shape[0]
-        # larger deltas re-upload whole (sync), so no scatter exists
-        top = min(vb.pad_dim(max(max_rows, 1), 1), int(self.FULL_SYNC_FRACTION * n))
+        top = int(self.FULL_SYNC_FRACTION * host.allocatable.shape[0])
         bucket = 1
         while bucket <= top:
             self._scatter_usage(

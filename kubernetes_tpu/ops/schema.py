@@ -817,7 +817,13 @@ class SnapshotBuilder:
         index, a1 = live signatures.  Span `sched.encode.classes`
         times the class split: n = spread rows, a0 = live constraint
         classes (1 where the batch has no constraint: the trivial one),
-        a1 = the padded class dim the solve's statics take."""
+        a1 = the padded class dim the solve's statics take.  Row
+        `sched.encode.terms` sizes the inter-pod term tables (the
+        constraints span already times their build): n = valid term
+        rows, the batch's and the bound owners' (0 where there is
+        none), a0 = term owners read from the index, a1 = topology
+        values under the widest key a term names (the Z the solve's
+        count tables take, before padding)."""
         if state.builder is not self:
             raise ValueError("state was built by a different SnapshotBuilder")
         # one effective-requests derivation per pod for the whole build:
@@ -849,6 +855,16 @@ class SnapshotBuilder:
             sp.n = int(spread.valid.sum())
             sp.a0 = int((pods.cons_rep >= 0).sum())
             sp.a1 = pods.class_rep.shape[0]
+        t_now = trace.now()
+        trace.event(
+            "sched.encode.terms", t_now, t_now, n=int(terms.valid.sum()),
+            a0=bound.num_owners,
+            a1=max(
+                (len(self.topo_vocabs[self.limits.topology_keys[s]])
+                 for s in np.unique(terms.slot[terms.valid])),
+                default=0,
+            ),
+        )
         meta = SnapshotMeta(
             num_nodes=state._high,
             num_pods=len(pending_pods),

@@ -594,7 +594,11 @@ class Scheduler:
                 # ACCOUNTED under — deallocating first would make
                 # remove_pod subtract device counts that were never
                 # added (unaccounting symmetry)
+                # one interval a removed pod, its wait for cache.lock
+                # (an encode or a commit holds it) included
+                t0 = _trace.now()
                 self.cache.remove_pod(pod)
+                _trace.tally("sched.cache.remove", t0, _trace.now())
                 # a terminated pod frees resources: unschedulable pods
                 # may fit now — but only resource/port/spread/interpod
                 # failures can benefit (AssignedPodDelete wake set)
@@ -2107,8 +2111,10 @@ class Scheduler:
         set warms what only a cluster that changes between solves
         meets: a batch that leaves pad rows (a first-seen class: the
         PartialsCache's insert path), one dirty row (its refresh path)
-        and the mirror's usage scatter at every dirty-row bucket a wave
-        of up to a full batch can leave (mirror.warm_usage_buckets).
+        and the mirror's usage scatter at every dirty-row bucket its
+        delta path serves: what a bind wave leaves and what the pods
+        that left the cluster since the last encode leave, which no
+        batch size bounds (mirror.warm_usage_buckets).
 
         Returns seconds spent.  Never raises: a bucket that fails to
         encode (cap overflow) is skipped — the real cycle handles those
@@ -2174,7 +2180,7 @@ class Scheduler:
             mirror = fwk.tpu._mirror if fwk.tpu.use_mirror else None
             if mirror is not None:
                 with self.cache.lock:
-                    mirror.warm_usage_buckets(cap)
+                    mirror.warm_usage_buckets()
         except Exception:
             log.exception("warmup: warming the delta paths failed")
         finally:

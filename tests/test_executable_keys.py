@@ -111,3 +111,103 @@ def test_one_padding_rule_for_rows_and_classes():
         8, 64, 128, 256, 512]
     assert assign.waves_couple(assign.FeatureFlags(spread=True))
     assert not assign.waves_couple(assign.FeatureFlags(images=True))
+
+
+# -- pods that leave: the deployment sched-perf-5000n-antiaffinity -----------------
+
+ANTI_NODES = 256
+ANTI_BATCH = 64
+
+
+@pytest.fixture(scope="module")
+def anti_affinity_template():
+    # scheduler_perf's pod-with-pod-anti-affinity.yaml, the port's own copy
+    import os
+
+    import yaml
+
+    from kubernetes_tpu import perf
+
+    path = os.path.join(os.path.dirname(perf.__file__), "config",
+                        "pod-with-pod-anti-affinity.yaml")
+    with open(path) as f:
+        d = yaml.safe_load(f)
+    d["metadata"].pop("generateName")
+    return d
+
+
+def test_no_removal_compiles_after_warmup_and_placements_equal_the_oracles(
+        anti_affinity_template):
+    """Bound pods leave between batches, 1 to 64 at a time, as the
+    informer removes them (``SchedulerCache.remove_pod``): whatever rows
+    the binds and the removals since the last encode dirtied, the mirror's
+    scatter for them was built in ``warmup`` (before PR 32 only the buckets
+    a bind wave leaves were: 64 binds and 64 removals asked for a new one),
+    and every batch lands where ``testing/oracle.py`` puts it on the same
+    sequence of binds and removals."""
+    import copy
+
+    from kubernetes_tpu.api import kubeyaml
+    from kubernetes_tpu.testing.oracle import Oracle
+
+    def anti_affinity_pod(name, ns):
+        d = copy.deepcopy(anti_affinity_template)
+        d["metadata"].update(name=name, namespace=ns)
+        return kubeyaml.pod_from_dict(d)
+
+    store = st.Store()
+    nodes = [
+        make_node(f"node-{i}").capacity(cpu_milli=4000, mem=32 * GI, pods=110)
+        .zone(f"zone-{i % 8}").obj()
+        for i in range(ANTI_NODES)
+    ]
+    for node in nodes:
+        store.create(node)
+    sched = Scheduler(store, batch_size=ANTI_BATCH)
+    sched.start()
+    assert sched.informers.wait_for_sync()
+    rng = random.Random(2032)
+    live = []       # bound and not removed, oldest first
+    try:
+        tpu = sched.tpu
+        sched.warmup([anti_affinity_pod(f"warm-{i}", "sched-1") for i in range(ANTI_BATCH)])
+        fresh, seen, removed, buckets = [], set(), 0, set()
+        synced = tpu.state.generation
+        for b in range(SWEEP):
+            size = rng.randint(1, ANTI_BATCH)
+            pods = [
+                anti_affinity_pod(f"p-{b}-{i}", rng.choice(["sched-1", "sched-1", "sched-0"]))
+                for i in range(size)
+            ]
+            want = Oracle(nodes, bound_pods=live).schedule(pods)
+            dirty = tpu.state.dirty_rows(synced, ANTI_NODES)[1].shape[0]
+            buckets.add(vocab.pad_dim(max(dirty, 1), 1))
+            mark = compileclock.events()
+            names = tpu.schedule_pending(pods, lock=sched.cache.lock)
+            synced = tpu.state.generation
+            if compileclock.events() != mark:
+                fresh.append((b, size, dirty, tpu.last_solve.meta.route))
+            seen.add(tpu.last_solve.meta.route)
+            assert names == want, f"batch {b}"
+            for pod, node in zip(pods, names):
+                if node:
+                    pod.spec.node_name = node
+                    sched.cache.assume(pod, node)
+                    live.append(pod)
+            # the longest-bound leave, as a Job's pods finish; twice where
+            # the one-pod-a-node cluster is filling up
+            for _ in range(2 if len(live) > ANTI_NODES * 5 // 8 else 1):
+                k = min(rng.randint(1, 64), len(live))
+                for pod in live[:k]:
+                    sched.cache.remove_pod(pod)
+                del live[:k]
+                removed += k
+        assert fresh == []
+        assert seen == {"greedy", "wavefront"}
+        assert removed > 4000
+        # the sweep did ask for scatters past a batch's rows
+        assert max(buckets) > ANTI_BATCH
+    finally:
+        for pod in live:
+            sched.cache.remove_pod(pod)
+        sched.stop()

@@ -205,6 +205,29 @@ def test_mux_informers_failover_across_replica_kill():
         plane.stop()
 
 
+def test_mux_informer_that_listed_an_empty_store_watches_from_rv_zero():
+    """A list of an empty store reads rv 0; what is written between that
+    list and the server's taking the watch must still arrive (the watch
+    asked "from now" for a falsy rv and lost it: the wedge behind the
+    fail-over test's rare failures under load)."""
+    store = st.Store()
+    plane = APIServerReplicaSet(store, replicas=1)
+    mux = HttpWatchMux(plane.urls(), threads=1)
+    try:
+        inf = mux.add_informer("Pod")
+        stream = mux._streams[0]
+        mux._relist(stream)            # the list, by hand: the loop is not running yet
+        assert inf.synced and inf.last_rv == 0 and inf.cache == {}
+        for i in range(5):             # written before any watch exists
+            store.create(make_pod(f"early-{i}").obj())
+        mux.start()                    # now the loop connects the watch
+        assert _wait_for(lambda: len(inf.cache) == 5), sorted(inf.cache)
+        assert mux.violations() == []
+    finally:
+        mux.stop()
+        plane.stop()
+
+
 # -- the scheduler's serving-plane mirror ------------------------------------
 
 
