@@ -259,6 +259,7 @@ class HostSolve:
     result = None
     wave_count = None
     wave_fallbacks = None
+    wave_steps = None
     frag_score = None
     carveouts = None
     contiguous_gangs = None
@@ -528,6 +529,7 @@ class DeviceSolve:
                 "reasons": self.result.reasons,  # None stays None
                 "wave_count": getattr(self.result, "wave_count", None),
                 "wave_fallbacks": getattr(self.result, "wave_fallbacks", None),
+                "wave_steps": getattr(self.result, "wave_steps", None),
                 # slice carve-out telemetry (None off the slice family)
                 "frag_score": getattr(self.result, "frag_score", None),
                 "carveouts": getattr(self.result, "carveouts", None),
@@ -573,6 +575,7 @@ class DeviceSolve:
                 else int(got["contiguous_gangs"]),
                 None if got["carveout_fallbacks"] is None
                 else int(got["carveout_fallbacks"]),
+                None if got["wave_steps"] is None else int(got["wave_steps"]),
             )
         return self._decoded
 
@@ -593,6 +596,12 @@ class DeviceSolve:
     @property
     def wave_fallbacks(self) -> Optional[int]:
         return self._decode()[3]
+
+    @property
+    def wave_steps(self) -> Optional[int]:
+        """In-wave sequential steps the device ran (None off the
+        wavefront route): pods where every wave had one member."""
+        return self._decode()[8]
 
     @property
     def frag_score(self) -> Optional[float]:
@@ -739,6 +748,11 @@ class TPUBatchScheduler:
     # Greedy-routed batches at least this large solve through the
     # wavefront path (ops.assign.wavefront_assign): below it the classic
     # scan's executable is cheaper to hold and the wave win is noise.
+    # Unswept on the chip (ROADMAP D2).  What a sweep weighs: on an
+    # 8,192-row node bucket a classic scan step is 20-32 us a pod
+    # (PERF_LEDGER, PR 32, perf5k-basic-steady: 0.318 ms for 10 pods),
+    # and since PR 33 a wave costs its evaluation plus a step a member,
+    # a one-member wave a scan step (PERF.md section 5 has the split).
     WAVEFRONT_MIN_PODS = 64
 
     def __init__(
@@ -950,8 +964,8 @@ class TPUBatchScheduler:
             and snap.pods.req.shape[0] >= self.WAVEFRONT_MIN_PODS
             and not features.slices
         ):
-            # same semantics as the scan (ops.assign parity suite), P/W
-            # sequential steps instead of P; mesh mode routes here too —
+            # same semantics as the scan (ops.assign parity suite), one
+            # batched evaluation a wave; mesh mode routes here too —
             # the sharded wavefront is scan-identical across shards.
             # Slice carve-out batches stay on the classic scan: every
             # shaped pod writes the free mask every other shaped pod's

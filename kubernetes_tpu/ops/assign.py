@@ -302,9 +302,11 @@ class SolveResult(NamedTuple):
     cluster: ClusterTensors   # post-solve cluster (assumed placements applied)
     reasons: jnp.ndarray = None   # i32[P]: REASON_* for unplaced pods
     # wavefront-path telemetry (None on the classic scan): executed wave
-    # count and fallback count (serialized waves + per-pod full re-evals)
+    # count, fallback count (pods of serialized waves + per-pod full
+    # re-evals) and the in-wave sequential steps the device ran
     wave_count: jnp.ndarray = None      # i32[]
     wave_fallbacks: jnp.ndarray = None  # i32[]
+    wave_steps: jnp.ndarray = None      # i32[]
     # slice carve-out telemetry (None unless features.slices): post-solve
     # cluster fragmentation and per-gang carve-out outcomes
     frag_score: jnp.ndarray = None          # f32[]
@@ -925,6 +927,32 @@ def greedy_assign_jit(cfg: ScoreConfig = DEFAULT_SCORE_CONFIG):
 #     against the live carry inside its mini-step (lax.cond — the rare
 #     branch costs nothing when untaken).
 #
+# A wave costs what its members cost.  The plan's rows are K lanes wide
+# whatever they hold (a coupled batch gets one row a pod: one member and
+# K-1 pads), so the device reads the row and takes one of three shapes:
+#
+#   * ONE MEMBER: the wave is that member's scan step — one _eval_pod
+#     against the wave-start carry, one argmax, the usage/ports/spread/
+#     term updates.  No [K, N] evaluation, no top-k, no safety check.
+#     Not a fallback: there was nothing to batch.
+#   * SAFE (two members or more, no in-wave coupling): the [K, N]
+#     evaluation and the top-(K+1), then the mini-scan — which runs to
+#     the wave's LAST VALID LANE, not to K (a hole before it is skipped
+#     by its valid bit; members need not be packed at the front).
+#   * COUPLED: the original step body over the members, to the last
+#     valid lane; it pays for no [K, N] evaluation either.
+#
+# The trip counts and the branch are functions of the plan alone, which
+# is replicated under both sharded layouts, so every shard runs the same
+# trips and the collectives inside a step stay aligned.
+#
+# Telemetry (SolveResult): wave_count — rows with a member; wave_fallbacks
+# — members of coupled waves plus per-member fit-flip re-evaluations;
+# wave_steps — in-wave sequential steps the device ran (1 a one-member
+# wave, the last valid lane's index + 1 otherwise).  Steps over pods is
+# 1.0 where the waves hold nothing but members; K x waves / pods is what
+# loops to the cap would have run.
+#
 # Gang all-or-nothing rides the same shared post-pass.  Keyed (tie_seed)
 # solves stay on the classic scan — reservoir sampling needs the full
 # gumbel tie set per step.
@@ -1240,9 +1268,14 @@ def wavefront_assign(
 
     def wave_step(carry, members):
         (requested, nonzero, new_ports, sp_counts,
-         tm_present, tm_blocked, tm_global, n_fb, n_waves) = carry
+         tm_present, tm_blocked, tm_global, n_fb, n_waves, n_steps) = carry
         mvalid = members >= 0
         mk = jnp.clip(members, 0, p - 1)
+        # the in-wave loops stop after the last valid lane (holes before
+        # it are skipped by valid_j, as ever).  A function of the
+        # replicated plan: every shard runs the same trips.
+        m_last = jnp.max(jnp.where(mvalid, arange_k + 1, 0))
+        n_members = mvalid.sum().astype(jnp.int32)
         req0, nz0 = requested, nonzero
         cl0 = cluster._replace(requested=requested, nonzero_requested=nonzero)
         sp = tm = None
@@ -1254,7 +1287,18 @@ def wavefront_assign(
                 global_any=tm_global,
             )
 
-        def run_wave(_):
+        def lane_rows():
+            """The four [K] output rows as a lane nobody ran leaves them:
+            unplaced (-1, so the deferred updates below stay no-ops), and
+            the rest what skip_wave yields (dropped with the -1 member)."""
+            return (
+                jnp.full(k_dim, -1, jnp.int32),
+                jnp.full(k_dim, NEG_INF),
+                jnp.zeros(k_dim, jnp.int32),
+                jnp.full(k_dim, REASON_NONE, jnp.int32),
+            )
+
+        def fast(_):
             # heavy half, batched: every member evaluated from the
             # wave-start carry in one vectorized pass
             def eval_one(i):
@@ -1304,201 +1348,44 @@ def wavefront_assign(
                 topv, pos = jax.lax.top_k(vg, kk_g)
                 topi = jnp.take_along_axis(ig, pos, axis=1)
 
-            def fast(_):
-                def mini(mc, j):
-                    req_c, nz_c, picked, fb = mc
-                    i = mk[j]
-                    valid_j = mvalid[j]
-                    pod = pod_view(pods, i)
-                    cls = jnp.clip(pods.class_id[i], 0, c_dim - 1)
-                    prev = (arange_k < j) & (picked >= 0)
-                    # picked holds GLOBAL node ids; sharded, the row
-                    # gathers below replicate the K picked rows to every
-                    # shard so the correction math (and the choice) is
-                    # identical everywhere — no per-pod election needed
-                    pxc = jnp.clip(picked, 0, n_total - 1)
-                    cap_rows = node_rows(cluster.allocatable, pxc)
-                    req0_rows = node_rows(req0, pxc)
-                    reqc_rows = node_rows(req_c, pxc)
-                    skip = (pod.req[None, :] <= 0)
-                    fits0 = (
-                        skip | (req0_rows + pod.req[None, :] <= cap_rows)
-                    ).all(-1)
-                    fitsc = (
-                        skip | (reqc_rows + pod.req[None, :] <= cap_rows)
-                    ).all(-1)
-                    flip = (
-                        prev & node_rows(sfeas_c[cls], pxc)
-                        & (fits0 != fitsc)
-                    ).any() & valid_j
+            def mini(j, mc):
+                req_c, nz_c, picked, fb, w_k, c_k, r_k = mc
+                i = mk[j]
+                valid_j = mvalid[j]
+                pod = pod_view(pods, i)
+                cls = jnp.clip(pods.class_id[i], 0, c_dim - 1)
+                prev = (arange_k < j) & (picked >= 0)
+                # picked holds GLOBAL node ids; sharded, the row
+                # gathers below replicate the K picked rows to every
+                # shard so the correction math (and the choice) is
+                # identical everywhere — no per-pod election needed
+                pxc = jnp.clip(picked, 0, n_total - 1)
+                cap_rows = node_rows(cluster.allocatable, pxc)
+                req0_rows = node_rows(req0, pxc)
+                reqc_rows = node_rows(req_c, pxc)
+                skip = (pod.req[None, :] <= 0)
+                fits0 = (
+                    skip | (req0_rows + pod.req[None, :] <= cap_rows)
+                ).all(-1)
+                fitsc = (
+                    skip | (reqc_rows + pod.req[None, :] <= cap_rows)
+                ).all(-1)
+                flip = (
+                    prev & node_rows(sfeas_c[cls], pxc)
+                    & (fits0 != fitsc)
+                ).any() & valid_j
 
-                    def full(_):
-                        # exact re-evaluation against the live carry:
-                        # ports/spread/terms are wave-start but untouched
-                        # within a safe wave, so this IS the sequential
-                        # state
-                        clj = cluster._replace(
-                            requested=req_c, nonzero_requested=nz_c
-                        )
-                        _, masked, found, reason, cnt = _eval_pod(
-                            clj, pods, i, cls, sfeas_c, aff_c, taint_c,
-                            extra_c, new_ports, sp, tm, spread, terms,
-                            features, cfg, axis_name=axis_name,
-                        )
-                        found = found & valid_j
-                        if axis_name is None:
-                            choice = jnp.argmax(masked).astype(jnp.int32)
-                            win = jnp.where(found, masked[choice], NEG_INF)
-                        else:
-                            choice, best = _elect(masked, offset, axis_name)
-                            win = jnp.where(found, best, NEG_INF)
-                        return (choice, win, cnt, reason, found,
-                                jnp.int32(1))
-
-                    def cheap(_):
-                        # sequential scores differ from the wave-start
-                        # vector only at picked nodes, and only in the
-                        # (un-normalized) allocation parts — correct
-                        # those entries in closed form
-                        fit0, bal0 = resource_score_parts(
-                            _rows_cluster(cap_rows, req0_rows,
-                                          node_rows(nz0, pxc)),
-                            pod, cfg,
-                        )
-                        fitc, balc = resource_score_parts(
-                            _rows_cluster(cap_rows, reqc_rows,
-                                          node_rows(nz_c, pxc)),
-                            pod, cfg,
-                        )
-                        d_alloc = (
-                            cfg.fit_weight * (fitc - fit0)
-                            + cfg.balanced_weight * (balc - bal0)
-                        )
-                        base = node_rows(masked_k[j], pxc)
-                        cand_ok = prev & (base > NEG_INF)
-                        cand_val = base + d_alloc
-                        tv, ti = topv[j], topi[j]
-                        ispicked = (
-                            (ti[:, None] == pxc[None, :]) & prev[None, :]
-                        ).any(-1)
-                        un_ok = ~ispicked & (tv > NEG_INF)
-                        first = jnp.argmax(un_ok)
-                        has_un = un_ok.any()
-                        bu_val = jnp.where(has_un, tv[first], NEG_INF)
-                        bu_idx = jnp.where(has_un, ti[first], n_total).astype(
-                            jnp.int32
-                        )
-                        vals = jnp.concatenate(
-                            [jnp.where(cand_ok, cand_val, NEG_INF),
-                             bu_val[None]]
-                        )
-                        idxs = jnp.concatenate([pxc, bu_idx[None]])
-                        best = jnp.max(vals)
-                        found = found_k[j] & valid_j & (best > NEG_INF)
-                        # first-max-index over the candidate union ==
-                        # first-max-index over the corrected [N] vector
-                        choice = jnp.min(
-                            jnp.where((vals >= best) & (vals > NEG_INF),
-                                      idxs, n_total)
-                        ).astype(jnp.int32)
-                        return (
-                            choice, jnp.where(found, best, NEG_INF),
-                            cnt_k[j], reason_k[j], found, jnp.int32(0),
-                        )
-
-                    choice, win, cnt, reason, found, used_full = (
-                        jax.lax.cond(flip, full, cheap, None)
-                    )
-                    cc = jnp.clip(choice, 0, n_total - 1)
-                    if axis_name is None:
-                        tgt = cc
-                    else:
-                        # the owning shard's local row; everyone else
-                        # scatters out of bounds (dropped)
-                        in_sh = (cc >= offset) & (cc < offset + n)
-                        tgt = jnp.where(in_sh, cc - offset, n)
-                    wgt = found.astype(req_c.dtype)
-                    req_c = req_c.at[tgt].add(pod.req * wgt)
-                    nz_c = nz_c.at[tgt].add(pod.nonzero_req * wgt)
-                    picked = picked.at[j].set(jnp.where(found, cc, -1))
-                    out = (jnp.where(found, cc, -1).astype(jnp.int32),
-                           win, cnt, reason)
-                    return (req_c, nz_c, picked, fb + used_full), out
-
-                (req2, nz2, picked, fb), (a_k, w_k, c_k, r_k) = jax.lax.scan(
-                    mini,
-                    (requested, nonzero,
-                     jnp.full(k_dim, -1, jnp.int32), jnp.int32(0)),
-                    arange_k,
-                )
-                # deferred dynamic-state updates: no member read these, so
-                # they commit batched at wave end (adds/ORs commute)
-                ports2 = new_ports
-                if features.ports:
-                    okp = picked >= 0
-                    if axis_name is None:
-                        tgt = jnp.where(okp, picked, n)  # OOB rows drop
-                    else:
-                        own = okp & (picked >= offset) & (
-                            picked < offset + n
-                        )
-                        tgt = jnp.where(own, picked - offset, n)
-                    bits = pods.port_bits[mk] * okp[:, None].astype(
-                        jnp.uint32
-                    )
-                    ports2 = new_ports.at[tgt].add(bits)
-                spc2 = sp_counts
-                if features.spread:
-                    # unrolled so XLA fuses the K count-updates into one
-                    # pass over [C, N] instead of K carried array writes
-                    st = sp0._replace(counts_node=sp_counts)
-                    for j in range(k_dim):
-                        ch = jnp.clip(a_k[j], 0, n_total - 1)
-                        st = spread_update(
-                            st, spread, mk[j], node_col(st.v, ch),
-                            node_col(st.eligible, ch), a_k[j] >= 0,
-                        )
-                    spc2 = st.counts_node
-                pr2, bl2, ga2 = tm_present, tm_blocked, tm_global
-                if features.interpod:
-                    st = tm0._replace(
-                        present_bits=tm_present, blocked_bits=tm_blocked,
-                        global_any=tm_global,
-                    )
-                    for j in range(k_dim):
-                        ch = jnp.clip(a_k[j], 0, n_total - 1)
-                        st = interpod_update(
-                            st, terms, mk[j], node_rows(cluster.topo_ids, ch),
-                            a_k[j] >= 0, slots=features.term_slots,
-                        )
-                    pr2, bl2, ga2 = (
-                        st.present_bits, st.blocked_bits, st.global_any
-                    )
-                return ((req2, nz2, ports2, spc2, pr2, bl2, ga2, fb),
-                        (a_k, w_k, c_k, r_k))
-
-            def serial(_):
-                # unsafe wave (in-wave coupling): run the original scan
-                # step over the members — exact by construction
-                def sstep(c, j):
-                    (req_c, nz_c, ports_c, spc, pr, bl, ga) = c
-                    i = mk[j]
-                    valid_j = mvalid[j]
+                def full(_):
+                    # exact re-evaluation against the live carry:
+                    # ports/spread/terms are wave-start but untouched
+                    # within a safe wave, so this IS the sequential
+                    # state
                     clj = cluster._replace(
                         requested=req_c, nonzero_requested=nz_c
                     )
-                    spj = tmj = None
-                    if features.spread:
-                        spj = sp0._replace(counts_node=spc)
-                    if features.interpod:
-                        tmj = tm0._replace(
-                            present_bits=pr, blocked_bits=bl, global_any=ga
-                        )
-                    cls = jnp.clip(pods.class_id[i], 0, c_dim - 1)
-                    pod = pod_view(pods, i)
                     _, masked, found, reason, cnt = _eval_pod(
                         clj, pods, i, cls, sfeas_c, aff_c, taint_c,
-                        extra_c, ports_c, spj, tmj, spread, terms,
+                        extra_c, new_ports, sp, tm, spread, terms,
                         features, cfg, axis_name=axis_name,
                     )
                     found = found & valid_j
@@ -1508,68 +1395,228 @@ def wavefront_assign(
                     else:
                         choice, best = _elect(masked, offset, axis_name)
                         win = jnp.where(found, best, NEG_INF)
-                    cc = jnp.clip(choice, 0, n_total - 1)
-                    onehot = ((jnp.arange(n) + offset) == cc) & found
-                    wgt = found.astype(req_c.dtype)
-                    req_c = req_c + onehot[:, None] * pod.req[None, :] * wgt
-                    nz_c = (
-                        nz_c + onehot[:, None] * pod.nonzero_req[None, :] * wgt
-                    )
-                    if features.ports:
-                        ports_c = jnp.where(
-                            onehot[:, None], ports_c | pod.port_bits[None, :],
-                            ports_c,
-                        )
-                    if features.spread:
-                        spj = spread_update(
-                            spj, spread, i, node_col(spj.v, cc),
-                            node_col(spj.eligible, cc), found,
-                        )
-                        spc = spj.counts_node
-                    if features.interpod:
-                        tmj = interpod_update(
-                            tmj, terms, i, node_rows(cluster.topo_ids, cc),
-                            found, slots=features.term_slots,
-                        )
-                        pr, bl, ga = (
-                            tmj.present_bits, tmj.blocked_bits,
-                            tmj.global_any,
-                        )
-                    out = (jnp.where(found, cc, -1).astype(jnp.int32),
-                           win, cnt, reason)
-                    return (req_c, nz_c, ports_c, spc, pr, bl, ga), out
+                    return (choice, win, cnt, reason, found,
+                            jnp.int32(1))
 
-                (req2, nz2, ports2, spc2, pr2, bl2, ga2), outs = (
-                    jax.lax.scan(
-                        sstep,
-                        (requested, nonzero, new_ports, sp_counts,
-                         tm_present, tm_blocked, tm_global),
-                        arange_k,
+                def cheap(_):
+                    # sequential scores differ from the wave-start
+                    # vector only at picked nodes, and only in the
+                    # (un-normalized) allocation parts — correct
+                    # those entries in closed form
+                    fit0, bal0 = resource_score_parts(
+                        _rows_cluster(cap_rows, req0_rows,
+                                      node_rows(nz0, pxc)),
+                        pod, cfg,
                     )
+                    fitc, balc = resource_score_parts(
+                        _rows_cluster(cap_rows, reqc_rows,
+                                      node_rows(nz_c, pxc)),
+                        pod, cfg,
+                    )
+                    d_alloc = (
+                        cfg.fit_weight * (fitc - fit0)
+                        + cfg.balanced_weight * (balc - bal0)
+                    )
+                    base = node_rows(masked_k[j], pxc)
+                    cand_ok = prev & (base > NEG_INF)
+                    cand_val = base + d_alloc
+                    tv, ti = topv[j], topi[j]
+                    ispicked = (
+                        (ti[:, None] == pxc[None, :]) & prev[None, :]
+                    ).any(-1)
+                    un_ok = ~ispicked & (tv > NEG_INF)
+                    first = jnp.argmax(un_ok)
+                    has_un = un_ok.any()
+                    bu_val = jnp.where(has_un, tv[first], NEG_INF)
+                    bu_idx = jnp.where(has_un, ti[first], n_total).astype(
+                        jnp.int32
+                    )
+                    vals = jnp.concatenate(
+                        [jnp.where(cand_ok, cand_val, NEG_INF),
+                         bu_val[None]]
+                    )
+                    idxs = jnp.concatenate([pxc, bu_idx[None]])
+                    best = jnp.max(vals)
+                    found = found_k[j] & valid_j & (best > NEG_INF)
+                    # first-max-index over the candidate union ==
+                    # first-max-index over the corrected [N] vector
+                    choice = jnp.min(
+                        jnp.where((vals >= best) & (vals > NEG_INF),
+                                  idxs, n_total)
+                    ).astype(jnp.int32)
+                    return (
+                        choice, jnp.where(found, best, NEG_INF),
+                        cnt_k[j], reason_k[j], found, jnp.int32(0),
+                    )
+
+                choice, win, cnt, reason, found, used_full = (
+                    jax.lax.cond(flip, full, cheap, None)
                 )
-                return ((req2, nz2, ports2, spc2, pr2, bl2, ga2,
-                         mvalid.sum().astype(jnp.int32)), outs)
+                cc = jnp.clip(choice, 0, n_total - 1)
+                if axis_name is None:
+                    tgt = cc
+                else:
+                    # the owning shard's local row; everyone else
+                    # scatters out of bounds (dropped)
+                    in_sh = (cc >= offset) & (cc < offset + n)
+                    tgt = jnp.where(in_sh, cc - offset, n)
+                wgt = found.astype(req_c.dtype)
+                req_c = req_c.at[tgt].add(pod.req * wgt)
+                nz_c = nz_c.at[tgt].add(pod.nonzero_req * wgt)
+                picked = picked.at[j].set(jnp.where(found, cc, -1))
+                return (req_c, nz_c, picked, fb + used_full,
+                        w_k.at[j].set(win), c_k.at[j].set(cnt),
+                        r_k.at[j].set(reason))
 
-            safe = wave_safe(mk, mvalid)
-            (req2, nz2, ports2, spc2, pr2, bl2, ga2, fb), outs = (
-                jax.lax.cond(safe, fast, serial, None)
+            a0_k, w0_k, c0_k, r0_k = lane_rows()
+            req2, nz2, picked, fb, w_k, c_k, r_k = jax.lax.fori_loop(
+                0, m_last, mini,
+                (requested, nonzero, a0_k, jnp.int32(0), w0_k, c0_k, r0_k),
             )
-            return ((req2, nz2, ports2, spc2, pr2, bl2, ga2,
-                     n_fb + fb, n_waves + 1), outs)
+            a_k = picked  # a lane's placement IS its picked node (-1: none)
+            # deferred dynamic-state updates: no member read these, so
+            # they commit batched at wave end (adds/ORs commute)
+            ports2 = new_ports
+            if features.ports:
+                okp = picked >= 0
+                if axis_name is None:
+                    tgt = jnp.where(okp, picked, n)  # OOB rows drop
+                else:
+                    own = okp & (picked >= offset) & (
+                        picked < offset + n
+                    )
+                    tgt = jnp.where(own, picked - offset, n)
+                bits = pods.port_bits[mk] * okp[:, None].astype(
+                    jnp.uint32
+                )
+                ports2 = new_ports.at[tgt].add(bits)
+            spc2 = sp_counts
+            if features.spread:
+                # unrolled so XLA fuses the K count-updates into one
+                # pass over [C, N] instead of K carried array writes
+                st = sp0._replace(counts_node=sp_counts)
+                for j in range(k_dim):
+                    ch = jnp.clip(a_k[j], 0, n_total - 1)
+                    st = spread_update(
+                        st, spread, mk[j], node_col(st.v, ch),
+                        node_col(st.eligible, ch), a_k[j] >= 0,
+                    )
+                spc2 = st.counts_node
+            pr2, bl2, ga2 = tm_present, tm_blocked, tm_global
+            if features.interpod:
+                st = tm0._replace(
+                    present_bits=tm_present, blocked_bits=tm_blocked,
+                    global_any=tm_global,
+                )
+                for j in range(k_dim):
+                    ch = jnp.clip(a_k[j], 0, n_total - 1)
+                    st = interpod_update(
+                        st, terms, mk[j], node_rows(cluster.topo_ids, ch),
+                        a_k[j] >= 0, slots=features.term_slots,
+                    )
+                pr2, bl2, ga2 = (
+                    st.present_bits, st.blocked_bits, st.global_any
+                )
+            return ((req2, nz2, ports2, spc2, pr2, bl2, ga2, fb, m_last),
+                    (a_k, w_k, c_k, r_k))
+
+        def sstep(c, j):
+            """The original scan step for lane j against carry c — the
+            body of a serialized wave and, alone, of a one-member wave."""
+            (req_c, nz_c, ports_c, spc, pr, bl, ga) = c
+            i = mk[j]
+            valid_j = mvalid[j]
+            clj = cluster._replace(
+                requested=req_c, nonzero_requested=nz_c
+            )
+            spj = tmj = None
+            if features.spread:
+                spj = sp0._replace(counts_node=spc)
+            if features.interpod:
+                tmj = tm0._replace(
+                    present_bits=pr, blocked_bits=bl, global_any=ga
+                )
+            cls = jnp.clip(pods.class_id[i], 0, c_dim - 1)
+            pod = pod_view(pods, i)
+            _, masked, found, reason, cnt = _eval_pod(
+                clj, pods, i, cls, sfeas_c, aff_c, taint_c,
+                extra_c, ports_c, spj, tmj, spread, terms,
+                features, cfg, axis_name=axis_name,
+            )
+            found = found & valid_j
+            if axis_name is None:
+                choice = jnp.argmax(masked).astype(jnp.int32)
+                win = jnp.where(found, masked[choice], NEG_INF)
+            else:
+                choice, best = _elect(masked, offset, axis_name)
+                win = jnp.where(found, best, NEG_INF)
+            cc = jnp.clip(choice, 0, n_total - 1)
+            onehot = ((jnp.arange(n) + offset) == cc) & found
+            wgt = found.astype(req_c.dtype)
+            req_c = req_c + onehot[:, None] * pod.req[None, :] * wgt
+            nz_c = (
+                nz_c + onehot[:, None] * pod.nonzero_req[None, :] * wgt
+            )
+            if features.ports:
+                ports_c = jnp.where(
+                    onehot[:, None], ports_c | pod.port_bits[None, :],
+                    ports_c,
+                )
+            if features.spread:
+                spj = spread_update(
+                    spj, spread, i, node_col(spj.v, cc),
+                    node_col(spj.eligible, cc), found,
+                )
+                spc = spj.counts_node
+            if features.interpod:
+                tmj = interpod_update(
+                    tmj, terms, i, node_rows(cluster.topo_ids, cc),
+                    found, slots=features.term_slots,
+                )
+                pr, bl, ga = (
+                    tmj.present_bits, tmj.blocked_bits,
+                    tmj.global_any,
+                )
+            out = (jnp.where(found, cc, -1).astype(jnp.int32),
+                   win, cnt, reason)
+            return (req_c, nz_c, ports_c, spc, pr, bl, ga), out
+
+        state0 = (requested, nonzero, new_ports, sp_counts,
+                  tm_present, tm_blocked, tm_global)
+
+        def step_into(j, c_rows):
+            c, rows = c_rows
+            c, out = sstep(c, j)
+            return c, tuple(r.at[j].set(o) for r, o in zip(rows, out))
+
+        def serial(_):
+            # unsafe wave (in-wave coupling): run the original scan
+            # step over the members — exact by construction
+            c, outs = jax.lax.fori_loop(
+                0, m_last, step_into, (state0, lane_rows())
+            )
+            return c + (n_members, m_last), outs
+
+        def single(_):
+            # one member: nothing in the wave to batch or to correct, so
+            # the wave is that member's scan step — no [K, N] evaluation,
+            # no top-k, no safety check; not a fallback
+            c, outs = step_into(
+                jnp.argmax(mvalid).astype(jnp.int32), (state0, lane_rows())
+            )
+            return c + (jnp.int32(0), jnp.int32(1)), outs
+
+        def many(_):
+            return jax.lax.cond(wave_safe(mk, mvalid), fast, serial, None)
 
         def skip_wave(_):
-            outs = (
-                jnp.full(k_dim, -1, jnp.int32),
-                jnp.full(k_dim, NEG_INF),
-                jnp.zeros(k_dim, jnp.int32),
-                jnp.full(k_dim, REASON_NONE, jnp.int32),
-            )
-            return ((requested, nonzero, new_ports, sp_counts, tm_present,
-                     tm_blocked, tm_global, n_fb, n_waves), outs)
+            return state0 + (jnp.int32(0), jnp.int32(0)), lane_rows()
 
-        new_carry, outs = jax.lax.cond(
-            mvalid.any(), run_wave, skip_wave, None
+        (*state, fb, steps), outs = jax.lax.switch(
+            jnp.minimum(n_members, 2), (skip_wave, single, many), None
         )
+        new_carry = (*state, n_fb + fb, n_waves + jnp.minimum(n_members, 1),
+                     n_steps + steps)
         return new_carry, outs
 
     zero = jnp.zeros(())
@@ -1583,8 +1630,9 @@ def wavefront_assign(
         tm0.global_any if features.interpod else zero,
         jnp.int32(0),
         jnp.int32(0),
+        jnp.int32(0),
     )
-    (requested, nonzero, new_ports, *_rest, n_fb, n_waves), (
+    (requested, nonzero, new_ports, *_rest, n_fb, n_waves, n_steps), (
         assign_w, win_w, cnt_w, reason_w
     ) = jax.lax.scan(wave_step, init, wave_members)
 
@@ -1613,7 +1661,7 @@ def wavefront_assign(
     )
     return SolveResult(
         assignment, win_scores, feas_counts, final, reasons,
-        wave_count=n_waves, wave_fallbacks=n_fb,
+        wave_count=n_waves, wave_fallbacks=n_fb, wave_steps=n_steps,
     )
 
 

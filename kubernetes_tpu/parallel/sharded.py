@@ -257,8 +257,9 @@ def sharded_wavefront_assign(
     statics: Optional[ClassStatics] = None,
 ) -> SolveResult:
     """wavefront_assign with the node axis sharded over `mesh` — the
-    production mesh route for large greedy batches: ~P/W wave steps
-    instead of P, each wave evaluated on all chips in parallel.
+    production mesh route for large greedy batches: one [K, N]
+    evaluation a wave, on all chips in parallel, and a light step a
+    member (a one-member wave is the scan's own step).
 
     The wave plan stays a replicated host-side device argument
     (plan_waves — pod-space only), the batched [K, N] evaluation runs
@@ -281,7 +282,7 @@ def sharded_wavefront_assign(
     out_specs = SolveResult(
         assignment=rep, scores=rep, feasible_counts=rep,
         cluster=CLUSTER_SPECS, reasons=rep, wave_count=rep,
-        wave_fallbacks=rep,
+        wave_fallbacks=rep, wave_steps=rep,
     )
 
     if statics is None:
@@ -673,7 +674,7 @@ def podsharded_wavefront_assign(
     out_specs = SolveResult(
         assignment=rep, scores=rep, feasible_counts=rep,
         cluster=rep_cluster, reasons=rep, wave_count=rep,
-        wave_fallbacks=rep,
+        wave_fallbacks=rep, wave_steps=rep,
     )
 
     if statics is None:

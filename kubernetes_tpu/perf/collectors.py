@@ -185,6 +185,7 @@ class MetricsCollector:
         "scheduler_commit_wave_size_pods",
         "scheduler_solve_wave_count",
         "scheduler_solve_wave_fallbacks",
+        "scheduler_solve_wave_steps",
         "scheduler_preemption_victims",
         # failed pods sharing one batched preemption dry-run
         "scheduler_preemption_batch_size_pods",

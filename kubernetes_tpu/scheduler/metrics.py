@@ -238,7 +238,7 @@ class Registry:
         )
         # OUR solve-side pipeline metrics (no reference analogue):
         # waves per wavefront-routed greedy solve (ops.assign wavefront:
-        # the scan's P sequential steps collapse to ~P/W)
+        # P/32 heavy steps where nothing couples, one a pod where all do)
         self.solve_wave_count = Histogram(
             "scheduler_solve_wave_count",
             buckets=tuple(float(2 ** i) for i in range(13)),
@@ -248,6 +248,13 @@ class Registry:
         # the partitioner is mis-planning for this workload
         self.solve_wave_fallbacks = Histogram(
             "scheduler_solve_wave_fallbacks",
+            buckets=tuple(float(2 ** i) for i in range(13)),
+        )
+        # in-wave sequential steps per wavefront solve: each wave runs to
+        # its last member (a one-member wave is one step), so steps over
+        # pods is 1.0 where the waves hold nothing but members
+        self.solve_wave_steps = Histogram(
+            "scheduler_solve_wave_steps",
             buckets=tuple(float(2 ** i) for i in range(13)),
         )
         # wall seconds of solver executable compiles: synchronous

@@ -1653,9 +1653,11 @@ class Scheduler:
             self.metrics.solve_wave_fallbacks.observe(
                 float(ds.wave_fallbacks or 0)
             )
+            self.metrics.solve_wave_steps.observe(float(ds.wave_steps or 0))
             _trace.event(
                 "sched.solve.waves", now, now, ds.wave_count,
                 a0=float(ds.wave_fallbacks or 0),
+                a1=float(ds.wave_steps or 0),
             )
         if ds.frag_score is not None:
             # slice-family solve: mirror the carve-out telemetry (same

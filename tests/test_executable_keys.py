@@ -80,6 +80,12 @@ def test_no_batch_composition_compiles_after_warmup(cluster, template, route):
         names = tpu.schedule_pending(pods, lock=sched.cache.lock)
         meta = tpu.last_solve.meta
         seen.add(meta.route)
+        if meta.route == "wavefront":
+            # each wave runs at least a step and at most its row's lanes
+            waves, steps = tpu.last_solve.wave_count, tpu.last_solve.wave_steps
+            assert waves <= steps <= waves * assign.DEFAULT_WAVE_CAP
+        else:
+            assert tpu.last_solve.wave_steps is None
         if compileclock.events() != mark:
             fresh.append((b, size, len(nss), meta.route, meta.features.bound_spread,
                           None if meta.statics is None else tuple(meta.statics[0].shape)))
@@ -171,7 +177,7 @@ def test_no_removal_compiles_after_warmup_and_placements_equal_the_oracles(
     try:
         tpu = sched.tpu
         sched.warmup([anti_affinity_pod(f"warm-{i}", "sched-1") for i in range(ANTI_BATCH)])
-        fresh, seen, removed, buckets = [], set(), 0, set()
+        fresh, seen, removed, buckets, full = [], set(), 0, set(), 0
         synced = tpu.state.generation
         for b in range(SWEEP):
             size = rng.randint(1, ANTI_BATCH)
@@ -189,6 +195,12 @@ def test_no_removal_compiles_after_warmup_and_placements_equal_the_oracles(
                 fresh.append((b, size, dirty, tpu.last_solve.meta.route))
             seen.add(tpu.last_solve.meta.route)
             assert names == want, f"batch {b}"
+            if size == ANTI_BATCH:
+                # no pad pod: one wave a pod, each one scan step
+                ds = tpu.last_solve
+                assert (ds.wave_count, ds.wave_fallbacks, ds.wave_steps) == (
+                    size, 0, size)
+                full += 1
             for pod, node in zip(pods, names):
                 if node:
                     pod.spec.node_name = node
@@ -205,9 +217,58 @@ def test_no_removal_compiles_after_warmup_and_placements_equal_the_oracles(
         assert fresh == []
         assert seen == {"greedy", "wavefront"}
         assert removed > 4000
+        assert full >= 1
         # the sweep did ask for scatters past a batch's rows
         assert max(buckets) > ANTI_BATCH
     finally:
         for pod in live:
             sched.cache.remove_pod(pod)
+        sched.stop()
+
+
+def test_wave_steps_reach_the_solve_the_histogram_and_the_recorders_row(
+        anti_affinity_template):
+    """A served toy cycle of 64 mutually repelling pods: what the device
+    counted comes back with the names in the one readback and is written
+    three times: ``DeviceSolve.wave_steps``, the histogram
+    ``scheduler_solve_wave_steps`` and the ``sched.solve.waves`` row
+    (``n`` waves, ``a0`` fallbacks, ``a1`` steps)."""
+    import copy
+    import time
+
+    from kubernetes_tpu.api import kubeyaml
+    from kubernetes_tpu.utils import trace
+
+    store = st.Store()
+    for i in range(96):
+        store.create(
+            make_node(f"node-{i}").capacity(cpu_milli=4000, mem=32 * GI, pods=110).obj()
+        )
+    for i in range(ANTI_BATCH):     # in the store before the informer lists: one pop
+        d = copy.deepcopy(anti_affinity_template)
+        d["metadata"].update(name=f"p-{i}", namespace="sched-1")
+        store.create(kubeyaml.pod_from_dict(d))
+    sched = Scheduler(store, batch_size=ANTI_BATCH)
+    sched.informers.informer("Node").start()
+    sched.informers.informer("Pod").start()
+    try:
+        assert sched.informers.wait_for_sync(10)
+        deadline = time.monotonic() + 10
+        while sched.queue.pending_count() < ANTI_BATCH and time.monotonic() < deadline:
+            time.sleep(0.01)
+        t0 = time.perf_counter()
+        assert sched.schedule_batch(timeout=0.5).get("scheduled", 0) == ANTI_BATCH
+        assert sched.flush_binds(timeout=30)
+        ds = sched.tpu.last_solve
+        assert ds.meta.route == "wavefront"
+        assert (ds.wave_count, ds.wave_fallbacks, ds.wave_steps) == (
+            ANTI_BATCH, 0, ANTI_BATCH)
+        rows = [dict(zip(trace.SPAN_FIELDS, r)) for r in trace.snapshot(t0)["spans"]]
+        row, = [r for r in rows if r["name"] == "sched.solve.waves"]
+        assert (row["n"], row["a0"], row["a1"]) == (ANTI_BATCH, 0.0, float(ANTI_BATCH))
+        hist = sched.metrics.solve_wave_steps
+        assert (hist.n, hist.total) == (1, float(ANTI_BATCH))
+        pods, _ = store.list("Pod")
+        assert len({p.spec.node_name for p in pods}) == ANTI_BATCH  # one a node
+    finally:
         sched.stop()

@@ -359,6 +359,9 @@ SERIES = [
     'scheduler_solve_wave_fallbacks_bucket',
     'scheduler_solve_wave_fallbacks_count',
     'scheduler_solve_wave_fallbacks_sum',
+    'scheduler_solve_wave_steps_bucket',
+    'scheduler_solve_wave_steps_count',
+    'scheduler_solve_wave_steps_sum',
     'scheduler_speculative_solves_total',
     'scheduler_store_checkpoints_total',
     'scheduler_store_journal_suffix_records',

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import wave_cases
 
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.ops import assign, preemption, schema
@@ -147,6 +148,34 @@ def test_podsharded_wavefront_serialized_waves_parity():
         snap, members, sharded.make_pod_mesh(8)
     )
     _assert_solve_equal(single, multi)
+
+
+# -- a wave costs what its members cost, under the pod shard ----------
+#
+# The loops' trip count and the one-member branch are functions of the
+# replicated plan, so every shard takes the same branch and runs the same
+# trips; results and all three counters must equal the single chip's.
+
+
+@pytest.mark.parametrize(
+    "name", ["repel-64", "repel-64-owners", "widths", "holes"]
+)
+def test_podsharded_wave_steps_follow_the_members(name):
+    snap, members, want = wave_cases.step_case(name)
+    scan = assign.greedy_assign(snap)
+    single = assign.wavefront_assign(snap, members)
+    multi = sharded.podsharded_wavefront_assign(snap, members, sharded.make_pod_mesh(8))
+    for res in (single, multi):
+        for field in ("assignment", "scores", "feasible_counts", "reasons"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(scan, field)),
+                np.asarray(getattr(res, field)), err_msg=field,
+            )
+        np.testing.assert_array_equal(
+            np.asarray(scan.cluster.requested),
+            np.asarray(res.cluster.requested),
+        )
+        assert wave_cases.counters(res) == want
 
 
 def test_podsharded_wavefront_mesh_sizes():

@@ -6,6 +6,7 @@ Runs on the 8-virtual-device CPU mesh from conftest.py.
 import jax
 import numpy as np
 import pytest
+import wave_cases
 
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.ops import assign, schema
@@ -265,6 +266,34 @@ def test_sharded_wavefront_serialized_waves_parity():
         np.asarray(single.assignment), np.asarray(multi.assignment)
     )
     assert int(single.wave_fallbacks) == int(multi.wave_fallbacks)
+
+
+# -- a wave costs what its members cost, under the node shard ---------
+#
+# The loops' trip count and the one-member branch are functions of the
+# replicated plan, so every shard takes the same branch and runs the same
+# trips; results and all three counters must equal the single chip's.
+
+
+@pytest.mark.parametrize(
+    "name", ["repel-64", "repel-64-owners", "widths", "holes"]
+)
+def test_sharded_wave_steps_follow_the_members(name):
+    snap, members, want = wave_cases.step_case(name)
+    scan = assign.greedy_assign(snap)
+    single = assign.wavefront_assign(snap, members)
+    multi = sharded.sharded_wavefront_assign(snap, members, sharded.make_mesh(8))
+    for res in (single, multi):
+        for field in ("assignment", "scores", "feasible_counts", "reasons"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(scan, field)),
+                np.asarray(getattr(res, field)), err_msg=field,
+            )
+        np.testing.assert_array_equal(
+            np.asarray(scan.cluster.requested),
+            np.asarray(res.cluster.requested),
+        )
+        assert wave_cases.counters(res) == want
 
 
 def test_sharded_wavefront_and_greedy_gang_release_parity():

@@ -11,6 +11,7 @@ tests also drive hostile hand-built partitions.
 
 import numpy as np
 import pytest
+import wave_cases
 
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.ops import assign, schema
@@ -318,3 +319,67 @@ def test_random_partitions_are_exact(seed):
         members[wi, : len(ch)] = ch
     wave = assign.wavefront_assign_jit()(snap, wave_members=members)
     assert_parity(scan, wave, len(pods))
+
+
+# -- a wave costs what its members cost (wave_steps) -------------------------
+#
+# The in-wave loops run to the wave's last valid lane and a one-member
+# wave is the scan's own step, so `wave_steps` (the in-wave sequential
+# steps the device ran) is the members' sum — and every result below
+# must stay the scan's, bit for bit.
+
+
+@pytest.mark.parametrize(
+    "name",
+    ["repel-64", "repel-64-owners", "repel-128", "repel-128-owners",
+     "widths", "holes"],
+)
+def test_wave_steps_follow_the_members(name):
+    snap, members, want = wave_cases.step_case(name)
+    scan = assign.greedy_assign_jit()(snap)
+    wave = assign.wavefront_assign_jit()(snap, wave_members=members)
+    wave_cases.assert_bit_parity(scan, wave)
+    assert wave_cases.counters(wave) == want
+    if name.startswith("repel"):
+        # one pod a node, and none beside a bound owner of the term
+        placed = np.asarray(wave.assignment).tolist()
+        assert min(placed) >= 0 and len(set(placed)) == len(placed)
+        if name.endswith("owners"):
+            owned = {3 * i for i in range(wave_cases.REPEL_OWNERS)}
+            assert not owned & set(placed)
+
+
+def test_coupled_wave_of_three_is_three_serial_steps():
+    """Three mutually repelling pods forced into one wave: the safety
+    check serializes it, 3 steps and 3 fallbacks; the lone waves beside
+    it are a step each and no fallback."""
+    pods = [wave_cases.repelling(f"p{i}").obj() for i in range(8)]
+    snap, _ = schema.SnapshotBuilder().build(wave_cases.big_nodes(8), pods)
+    o = wave_cases.solve_order(snap)
+    members = wave_cases.plan_of([o[0:3]] + [[i] for i in o[3:]])
+    scan = assign.greedy_assign_jit()(snap)
+    wave = assign.wavefront_assign_jit()(snap, wave_members=members)
+    wave_cases.assert_bit_parity(scan, wave)
+    assert wave_cases.counters(wave) == (6, 3, 3 + 5)
+
+
+def test_fit_flip_inside_a_five_member_wave_still_reevaluates():
+    """test_fit_flip_forces_full_reeval's nodes under a 5-member wave:
+    the flips are still found inside the shortened loop and counted as
+    they were (4 on the parent's 32-step loop: pinned)."""
+    nodes = [
+        make_node("n0").capacity(cpu_milli=1000, mem=2 * GI, pods=110).obj(),
+        make_node("n1").capacity(cpu_milli=700, mem=2 * GI, pods=110).obj(),
+    ]
+    pods = [
+        make_pod(f"p{i}").req(cpu_milli=600, mem=256 * MI).obj()
+        for i in range(8)
+    ]
+    snap, _ = schema.SnapshotBuilder().build(nodes, pods)
+    o = wave_cases.solve_order(snap)
+    scan = assign.greedy_assign_jit()(snap)
+    wave = assign.wavefront_assign_jit()(
+        snap, wave_members=wave_cases.plan_of([o[:5], o[5:]])
+    )
+    wave_cases.assert_bit_parity(scan, wave)
+    assert wave_cases.counters(wave) == (2, 4, 5 + 3)
