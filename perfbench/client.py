@@ -22,6 +22,7 @@ class WatchClient:
         self.bind_log: list = []     # (t, rv, ns, name, node) in arrival order
         self.rebound: list = []      # (key, first node, other node)
         self.gone: dict = {}         # (ns, name) -> (t, rv): bound pods seen deleted
+        self.gone_pending: dict = {}     # (ns, name) -> (t, rv): pods seen deleted unbound
         self.rv_regressions = 0
         self.events = 0
         self.expired = 0
@@ -101,3 +102,5 @@ class WatchClient:
                 self._learn(self.clock(), rv, ns, name, node)
                 if kind == "DELETED":
                     self.gone.setdefault((ns, name), (self.clock(), rv))
+            elif kind == "DELETED":
+                self.gone_pending.setdefault((ns, name), (self.clock(), rv))

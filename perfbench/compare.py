@@ -24,6 +24,12 @@ The deletions the harness asked for and had acknowledged are its own truth
 and not the program's: one of them that the client never saw, and a deletion
 the client saw that nobody asked for, are counted each under its own name
 where the mix deletes at all.
+
+A role that no node of the empty cluster admits, by the rules alone, is not
+meant to bind: its pods are left out of `unbound`, one of them seen bound at
+any time counts under `bound_unplaceable`, and each has to read back, unbound,
+from the recovered store.  Which roles those are is asked of the fresh ledger
+before the replay, never of what the program did.
 """
 
 from __future__ import annotations
@@ -42,12 +48,17 @@ def compare(deployment, created, client, recovered, solves, deleted=None) -> dic
     was acknowledged; None where the mix deletes nothing.
     Returns {"checks": {name: [value, limit]}, "correct": bool}."""
     ledger = reference.Ledger(deployment.nodes(), deployment.templates)
+    unplaceable = reference.unplaceable_roles(deployment, ledger)
     role_of = {(ns, name): role for ns, name, role in created}
     seen = {key: node for key, (_, node, _) in client.bound.items()}
     bind_rv = {key: rv for key, (_, _, rv) in client.bound.items()}
     asked = set(deleted or ())
 
-    unbound = sum(1 for key in role_of if key not in seen)
+    unbound = sum(1 for key, role in role_of.items()
+                  if key not in seen and role not in unplaceable)
+    # pods of a role no node can hold: those left pending, as they should be
+    pending = [key for key, role in role_of.items()
+               if key not in seen and role in unplaceable]
     stray = sum(1 for key in seen if key not in role_of)   # binds of pods nobody created
     mine = [key for key in seen if key in role_of]
     stray += sum(1 for key in mine if seen[key] not in ledger.nodes)   # binds to no node
@@ -99,6 +110,9 @@ def compare(deployment, created, client, recovered, solves, deleted=None) -> dic
     journal_diff += sum(
         1 for key, node in recovered.items() if key in asked or (node and key not in seen)
     )
+    # and every pod left pending and never asked away, unbound (one that reads
+    # back with a node is in the sum above)
+    journal_diff += sum(1 for key in pending if key not in asked and key not in recovered)
 
     rule_checks = ledger.checks()
     checks = {
@@ -110,9 +124,14 @@ def compare(deployment, created, client, recovered, solves, deleted=None) -> dic
         "rv_regressions": [client.rv_regressions, 0],
         **rule_checks,
     }
+    if unplaceable:
+        checks["bound_unplaceable"] = [
+            sum(1 for key in seen if role_of.get(key) in unplaceable), 0]
     if deleted is not None:
         checks["deletions_lost"] = [sum(1 for key in asked if key not in client.gone), 0]
-        checks["deletions_unasked"] = [sum(1 for key in client.gone if key not in asked), 0]
+        checks["deletions_unasked"] = [
+            sum(1 for key in client.gone if key not in asked)
+            + sum(1 for key in pending if key in client.gone_pending and key not in asked), 0]
     return {
         "checks": checks,
         "correct": all(v <= lim for v, lim in checks.values()),

@@ -70,6 +70,18 @@ class Deployment:
         self.role_namespaces = {
             role: list(ns) for role, ns in (assumed.get("role_namespaces") or {}).items()
         }
+        # upstream's own word for pods a run does not wait for: the createPods
+        # op that creates a role may say skipWaitToCompletion (ops in order:
+        # the one that collects metrics creates the measured pods, the one
+        # before it the init pods)
+        ops = [op for op in config["test_case"]["workloadTemplate"]
+               if op.get("opcode") == "createPods"]
+        measured = next((op for op in ops if op.get("collectMetrics")), ops[-1])
+        by_role = {"measure": measured,
+                   "init": next((op for op in ops if op is not measured), {})}
+        self.skip_wait = {
+            role: bool(op.get("skipWaitToCompletion")) for role, op in by_role.items()
+        }
         self.store_args = dict(assumed["store"])
         self.scheduler_args = dict(assumed["scheduler"])
         self.max_fill_share = float(config["max_fill_share"])
@@ -87,6 +99,10 @@ class Deployment:
         meta = dict(t.get("metadata") or {}, name=name, namespace=namespace)
         meta.pop("generateName", None)
         return dict(t, metadata=meta)
+
+    def namespace_of(self, role: str) -> str:
+        """One namespace the role's pods are created in."""
+        return self.role_namespaces.get(role, self.namespaces)[0]
 
     def namespace_walk(self, seed: int, stream: int, role: str = "measure"):
         """An endless walk over the role's namespaces (all of them, where the

@@ -17,7 +17,7 @@ import tempfile
 import time
 
 from . import compare as compare_mod
-from . import rules, tracered, waves
+from . import reference, rules, tracered, waves
 from .client import WatchClient
 from .deployment import Deployment
 from .manifest import ManifestError
@@ -91,6 +91,17 @@ class Setup:
         self.config = manifest.config(cell["config"])
         self.dep = Deployment(self.config, toy=toy)
         rules.require_claimed(self.dep.templates)   # before any load: no rule unchecked
+        # a role that no node of the empty cluster admits, by the rules alone,
+        # is not meant to bind: its pods are created, acknowledged and left
+        self.unplaceable = reference.unplaceable_roles(self.dep)
+        for role in sorted(self.unplaceable):
+            if not self.dep.skip_wait[role]:
+                raise ManifestError(
+                    f"no node of the empty cluster admits a {role} pod of {self.config['name']}, "
+                    "and the createPods op that creates them does not say "
+                    "skipWaitToCompletion: true; a run would wait for binds that cannot come, "
+                    "as upstream's would, so the deployment does not run"
+                )
         self.params = traffic_params(manifest.traffic(cell["traffic"]), toy, overrides)
         self.rec = Recorder()
         self.rec.install_compile_listener()
@@ -99,11 +110,37 @@ class Setup:
         self.system = make_system(system_name, self.dep, self.workdir, self.rec, control)
         self.client = None
         self.init_created: list = []
+        self.left_pending: set = set()      # the keys of set-up's pods that are not meant to bind
         self.phases: dict = {}
 
     def phase(self, name: str, t0: float) -> None:
         self.phases[name] = self.rec.clock() - t0
         log(f"set-up: {name} {self.phases[name]:.2f} s")
+
+    def n_awaited(self, created) -> int:
+        """How many of `created` are pods a run waits to see bound."""
+        return sum(1 for c in created if c[2] not in self.unplaceable)
+
+    def n_bound(self) -> int:
+        """Pods the run waits for that the client has seen bound."""
+        bound = self.client.bound
+        return len(bound) - sum(1 for key in self.left_pending if key in bound)
+
+    def warmup_pods(self, seed: int, n_warm: int) -> list:
+        """Pods of every template that can be pending in the window: the
+        measured one, and each role's that is left pending; `n_warm` of each,
+        turn by turn, so that every bucket `warmup` solves holds them all."""
+        walk = self.dep.namespace_walk(seed, 0)
+        pods = [self.dep.pod("measure", f"warmup-{i}", next(walk)) for i in range(n_warm)]
+        others = sorted(self.unplaceable - {"measure"})
+        if not others:
+            return pods
+        walks = {role: self.dep.namespace_walk(seed, 0, role) for role in others}
+        mixed = []
+        for i, d in enumerate(pods):
+            mixed.append(d)
+            mixed += [self.dep.pod(r, f"warmup-{r}-{i}", next(walks[r])) for r in others]
+        return mixed
 
     def bring_up(self, seed: int) -> None:
         clock = self.rec.clock
@@ -115,10 +152,7 @@ class Setup:
         t = clock()
         n_warm = int(self.params.get("warmup_pods", 0))
         if n_warm:
-            walk = self.dep.namespace_walk(seed, 0)
-            self.system.warmup(
-                [self.dep.pod("measure", f"warmup-{i}", next(walk)) for i in range(n_warm)]
-            )
+            self.system.warmup(self.warmup_pods(seed, n_warm))
         self.phase("warmup", t)
         self.t_warmup_end = clock()
 
@@ -131,7 +165,12 @@ class Setup:
             d = self.dep.pod("init", f"init-{i}", next(init_walk))
             self.system.create(d, "init")
             self.init_created.append((d["metadata"]["namespace"], d["metadata"]["name"], "init"))
-        if not wait_for(lambda: self.client.n_bound() >= len(self.init_created), 600.0):
+        self.left_pending = {c[:2] for c in self.init_created if c[2] in self.unplaceable}
+        # upstream's skipWaitToCompletion: created, acknowledged, and not waited
+        # for here (a role that can bind is still awaited by every later wait)
+        awaited = self.n_awaited(self.init_created)
+        if not self.dep.skip_wait["init"] and not wait_for(
+                lambda: self.n_bound() >= awaited, 600.0):
             raise TimeoutError("the init pods were not all bound within 600 s")
         self.phase("init_pods", t)
 
@@ -148,7 +187,8 @@ class Setup:
                 self.init_created.append(
                     (d["metadata"]["namespace"], d["metadata"]["name"], "measure")
                 )
-            if not wait_for(lambda: self.client.n_bound() >= len(self.init_created), 600.0):
+            awaited = self.n_awaited(self.init_created)
+            if not wait_for(lambda: self.n_bound() >= awaited, 600.0):
                 raise TimeoutError(f"the walk's burst of {n} pods was not bound within 600 s")
         self.phase("bucket_walk", t)
 
@@ -286,8 +326,9 @@ def run_cell(manifest, cell: dict, seed: int, seconds: float, trace: bool, toy: 
         # slow stop_trace used the whole of it up and the run's last pods read
         # as unbound)
         created = setup.init_created + [c[:3] for c in gen.created]
+        awaited = setup.n_awaited(created)
         drained = wait_for(
-            lambda: client.n_bound() >= len(created),
+            lambda: setup.n_bound() >= awaited,
             max(0.0, t_off + float(p["drain_s"]) - clock()),
         )
         # where pods complete, every acknowledged deletion is followed to the
@@ -312,6 +353,9 @@ def run_cell(manifest, cell: dict, seed: int, seconds: float, trace: bool, toy: 
         solves = [c["keys"] for c in sorted(rec.cycles, key=lambda c: c.get("t_decode1", 0.0))
                   if "keys" in c]
         verdict = compare_mod.compare(setup.dep, created, client, recovered, solves, deleted)
+        # the pods left pending, as they should be: never seen bound, read back unbound
+        pending_at_end = sum(1 for key in setup.left_pending
+                             if key not in client.bound and recovered.get(key) == "")
         compare_s = clock() - t_cmp
 
         tr = None
@@ -324,7 +368,7 @@ def run_cell(manifest, cell: dict, seed: int, seconds: float, trace: bool, toy: 
             "cell": cell["name"], "kind": p["kind"], "params": p, "seed": seed,
             "seconds": seconds, "device": device_info(),
             "t_start": t_start, "t_open": t_open, "t_close": t_close,
-            "t_drained": t_drained, "drained": drained, "setup_s": setup_s,
+            "t_off": t_off, "t_drained": t_drained, "drained": drained, "setup_s": setup_s,
             "setup_phases": dict(setup.phases, warm_replay=replay["seconds"]),
             "replay": replay, "t_warmup_end": setup.t_warmup_end,
             "bind_log": list(client.bind_log), "bound": dict(client.bound),
@@ -341,7 +385,7 @@ def run_cell(manifest, cell: dict, seed: int, seconds: float, trace: bool, toy: 
             "counters_open": counters_open, "counters_close": counters_close,
             "trace": tr, "trace_window": (t_trace0, t_trace1),
             "peak_device_bytes": peak, "compare_s": compare_s,
-            "verdict": verdict,
+            "verdict": verdict, "pending_at_end": pending_at_end,
         }
         return record
     finally:

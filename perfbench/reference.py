@@ -54,12 +54,31 @@ class Ledger:
         for r in self.rules:
             r.mark_wave_end()
 
+    def placeable(self, role: str, namespace: str) -> bool:
+        """Does every rule that applies admit such a pod on some node of the
+        cluster as the ledger holds it now?  Asked of a fresh ledger, the
+        empty cluster, it says whether the role can be bound at all: a role
+        that no node can hold then is not meant to bind, whatever a run does."""
+        return any(
+            all(r.admits(role, node, namespace) for r in self.rules) for node in self.nodes
+        )
+
     def checks(self) -> dict:
         """Every rule's numbers, each beside its limit."""
         out = {}
         for r in self.rules:
             out.update(r.checks())
         return out
+
+
+def unplaceable_roles(deployment, ledger: Ledger | None = None) -> frozenset:
+    """The roles whose pods no node of the empty cluster admits, by the
+    deployment's rules alone (`ledger`: a fresh one, where the caller has it)."""
+    ledger = ledger or Ledger(deployment.nodes(), deployment.templates)
+    return frozenset(
+        role for role in deployment.templates
+        if not ledger.placeable(role, deployment.namespace_of(role))
+    )
 
 
 # -- the reference put in the program's place ------------------------------------
@@ -107,6 +126,11 @@ class System:
     97th acknowledged deletion; "delete_lost" acknowledges every 97th deletion
     and does nothing (no event, no journal line, the pod lives on); "once"
     later moves every 101st bound pod to another node.
+
+    Pods of a role that no node of the empty cluster admits are parked: each
+    is acknowledged, emitted as ADDED and journaled with an empty node, and no
+    cycle walks the nodes for it.  Where the deployment has such a role,
+    "unplaceable" binds every 97th of them to the next node all the same.
     """
 
     def __init__(self, deployment, workdir: str, recorder, broken: str | None = None):
@@ -125,7 +149,10 @@ class System:
     def start(self) -> None:
         self._nodes = self.dep.nodes()
         self._ledger = Ledger(self._nodes, self.dep.templates)
+        self._unplaceable = unplaceable_roles(self.dep, self._ledger)
         known = set(STORE_BREAKS) | {r.control for r in self._ledger.rules}
+        if self._unplaceable:
+            known.add("unplaceable")
         if self.broken - known:
             raise ValueError(
                 f"unknown control {sorted(self.broken - known)}; this deployment has {sorted(known)}"
@@ -144,6 +171,7 @@ class System:
         self._cursor = 0
         self._bound = 0
         self._deleted = 0
+        self._parked = 0
         self._f = open(self.journal, "w")
         self._thread.start()
 
@@ -160,11 +188,31 @@ class System:
 
     def create(self, pod: dict, role: str) -> None:
         m = pod["metadata"]
+        if role in self._unplaceable:
+            return self._park(m["namespace"], m["name"], role)
         with self._cv:
             self._rv += 1
             self._emit(("ADDED", m["namespace"], m["name"], "", self._rv))
             self._pending.append((m["namespace"], m["name"], role))
             self._cv.notify()
+
+    def _park(self, ns: str, name: str, role: str) -> None:
+        """A pod no node can hold: acknowledged, durable, and left pending."""
+        with self._mu:
+            self._parked += 1
+            self._f.write(json.dumps([ns, name, ""]) + "\n")
+            with self._cv:
+                self._rv += 1
+                self._emit(("ADDED", ns, name, "", self._rv))
+            if "unplaceable" in self.broken and self._parked % 97 == 0:
+                node = self._names[self._cursor % len(self._names)]
+                self._cursor += 1
+                self._ledger.bind(role, node, ns)
+                self._where[(ns, name)] = (role, node)
+                self._f.write(json.dumps([ns, name, node]) + "\n")
+                with self._cv:
+                    self._rv += 1
+                    self._emit(("MODIFIED", ns, name, node, self._rv))
 
     def delete(self, namespace: str, name: str) -> None:
         """A bound pod completes: its room is free from here on."""

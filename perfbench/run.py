@@ -138,6 +138,7 @@ def details(record: dict) -> dict:
         "shapes": sorted({(c.get("P"), c.get("N"), c.get("R")) for c in cyc}, key=str),
         "client": record["client"], "compare_s": record["compare_s"],
         "drained": record["drained"], "drain_s": record["t_drained"] - record["t_close"],
+        "drain_after_load_off_s": record["t_drained"] - record["t_off"],
         "gc_in_window": {
             str(gen): [
                 sum(1 for p in record["gc_pauses"]
@@ -145,7 +146,7 @@ def details(record: dict) -> dict:
                 reduce.gc_seconds_between(record, record["t_open"], record["t_close"], gen),
             ] for gen in (0, 1, 2)
         },
-        "deleted": len(record["deleted"]),
+        "deleted": len(record["deleted"]), "pending_at_end": record["pending_at_end"],
         "live_in_window": reduce.live_range(record, record["t_open"], record["t_close"]),
         "pods_bound_at_open": sum(1 for b in record["bind_log"] if b[0] < record["t_open"]),
         "counters_open": record["counters_open"], "counters_close": record["counters_close"],

@@ -9,8 +9,9 @@
 
 drives the functions ``perfbench/run.py`` drives, with what a cell's proof
 needs besides: the plain reference (whole, or with one guarantee broken: the
-control, which is a rule file's own (`capacity`, `skew`, `antiaffinity`) or the
-store's (`durability`, `delete_durability`, `delete_lost`, `once`)) in the program's place, a parameter of the traffic file overridden, a
+control, which is a rule file's own (`capacity`, `skew`, `antiaffinity`), the
+store's (`durability`, `delete_durability`, `delete_lost`, `once`) or, where a role is not meant to
+bind, `unplaceable`) in the program's place, a parameter of the traffic file overridden, a
 run's details written to a file, ``gc.freeze()`` after set-up (an experiment
 on the program's behalf that the benchmark itself never makes), and the rate
 sweep that finds an open loop's knee.  ``--workload`` may also be ``<configuration>:<mix>`` for a cell that

@@ -1,8 +1,9 @@
-"""The next deployment's files, unlisted: ``sched-perf-5000n-antiaffinity``
-under ``closed256-live2000``, as a later PR would list it.  The whole reference
-comes out correct there, each control not correct by its own number, the served
-program runs it at toy size, and the copies equal the port's.  And the three
-cells that are listed keep their checks: the same names, limits and order."""
+"""``sched-perf-5000n-antiaffinity`` under ``closed256-live2000``, spelled out
+by hand as PR 31 brought the files (PR 32 listed them; what the entries say is
+in ``test_perfbench_antiaffinity_cell.py``).  The whole reference comes out
+correct there, each control not correct by its own number, the served program
+runs it at toy size, and the copies equal the port's.  And the cells that are
+listed keep their checks: the same names, limits and order."""
 
 import json
 import os
@@ -43,20 +44,6 @@ def failing(verdict):
 
 
 # -- the files ---------------------------------------------------------------------
-
-def test_the_files_are_in_the_tree_and_no_entry_names_them_yet():
-    m = Manifest()
-    assert CONFIG not in {c["name"] for c in DOC["configs"]}
-    assert MIX not in {w["traffic"] for w in DOC["workloads"]}
-    doc = m.config(CONFIG)          # found by its name alone
-    assert doc["name"] == CONFIG and "SchedulingPodAntiAffinity/5000Nodes" in doc["source"]
-    assert set(doc["reduced"]) == {"measurePods"}
-    assert doc["capacity_pods"] == 5000 and "200,000" in doc["capacity_note"]
-    assert set(doc["guarantees"]) == {"bound_exactly_once", "fits", "durable", "rv_monotone",
-                                      "anti_affinity"}
-    assert doc["assumed"]["scheduler"] == {"batch_size": 1024} and doc["assumed"]["pods_complete"]
-    assert doc["assumed"]["store"] == m.config("sched-perf-5000n")["assumed"]["store"]
-
 
 def test_the_mix_is_closed256_and_a_population():
     m = Manifest()
@@ -168,17 +155,20 @@ def test_the_served_program_runs_the_deployment_at_toy_size():
     assert v["checks"]["colocated_pods"] == [0, 0] and len(rec["deleted"]) > 0
 
 
-# -- the three cells that are listed keep their checks -----------------------------
+# -- the cells that are listed keep their checks -----------------------------------
 
 EXPECTED = {
     "perf5k-basic-closed256": {n: [0, 0] for n in ALWAYS},
     "perf5k-basic-steady": {n: [0, 0] for n in ALWAYS},
     "perf5k-spread-closed256": dict({n: [0, 0] for n in ALWAYS}, max_zone_skew=[None, 5]),
+    CELL["name"]: {n: [0, 0] for n in ALWAYS + ["colocated_pods"] + DELETES},
 }
 
 
+# (named `test_a_listed_cell_keeps_...` until PR 34: pytest.ini, which a benchmark PR may not
+# edit, still deselects that name's fourth case from when EXPECTED held three cells)
 @pytest.mark.parametrize("cell", [w["name"] for w in DOC["workloads"]])
-def test_a_listed_cell_keeps_its_checks_names_limits_and_order(cell):
+def test_every_listed_cell_keeps_its_checks_names_limits_and_order(cell):
     rec = run(Manifest().cell(cell), 2**31 + 35)
     v = rec["verdict"]
     want = EXPECTED[cell]
@@ -189,4 +179,7 @@ def test_a_listed_cell_keeps_its_checks_names_limits_and_order(cell):
     # the skew within its 5
     assert v["correct"] is True
     assert all(v["checks"][k] == pair for k, pair in want.items() if pair[0] is not None)
-    assert rec["deleted"] == [] and rec["gone"] == {} and rec["live"] == []
+    if "live_pods" in Manifest().traffic(Manifest().cell(cell)["traffic"]):
+        assert len(rec["deleted"]) > 0 and "bound_unplaceable" not in v["checks"]
+    else:
+        assert rec["deleted"] == [] and rec["gone"] == {} and rec["live"] == []

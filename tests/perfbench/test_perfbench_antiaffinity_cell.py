@@ -33,14 +33,14 @@ CONTROL = "perf5k-basic-closed256"
 TERMS = "interpod_term_rows_per_cycle.backlog"
 REMOVE = "cache_remove_us_per_pod.backlog"
 ROOF = "solve_roofline_interpod.backlog"
+STEPS = "solve_wave_steps_per_pod.backlog"      # PR 34: the three closed cells
 NEW = {TERMS, REMOVE, ROOF}
 # what only a chip holds (its profiler trace, its memory statistics): no CPU run reads these
 DEVICE_ONLY = {"solve_device_us_per_pod.backlog", "device_idle_share.backlog",
                "peak_device_bytes", ROOF}
-# listed for other cells and not for this one: no spread row here, the plain roofline's bytes
-# leave the term tables out, and test_perfbench_events.py pins the third's list
-NOT_HERE = {"spread_rows_per_cycle.backlog", "solve_roofline.backlog",
-            "events_cpu_share.backlog"}
+# listed for other cells and not for this one: no spread row here, and the plain roofline's
+# bytes leave the term tables out
+NOT_HERE = {"spread_rows_per_cycle.backlog", "solve_roofline.backlog"}
 CHECKS = by_hand.ALWAYS + ["colocated_pods"] + by_hand.DELETES
 TALLIED = -1
 
@@ -99,7 +99,13 @@ def test_the_metrics_the_cell_lists_and_those_it_does_not():
     control = set(per_layer(CONTROL))
     assert NEW & control == {TERMS}           # the control reads 0 term rows, removes nothing
     by_name = {x["name"]: x for x in DOC["per_layer"]}
-    assert [x["name"] for x in DOC["per_layer"][-3:]] == [TERMS, REMOVE, ROOF]
+    assert [x["name"] for x in DOC["per_layer"][-4:]] == [TERMS, REMOVE, ROOF, STEPS]
+    assert by_name[STEPS]["workloads"] == [CONTROL, "perf5k-spread-closed256", CELL]
+    assert (by_name[STEPS]["unit"], by_name[STEPS]["better"], by_name[STEPS]["source"]) == (
+        "steps/pod", "lower", "program_counter")
+    assert by_name[STEPS]["layer"] == by_name["solve_waves_per_cycle.backlog"]["layer"]
+    assert by_name[STEPS]["moves"] == "bound_pods_per_s"
+    assert by_name["events_cpu_share.backlog"]["workloads"][-1] == CELL     # appended, PR 34
     assert by_name[TERMS]["workloads"] == [CELL, CONTROL]
     assert by_name[REMOVE]["workloads"] == by_name[ROOF]["workloads"] == [CELL]
     assert (by_name[TERMS]["unit"], by_name[TERMS]["source"]) == ("rows", "program_counter")
@@ -174,6 +180,10 @@ def test_every_listed_per_layer_metric_reads_a_number(toy_record, metric):
         assert value == 1.0         # every pod carries the one term
     if metric == REMOVE:
         assert value > 0.0
+    if metric == STEPS:
+        assert value >= 1.0         # one step a pod is the floor; pads read more
+    if metric == "events_cpu_share.backlog":
+        assert 0.0 <= value <= 100.0
     if metric == "encode_bound_entries_read_per_cycle.backlog":
         assert value >= 24          # the init pods at least: every bound pod is an owner
 
@@ -259,6 +269,32 @@ def test_cache_remove_is_the_tallies_seconds_over_their_pods():
     rec["_programtrace"]["spans"] = spans[2:]       # nothing removed, or no such tally
     assert read(rec) is None
     assert read({"_programtrace": None}) is None
+
+
+def test_wave_steps_per_pod_is_the_rows_steps_over_their_cycles_pods():
+    read = Manifest().reader("per_layer", STEPS)
+
+    def cycle(i, start, pods):
+        return {"id": i, "name": "sched.cycle", "start": start, "end": start + 0.1, "n": pods,
+                "a0": 0.0, "a1": 0.0, "parent": 0, "cycle": i}
+
+    def waves(cyc, start, n, steps):
+        return dict(row("sched.solve.waves", start, n, a1=steps), cycle=cyc, parent=cyc)
+
+    spans = [cycle(7, 101.0, 85), waves(7, 101.05, 86, 128.0),      # 84 single steps, 32 and 12
+             cycle(8, 102.0, 128), waves(8, 102.05, 128, 128.0),    # a full bucket: the floor
+             cycle(9, 103.0, 10),                                   # a greedy cycle: no row
+             waves(5, 100.5, 4, 64.0)]      # its cycle began before the first edge: left out
+    rec = {"_programtrace": {"edges": (100.0, 110.0), "spans": spans}}
+    assert read(rec) == pytest.approx(256.0 / 213.0)
+    rec["_programtrace"]["spans"] = spans[2:4]
+    assert read(rec) == 1.0
+    # the parent's rows carry no step count: nothing, never 0 under a floor of 1
+    rec["_programtrace"]["spans"] = [cycle(8, 102.0, 128), waves(8, 102.05, 128, 0.0)]
+    assert read(rec) is None
+    rec["_programtrace"]["spans"] = [cycle(9, 103.0, 10)]           # no solve took the route
+    assert read(rec) is None
+    assert read({"_programtrace": None}) is None                    # no recorder
 
 
 @pytest.mark.parametrize("shape,expect", [

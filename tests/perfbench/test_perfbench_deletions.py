@@ -43,6 +43,7 @@ class Seen:
     def __init__(self, binds, deletions=()):
         self.bound = {key: (0.0, node, rv) for key, node, rv in binds}
         self.gone = {key: (0.0, rv) for key, rv in deletions}
+        self.gone_pending = {}      # no pod of these runs is deleted unbound
         self.rebound, self.rv_regressions = [], 0
 
 

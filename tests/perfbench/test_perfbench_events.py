@@ -51,7 +51,8 @@ def span(name, start, end, cpu0, cpu1):
 def test_the_two_metrics_are_listed_as_the_issue_names_them():
     mine = [m for m in DOC["per_layer"] if m["name"].startswith("events_cpu_share.")]
     assert sorted((m["name"], m["workloads"]) for m in mine) == [
-        ("events_cpu_share.backlog", ["perf5k-basic-closed256", "perf5k-spread-closed256"]),
+        ("events_cpu_share.backlog", ["perf5k-basic-closed256", "perf5k-spread-closed256",
+                                      "perf5k-antiaffinity-closed256-live2000"]),
         ("events_cpu_share.steady", ["perf5k-basic-steady"])]
     for m in mine:
         assert (m["unit"], m["better"], m["source"]) == ("%", "lower", "program_span")
