@@ -672,25 +672,28 @@ class SolverPrewarmPool:
                 job = self._q.get(timeout=5.0)
             except _q.Empty:
                 return  # idle: let the thread retire; re-spawned on demand
-            if job is None or self._stop:
-                return
-            label, compile_fn = job
-            t0 = time.perf_counter()
             try:
-                compile_fn()
-                self.compiled += 1
-            except Exception:  # noqa: BLE001 — speculative work only
-                self.errors += 1
-                _log.warning(
-                    "prewarm compile failed for %s on %s", label,
-                    device_label(), exc_info=True,
-                )
-                continue
-            if self.compile_observer is not None:
+                if job is None or self._stop:
+                    return
+                label, compile_fn = job
+                t0 = time.perf_counter()
                 try:
-                    self.compile_observer(time.perf_counter() - t0)
-                except Exception:  # noqa: BLE001
-                    pass
+                    compile_fn()
+                    self.compiled += 1
+                except Exception:  # noqa: BLE001 — speculative work only
+                    self.errors += 1
+                    _log.warning(
+                        "prewarm compile failed for %s on %s", label,
+                        device_label(), exc_info=True,
+                    )
+                    continue
+                if self.compile_observer is not None:
+                    try:
+                        self.compile_observer(time.perf_counter() - t0)
+                    except Exception:  # noqa: BLE001
+                        pass
+            finally:
+                self._q.task_done()     # what join() waits for
 
     def offer(self, key, label: str, compile_fn) -> bool:
         """Enqueue a speculative compile if its key is new.  Never
@@ -715,6 +718,20 @@ class SolverPrewarmPool:
                 return False
             self._seen.add(key)
             return True
+
+    def join(self, timeout: float = 120.0) -> bool:
+        """Wait until every job offered so far has been built (or has
+        failed): Scheduler.warmup returns only then, so that nothing is
+        built after it whatever the timing.  False at the timeout."""
+        deadline = time.monotonic() + timeout
+        done = self._q.all_tasks_done
+        with done:
+            while self._q.unfinished_tasks:
+                remaining = deadline - time.monotonic()
+                if remaining <= 0:
+                    return False
+                done.wait(remaining)
+        return True
 
     def close(self, timeout: float = 60.0) -> None:
         self._stop = True

@@ -1097,7 +1097,15 @@ def plan_waves(  # graftlint: disable=purity -- host-side prep: the wave partiti
             tm_acc = np.zeros_like(writes_tm[0])
         demand = np.zeros(req.shape[1], dtype=np.float32)
 
+    # a pod that asks for more than the emptiest node has free fits
+    # nowhere whatever its wave places: it writes no usage row, so it
+    # rides in the open wave and counts for nothing in its demand (alone
+    # in a wave each, a batch of such pods took a plan of one row a pod,
+    # another executable than its bucket's)
+    unplaceable = (req > slack).any(axis=1)
+
     for i in order.tolist():
+        rides = bool(unplaceable[i])
         conflict = len(cur) >= wave_cap
         if not conflict and cur:
             if use_ports and (port_acc & port_bits[i]).any():
@@ -1106,7 +1114,7 @@ def plan_waves(  # graftlint: disable=purity -- host-side prep: the wave partiti
                 conflict = True
             elif use_terms and (tm_acc & reads_tm[i]).any():
                 conflict = True
-            elif ((demand + req[i]) > slack).any():
+            elif not rides and ((demand + req[i]) > slack).any():
                 conflict = True
         if conflict:
             close()
@@ -1117,7 +1125,8 @@ def plan_waves(  # graftlint: disable=purity -- host-side prep: the wave partiti
             sp_acc |= writes_sp[i]
         if use_terms:
             tm_acc |= writes_tm[i]
-        demand += req[i]
+        if not rides:
+            demand += req[i]
     close()
 
     n_waves = len(waves)

@@ -123,17 +123,19 @@ def auction_features_ok(features: FeatureFlags) -> bool:
 
 
 def default_tie_k(snapshot: Snapshot) -> int:  # graftlint: disable=purity -- host-side prep on the pre-transfer snapshot
-    """Tie nodes enumerated per class per round: enough for the LARGEST
-    class to bid distinct nodes (a burst of identical pods would
-    otherwise cram onto tie_k nodes instead of spreading over the tie
-    set), power-of-two bucketed for jit-cache stability, bounded by the
-    node axis."""
+    """Tie nodes enumerated per class per round: enough for a class that
+    holds every valid pod of the batch to bid distinct nodes (a burst of
+    identical pods would otherwise cram onto tie_k nodes instead of
+    spreading over the tie set), power-of-two bucketed, bounded by the
+    node axis.  tie_k is static, so part of the executable's key: sized
+    by the batch's pods, not by its largest class, it follows the pod
+    bucket whatever request shapes fill it.  A pod reads only the first
+    `its class's size` slots of the list and top_k's prefix does not
+    move with k, so the larger size changes no bid."""
     from ..utils.vocab import pad_dim
 
-    cid = np.asarray(snapshot.pods.class_id)
-    live = cid[np.asarray(snapshot.pods.valid)]
-    biggest = int(np.bincount(live).max()) if live.size else 1
-    return min(pad_dim(max(biggest, 64), 1), snapshot.cluster.allocatable.shape[0])
+    pods = int(np.count_nonzero(np.asarray(snapshot.pods.valid)))
+    return min(pad_dim(max(pods, 64), 1), snapshot.cluster.allocatable.shape[0])
 
 
 @hot_path

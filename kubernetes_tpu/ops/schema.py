@@ -423,6 +423,11 @@ class SnapshotBuilder:
         # parity oracle — flip this off to force it.
         self.columnar = True
         self._spec_store = _PodSpecStore()
+        # The least number of spec-class slots a batch gets (_pod_classes):
+        # the distinct spec classes of the templates Scheduler.warmup was
+        # handed, plus the pad rows' slot.  0 follows the batch, which for
+        # a deployment of one template is the same dim (2).
+        self.spec_class_floor = 0
 
     def _transform(self, pod: api.Pod):
         if self.pod_transform is None:
@@ -794,6 +799,25 @@ class SnapshotBuilder:
         return vb.pad_dim(
             max([len(v) for v in self.topo_vocabs.values()] or [1]), 1
         )
+
+    def fix_spec_classes(self, template_pods: Sequence[api.Pod]) -> int:
+        """Raise ``spec_class_floor`` to what `template_pods` fill: their
+        distinct spec rows (_spec_signature, the identity the class
+        signature's columns are encoded from) plus the pad rows' slot
+        (never lowered: a second warm-up with fewer templates keeps the
+        first's).  Every later batch of a subset of those templates then
+        takes the class dim of a batch holding them all, and one
+        executable serves both.  Called by Scheduler.warmup under the
+        cache lock; returns the floor."""
+        specs = set()
+        for pod in template_pods:
+            extra_sel, _extra_req = self._transform(pod)
+            specs.add(self._spec_signature(
+                pod, extra_sel, self.pod_carveout_shape(pod)
+            ))
+        if specs:
+            self.spec_class_floor = max(self.spec_class_floor, len(specs) + 1)
+        return self.spec_class_floor
 
     def build_from_state(
         self,
@@ -1191,6 +1215,7 @@ class SnapshotBuilder:
         class_id, class_rep = _pod_classes(
             valid, name_id, sel_idx, tol_bits, tol_all, port_bits,
             pref_idx, pref_weight, req, nonzero, pod_shape,
+            floor=self.spec_class_floor,
         )
         batch = PodBatch(
             valid=valid,
@@ -1350,6 +1375,7 @@ class SnapshotBuilder:
         class_id, class_rep = _pod_classes(
             valid, name_id, sel_idx, tol_bits, tol_all, port_bits,
             pref_idx, pref_weight, req, nonzero, pod_shape,
+            floor=self.spec_class_floor,
         )
         batch = PodBatch(
             valid=valid,
@@ -2729,6 +2755,7 @@ def _pod_classes(
     req: np.ndarray,
     nonzero_req: np.ndarray,
     pod_shape: Optional[np.ndarray] = None,
+    floor: int = 0,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Group pods into spec-equivalence classes (see PodBatch docstring).
 
@@ -2739,6 +2766,11 @@ def _pod_classes(
     any given cluster state (the joint solver scores per class, not per
     pod).  Spread constraints and inter-pod terms stay per-pod (they
     interact with solver state).
+
+    `floor` is the least number of class slots the batch gets
+    (SnapshotBuilder.spec_class_floor: what the deployment's templates
+    fill, the pad rows' slot included), so that a batch holding one of
+    two request shapes keeps the class dim of a batch holding both.
     """
     p = valid.shape[0]
     sig = np.concatenate(
@@ -2775,7 +2807,7 @@ def _pod_classes(
     # the pad rows' class (valid=False) has its slot whether or not this
     # batch has pad rows: a batch that fills its pod bucket keeps the
     # class dim, and so the executable, of one that does not
-    c_dim = vb.pad_dim(len(reps) + int(bool(valid.all())), 1)
+    c_dim = vb.pad_dim(max(len(reps) + int(bool(valid.all())), floor), 1)
     class_rep = np.full(c_dim, -1, dtype=np.int32)
     class_rep[: len(reps)] = np.asarray(reps, dtype=np.int32)
     return class_id, class_rep

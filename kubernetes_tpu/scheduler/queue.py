@@ -205,6 +205,34 @@ class QueuedPodInfo:
     trace_cycle: int = 0
 
 
+class _Tiers(dict):
+    """Pod key -> tier, and how many keys each tier holds (``depth``),
+    kept as pods move, so that a pop's depth row and ``stats()`` (the
+    pending-pods gauges) read counters and walk no pod.  Written through
+    ``[key] = tier`` and ``pop`` alone."""
+
+    __slots__ = ("depth",)
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.depth: Dict[str, int] = {}
+
+    def __setitem__(self, key: str, tier: str) -> None:
+        depth = self.depth
+        old = self.get(key)
+        if old is not None:
+            depth[old] -= 1
+        depth[tier] = depth.get(tier, 0) + 1
+        super().__setitem__(key, tier)
+
+    def pop(self, key: str, default=None):
+        old = super().pop(key, None)
+        if old is None:
+            return default
+        self.depth[old] -= 1
+        return old
+
+
 class SchedulingQueue:
     # graftlint guarded-by declarations: all three tiers plus the gang
     # and in-flight-event bookkeeping mutate under the queue condition
@@ -273,7 +301,7 @@ class SchedulingQueue:
         self._unschedulable: Dict[str, QueuedPodInfo] = {}
         self._gated: Dict[str, QueuedPodInfo] = {}
         self._infos: Dict[str, QueuedPodInfo] = {}   # all known pending pods
-        self._tier: Dict[str, str] = {}          # key -> active|backoff|unsched|gated|gangstage|inflight
+        self._tier = _Tiers()                    # key -> active|backoff|unsched|gated|gangstage|inflight
         # Gang bookkeeping (the coscheduling PodGroup PreEnqueue pattern):
         # _group_keys tracks every pending member per gang (for atomic
         # draining in pop_batch); _group_size is the gang's declared
@@ -687,6 +715,14 @@ class SchedulingQueue:
                     self._cond.wait(remaining)
                     self._flush_due_locked()
                     collect()
+            # what this pop leaves standing in the three tiers (a row of
+            # no length under the caller's sched.pop_wait)
+            depth = self._tier.depth
+            t = trace.now()
+            trace.event(
+                "sched.queue.depth", t, t, depth.get("active", 0),
+                a0=depth.get("backoff", 0), a1=depth.get("unsched", 0),
+            )
             return batch
 
     def done(self, pod: api.Pod) -> None:
@@ -702,21 +738,23 @@ class SchedulingQueue:
 
     def add_unschedulable(
         self, info: QueuedPodInfo, reason: int = -1
-    ) -> None:
+    ) -> bool:
         """A cycle failed to place the pod: park it until an event or the
         flush interval (AddUnschedulableIfNotPresent).  `reason` is the
         solver's failure stage — events wake only plausibly-affected
-        pods (move_for_event)."""
+        pods (move_for_event).  True when the pod was parked; False when
+        an event it missed sent it to backoff at once, and when it is
+        gone or gated."""
         with self._cond:
             key = pod_key(info.pod)
             if key not in self._infos:
-                return  # deleted meanwhile
+                return False  # deleted meanwhile
             if self._tier.get(key) == "gated":
                 # re-gated mid-cycle (an update added scheduling gates
                 # while the pod was inflight): the gate parked it —
                 # overriding to "unsched" would let move_for_event
                 # requeue a gated pod into a solve
-                return
+                return False
             if self._tier.get(key) == "inflight":
                 _ledger.discharge("pod", key)
             info.unschedulable_since = self._clock()
@@ -725,9 +763,10 @@ class SchedulingQueue:
                 # an event that can fix this failure arrived while the
                 # pod was mid-cycle — retry instead of parking
                 self._push_backoff(info)
-                return
+                return False
             self._unschedulable[key] = info
             self._tier[key] = "unsched"
+            return True
 
     def _missed_event_locked(self, info: QueuedPodInfo, reason: int) -> bool:
         """True when an event logged after this pod was popped would have
@@ -784,10 +823,15 @@ class SchedulingQueue:
         or reasons wake everything).  Returns the number woken — the
         churn benchmark asserts this stays bounded."""
         wakes = EVENT_WAKES.get(event) if event is not None else None
-        moved = 0
+        moved = to_backoff = 0
+        t0 = trace.now()    # before the lock: its wait is in the interval
         with self._cond:
             self._event_seq += 1
             self._events_log.append((self._event_seq, wakes))
+            if not self._unschedulable:
+                # nothing is parked (every event of a cluster whose pods
+                # all bind): nothing to walk and nothing to write
+                return 0
             now = self._clock()
             for key, info in list(self._unschedulable.items()):
                 reason = info.unschedulable_reason
@@ -802,25 +846,27 @@ class SchedulingQueue:
                 moved += 1
                 if now < info.unschedulable_since + self._backoff_duration(info):
                     self._push_backoff(info)
+                    to_backoff += 1
                 else:
                     self._push_active(info)
+        # one row a call that found pods parked: n moved, of which a0
+        # into backoff and a1 straight to active, over its own seconds
+        trace.event("sched.queue.wake", t0, trace.now(), moved,
+                    a0=to_backoff, a1=moved - to_backoff)
         return moved
 
     # -- introspection -----------------------------------------------------
 
     def stats(self) -> Dict[str, int]:
         with self._cond:
-            active = sum(1 for t in self._tier.values() if t == "active")
-            backoff = sum(1 for t in self._tier.values() if t == "backoff")
+            depth = self._tier.depth
             return {
-                "active": active,
-                "backoff": backoff,
+                "active": depth.get("active", 0),
+                "backoff": depth.get("backoff", 0),
                 "unschedulable": len(self._unschedulable),
                 "gated": len(self._gated),
                 "gang_staged": len(self._gang_staged),
-                "inflight": sum(
-                    1 for t in self._tier.values() if t == "inflight"
-                ),
+                "inflight": depth.get("inflight", 0),
             }
 
     def pending_count(self) -> int:

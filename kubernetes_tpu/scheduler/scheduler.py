@@ -169,7 +169,8 @@ class _Cycle:
     __slots__ = ("stats", "trace", "reservations", "failed", "wave",
                  "pending", "solved_any", "batch", "handled",
                  "spec_token", "mirror_points", "partials_points",
-                 "compile_mark")
+                 "compile_mark", "fail_n", "fail_parked", "fail_s",
+                 "fail_t0", "fail_t1")
 
     def __init__(self, stats, trace, reservations, batch):
         self.stats = stats
@@ -191,6 +192,23 @@ class _Cycle:
         self.spec_token = None
         self.mirror_points: Dict[str, tuple] = {}
         self.partials_points: Dict[str, tuple] = {}
+        # the failure branch's tally (the sched.fail row _finish_cycle
+        # writes): pods that ended without a bind, how many of them were
+        # parked, the seconds their branches took, first start, last end
+        self.fail_n = self.fail_parked = 0
+        self.fail_s = self.fail_t0 = self.fail_t1 = 0.0
+
+    def note_failure(self, t0: float, parked: bool = False) -> None:
+        """One pod's attempt ended without a bind: the branch that began
+        at `t0` (before its ``_mark_failed``) has just parked it
+        (`parked`) or put it back on the queue."""
+        t1 = _trace.now()
+        if not self.fail_n:
+            self.fail_t0 = t0
+        self.fail_t1 = t1
+        self.fail_n += 1
+        self.fail_parked += bool(parked)
+        self.fail_s += t1 - t0
 
 
 _REASON_TEXT = {
@@ -1584,9 +1602,11 @@ class Scheduler:
             len(group), sched_name,
         )
         for info in group:
+            t_fail = _trace.now()
             cycle.handled.add(pod_key(info.pod))
             self._mark_failed(info, _trace.FAIL_MISSPECULATED)
             self.queue.requeue_backoff(info)
+            cycle.note_failure(t_fail)
 
     def _harvest_group(self, cycle, fwk, sched_name, group, ds, t_solve):
         """Decode one dispatched group (the coalesced readback) and stage
@@ -1695,6 +1715,15 @@ class Scheduler:
             pending, cycle.pending = cycle.pending, None
             self._harvest_group(cycle, *pending)
         stats, tr = cycle.stats, cycle.trace
+        if cycle.fail_n:
+            # the failure branch, one row a cycle that had one: n pods
+            # that ended without a bind, a1 of them parked (the rest
+            # went back on the queue at once), a0 the seconds their
+            # branches took (mark, park or requeue, FailedScheduling
+            # event) between the row's own start and end
+            _trace.event("sched.fail", cycle.fail_t0, cycle.fail_t1,
+                         cycle.fail_n, a0=cycle.fail_s,
+                         a1=cycle.fail_parked)
         if cycle.wave:
             # binding stage takes over: the NEXT cycle's pop+solve runs
             # while this wave commits (assume entries already bridge it)
@@ -1890,26 +1919,30 @@ class Scheduler:
                 # the reservations back before parking
                 fwk.run_unreserve(info.pod)
         if node_name is None:
+            t_fail = _trace.now()
             stats["unschedulable"] += 1
             self.metrics.schedule_attempts.inc("unschedulable")
             self._mark_failed(info, _trace.FAIL_UNSCHEDULABLE)
-            self.queue.add_unschedulable(info, reason=reason)
+            parked = self.queue.add_unschedulable(info, reason=reason)
             self.events.eventf(
                 info.pod, "Warning", "FailedScheduling",
                 f"0 nodes available ({_REASON_TEXT.get(reason, 'unschedulable')})",
             )
             failed.append(info)
             cycle.handled.add(pod_key(info.pod))
+            cycle.note_failure(t_fail, parked)
             return None
         try:
             self.cache.assume(info.pod, node_name)
         except (KeyError, ValueError):
+            t_fail = _trace.now()
             fwk.run_unreserve(info.pod)
             stats["bind_errors"] += 1
             self.metrics.schedule_attempts.inc("error")
             self._mark_failed(info, _trace.FAIL_ASSUME)
             self.queue.requeue_backoff(info)
             cycle.handled.add(pod_key(info.pod))
+            cycle.note_failure(t_fail)
             return None
         # Permit (schedule_one.go:231): reject aborts; wait parks
         # the pod in the waiting map and the binding runs on its own
@@ -1917,6 +1950,7 @@ class Scheduler:
         # loop moves on, like the reference's async bindingCycle
         verdict, timeout = fwk.run_permit(info.pod, node_name)
         if verdict == "reject":
+            t_fail = _trace.now()
             self.cache.forget(info.pod)
             fwk.run_unreserve(info.pod)
             stats["unschedulable"] += 1
@@ -1928,6 +1962,7 @@ class Scheduler:
             self._mark_failed(info, _trace.FAIL_PERMIT)
             self.queue.requeue_backoff(info)
             cycle.handled.add(pod_key(info.pod))
+            cycle.note_failure(t_fail)
             return None
         if verdict == "wait":
             wp = WaitingPod(info.pod, node_name, timeout)
@@ -2058,15 +2093,18 @@ class Scheduler:
                 tpu.encode_pending([info.pod], lock=self.cache.lock)
                 good.append(info)
             except (OverflowError, ValueError):
+                t_fail = _trace.now()
                 if cycle is not None:
                     cycle.handled.add(pod_key(info.pod))
                 self.metrics.schedule_attempts.inc("error")
                 self._mark_failed(info, _trace.FAIL_UNENCODABLE)
                 # only a pod UPDATE (spec change) can help — no cluster
                 # event wakes this reason (queue.move_for_event)
-                self.queue.add_unschedulable(
+                parked = self.queue.add_unschedulable(
                     info, reason=assign_ops.REASON_UNENCODABLE
                 )
+                if cycle is not None:
+                    cycle.note_failure(t_fail, parked)
         return good
 
     def _bind(self, pod: api.Pod, node_name: str) -> None:
@@ -2096,6 +2134,10 @@ class Scheduler:
         What it enumerates is the executable key set of the templates
         (docs/scheduler_loop.md, "What makes an executable new"): every
         shape but the pod bucket is a function of the deployment — the
+        unconstrained spec-class dim floors at what ALL of `pods` fill
+        (SnapshotBuilder.fix_spec_classes: a deployment of two request
+        shapes hands pods of both, and a live batch of one shape then
+        takes the executable of a batch of both), the constraint
         class dims and row dims floor at 32 (vocab.pad_constraint_dim),
         a coupled batch's wave plan has one row a pod
         (ops.assign.wave_rows), topo_z and the slot tuples come from
@@ -2118,6 +2160,10 @@ class Scheduler:
         that left the cluster since the last encode leave, which no
         batch size bounds (mirror.warm_usage_buckets).
 
+        Before it returns it waits for the prewarm pool's outstanding
+        jobs (the neighbour keys its own solves offered), so nothing is
+        building on any thread afterwards.
+
         Returns seconds spent.  Never raises: a bucket that fails to
         encode (cap overflow) is skipped — the real cycle handles those
         pods through its own rejection path."""
@@ -2128,12 +2174,24 @@ class Scheduler:
         cap = min(len(pods), max_batch or self.batch_size)
         from ..utils import vocab as vb
 
+        log = logging.getLogger(__name__)
+        # the spec-class dim of every later batch floors at what ALL the
+        # templates handed here fill, so a batch holding one request
+        # shape of two takes the executable of a batch holding both
+        try:
+            with self.cache.lock:
+                fwk.tpu.builder.fix_spec_classes(pods)
+        except Exception:
+            # a template the encoder refuses (cap overflow), as in
+            # warm_batch below: the buckets that encode are still warmed,
+            # at the dim their pods have
+            log.exception("warmup: a template pod has no spec signature")
+
         buckets, b = [], self.tpu.builder.limits.min_pods
         top = vb.pad_dim(cap, self.tpu.builder.limits.min_pods)
         while b <= top:
             buckets.append(b)
             b *= 2
-        log = logging.getLogger(__name__)
 
         def warm_batch(bucket: int, n_pods: int) -> None:
             try:
@@ -2166,6 +2224,14 @@ class Scheduler:
                                      or p.spec.affinity.pod_anti_affinity))
             for p in pods
         )
+        def join_prewarm() -> None:
+            # the pool compiles neighbour keys off this thread; its last
+            # job would otherwise end after warmup returned
+            pool = fwk.tpu.prewarm_pool
+            if pool is not None and not pool.join():
+                log.warning("warmup: the prewarm pool was still building "
+                            "when its wait ran out")
+
         warm_all()
         clone = copy.deepcopy(pods[0])
         clone.meta.name = "warmup-bound-pod"
@@ -2174,6 +2240,7 @@ class Scheduler:
         try:
             self.cache.assume(clone, node0)  # graftlint: disable=obligations -- the finally below forgets the clone; if THAT forget fails it is logged and cleanup_expired retires the synthetic assume by TTL
         except Exception:
+            join_prewarm()
             return self._clock() - t0  # no usable node; round A ran
         try:
             if needs_bound_round:
@@ -2190,6 +2257,7 @@ class Scheduler:
                 self.cache.forget(clone)
             except Exception:
                 log.exception("warmup: forgetting the bound clone failed")
+        join_prewarm()
         return self._clock() - t0
 
     # -- test convenience -------------------------------------------------

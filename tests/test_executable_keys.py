@@ -119,6 +119,252 @@ def test_one_padding_rule_for_rows_and_classes():
     assert not assign.waves_couple(assign.FeatureFlags(images=True))
 
 
+# -- pods that stay pending: the deployment sched-perf-5000n-unschedulable -----------
+
+PENDING_NODES = 128
+PENDING_LIVE = 5 * PENDING_NODES    # bound at once at most: every node keeps 3.5 cpu free
+
+
+def default_pod(name, ns):
+    # scheduler_perf's pod-default.yaml
+    return make_pod(name, ns).req(cpu_milli=100, mem=500 * MI).obj()
+
+
+def large_pod(name, ns):
+    # scheduler_perf's pod-large-cpu.yaml as upstream has it: 9 cpu, no priority
+    return make_pod(name, ns).req(cpu_milli=9000, mem=500 * MI).obj()
+
+
+@pytest.fixture
+def small_nodes(request):
+    """Nodes of node-default.yaml's size (4 cpu), 128 unless the test asks
+    for another number: none holds a 9-cpu pod."""
+    nodes = [
+        make_node(f"node-{i}").capacity(cpu_milli=4000, mem=32 * GI, pods=110)
+        .zone(f"zone-{i % 8}").obj()
+        for i in range(getattr(request, "param", PENDING_NODES))
+    ]
+    store = st.Store()
+    for node in nodes:
+        store.create(node)
+    sched = Scheduler(store, batch_size=BATCH)
+    sched.start()
+    assert sched.informers.wait_for_sync()
+    try:
+        yield sched, nodes
+    finally:
+        sched.stop()
+
+
+def builds_counter():
+    """Executables built or loaded (JAX's backend-compile events, which count
+    cache loads) on any thread, by the jitted function's name."""
+    import collections
+
+    import jax.monitoring
+
+    seen = collections.Counter()
+
+    def on_duration(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen[str(kw.get("fun_name", "?"))] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return seen, lambda: jax.monitoring.unregister_event_duration_listener(on_duration)
+
+
+def test_two_request_shapes_share_one_key_set_and_land_where_the_oracle_puts_them(small_nodes):
+    """``warmup`` with the pods of both templates; then 200 seeded batches,
+    pure measured, pure large and mixed in any proportion, 1 … 256 pods, with
+    placements assumed and the oldest removed as the sweep goes: none traces,
+    builds or loads anything, on any thread; every measured pod lands where
+    ``testing/oracle.py`` puts it and no large pod lands.  Before the class
+    dim took its floor from ``warmup``'s templates a pure batch had 2 class
+    slots and a mixed one 4, and a batch of pods that fit nowhere had a wave
+    plan of one row a pod (each alone in its wave): 10 of 120 such batches
+    built after a mixed ``warmup`` (CPU count on the parent)."""
+    from kubernetes_tpu.testing.oracle import Oracle
+
+    (sched, nodes), rng = small_nodes, random.Random(2035)
+    tpu = sched.tpu
+    warm = []
+    for i in range(BATCH):
+        warm += [default_pod(f"warm-{i}", NAMESPACES[i % 16]),
+                 large_pod(f"warm-large-{i}", NAMESPACES[i % 16])]
+    builds, unregister = builds_counter()
+    live = []       # bound and not removed, oldest first
+    try:
+        sched.warmup(warm)
+        assert tpu.builder.spec_class_floor == 3        # two templates and the pad rows' slot
+        warmed = sum(builds.values())
+        fresh, seen, dims, plans = [], set(), set(), set()
+        for b in range(SWEEP):
+            kind = ("measured", "large", "mixed")[b % 3]
+            size = rng.randint(1, BATCH)
+            share = rng.random()
+            pods = []
+            for i in range(size):
+                big = kind == "large" or (kind == "mixed" and rng.random() < share)
+                mk = large_pod if big else default_pod
+                pods.append(mk(f"{'large' if big else 'p'}-{b}-{i}", rng.choice(NAMESPACES)))
+            want = Oracle(nodes, bound_pods=live).schedule(pods)
+            mark, built = compileclock.events(), sum(builds.values())
+            names = tpu.schedule_pending(pods, lock=sched.cache.lock)
+            meta = tpu.last_solve.meta
+            if compileclock.events() != mark or sum(builds.values()) != built:
+                fresh.append((b, kind, size, meta.route))
+            seen.add(meta.route)
+            dims.add(meta.statics[0].shape[0])
+            if meta.route == "wavefront":
+                plans.add((vocab.pad_dim(size, 8), meta.wave_plan.members.shape[0]))
+                assert tpu.last_solve.wave_fallbacks == 0
+            assert names == want, f"batch {b} ({kind}, {size} pods)"
+            for pod, node in zip(pods, names):
+                assert (node is None) == pod.meta.name.startswith("large-")
+                if node:
+                    pod.spec.node_name = node
+                    sched.cache.assume(pod, node)
+                    live.append(pod)
+            while len(live) > PENDING_LIVE:
+                k = min(rng.randint(1, 64), len(live))
+                for pod in live[:k]:
+                    sched.cache.remove_pod(pod)
+                del live[:k]
+        assert fresh == [] and sum(builds.values()) == warmed
+        assert seen == {"greedy", "wavefront"}
+        assert dims == {4}              # pure and mixed batches alike: pad_dim(3, 1)
+        # one plan a bucket, the uncoupled one, whatever fits nowhere
+        assert plans == {(p, assign.wave_rows(p, 32, False)) for p, _ in plans}
+    finally:
+        unregister()
+        for pod in live:
+            sched.cache.remove_pod(pod)
+
+
+@pytest.mark.parametrize("small_nodes", [512], indirect=True)
+def test_a_full_batch_of_both_shapes_takes_the_auction_and_builds_nothing(small_nodes):
+    """The deployment's 1,024-pod cycles are the only auction cycles of the
+    benchmark: with the route's threshold brought down to this test's batch,
+    full batches of either shape or both build nothing after ``warmup``, no
+    large pod lands and every measured pod does.  On more nodes than a batch
+    has pods, so that the auction's ``tie_k`` is not cut to the node axis: it
+    followed the batch's largest class (128 pods of 256 in ``warmup``'s mixed
+    batch, all 256 of a pure one: another executable, which the chip's first
+    run of the cell built inside a window), and is sized by the batch's valid
+    pods, on this path as on the gang and the sharded ones."""
+    (sched, _), rng = small_nodes, random.Random(2036)
+    tpu = sched.tpu
+    tpu.AUCTION_MIN_PODS = BATCH
+    warm = []
+    for i in range(BATCH):
+        warm += [default_pod(f"warm-{i}", NAMESPACES[i % 16]),
+                 large_pod(f"warm-large-{i}", NAMESPACES[i % 16])]
+    builds, unregister = builds_counter()
+    try:
+        sched.warmup(warm)
+        warmed = sum(builds.values())
+        for b, share in enumerate([0.0, 1.0, 0.5, 0.95, 0.05, 1.0]):
+            pods = []
+            for i in range(rng.randint(BATCH // 2 + 1, BATCH)):
+                big = rng.random() < share
+                mk = large_pod if big else default_pod
+                pods.append(mk(f"{'large' if big else 'p'}-{b}-{i}", rng.choice(NAMESPACES)))
+            mark = compileclock.events()
+            names = tpu.schedule_pending(pods, lock=sched.cache.lock)
+            assert tpu.last_solve.meta.route == "auction"
+            assert tpu.last_solve.meta.tie_k == BATCH       # the bucket's, whatever fills it
+            assert compileclock.events() == mark and sum(builds.values()) == warmed, (b, share)
+            for pod, node in zip(pods, names):
+                assert (node is None) == pod.meta.name.startswith("large-")
+    finally:
+        unregister()
+
+
+# what `warmup` with one template builds or loads on 128 nodes at a batch of
+# 256, by function: the count on the parent commit (PR 34), pinned so that a
+# deployment of one template keeps the keys, and the set-up, it had
+ONE_TEMPLATE_WARMUP = {
+    "jit(run_warm)": 6, "jit(_unpack)": 6, "jit(gather_statics)": 1, "jit(eval_store)": 1,
+    "jit(set_spec_rows)": 1, "jit(insert_slots)": 1, "jit(refresh_rows)": 1, "jit(_set_rows)": 14,
+}
+
+
+ONE_TEMPLATE_SCRIPT = """
+import collections, json, sys
+import jax.monitoring
+from kubernetes_tpu.api import store as st
+from kubernetes_tpu.scheduler import Scheduler
+from kubernetes_tpu.testing.wrappers import GI, MI, make_node, make_pod
+
+builds = collections.Counter()
+def on_duration(event, secs, **kw):
+    if event == "/jax/core/compile/backend_compile_duration":
+        builds[str(kw.get("fun_name", "?"))] += 1
+jax.monitoring.register_event_duration_secs_listener(on_duration)
+store = st.Store()
+for i in range(128):
+    store.create(make_node(f"node-{i}").capacity(cpu_milli=4000, mem=32 * GI, pods=110)
+                 .zone(f"zone-{i % 8}").obj())
+sched = Scheduler(store, batch_size=256)
+sched.start()
+assert sched.informers.wait_for_sync()
+try:
+    sched.warmup([make_pod(f"warm-{i}", f"team-{i % 16}").req(cpu_milli=100, mem=500 * MI).obj()
+                  for i in range(256)])
+    pool = sched.tpu.prewarm_pool
+    left = pool is not None and not pool.join(timeout=0.0)
+    print(json.dumps({"builds": builds, "floor": sched.tpu.builder.spec_class_floor,
+                      "pool_left_a_job": left}))
+finally:
+    sched.stop()
+"""
+
+
+def test_a_one_template_warmup_builds_exactly_what_it_built_before():
+    """In a process of its own, since what an earlier test built this one
+    would find built."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=root + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    done = subprocess.run([sys.executable, "-c", ONE_TEMPLATE_SCRIPT], env=env, cwd=root,
+                          capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-2000:]
+    out = json.loads(done.stdout.strip().splitlines()[-1])
+    assert out["floor"] == 2            # the dim a one-template batch has anyway
+    assert out["builds"] == ONE_TEMPLATE_WARMUP
+    assert out["pool_left_a_job"] is False
+
+
+def test_warmup_waits_for_the_prewarm_pools_outstanding_jobs():
+    """``SolverPrewarmPool.join`` returns once every job offered so far has
+    run, and ``warmup`` calls it before it returns: nothing is built after
+    ``warmup`` whatever the timing (before, the pool's last job could end
+    after it, PERF.md PR 33)."""
+    import threading
+
+    from kubernetes_tpu.models.batch_scheduler import SolverPrewarmPool
+
+    pool = SolverPrewarmPool()
+    gate, ran = threading.Event(), []
+    try:
+        assert pool.join(timeout=0.0)           # nothing offered: nothing to wait for
+        assert pool.offer("k1", "slow", lambda: (gate.wait(10), ran.append("k1")))
+        assert pool.offer("k2", "boom", lambda: 1 / 0)      # a failed build counts as done
+        assert pool.offer("k3", "fast", lambda: ran.append("k3"))
+        assert not pool.join(timeout=0.05)      # the first job is still building
+        gate.set()
+        assert pool.join(timeout=10.0) and ran == ["k1", "k3"]
+        assert (pool.compiled, pool.errors) == (2, 1)
+    finally:
+        gate.set()
+        pool.close()
+
+
 # -- pods that leave: the deployment sched-perf-5000n-antiaffinity -----------------
 
 ANTI_NODES = 256

@@ -403,3 +403,134 @@ def test_a_requeued_pod_keeps_enqueued_counts_attempts_and_says_how_it_failed(fr
         assert ok["route"] == first["route"] == trace.ROUTE_ID["greedy"]
     finally:
         sched.stop()
+
+
+# -- pods that stay pending: the queue's wake row, its tier depths, the failure branch --
+
+
+def test_a_wake_is_one_row_with_its_pods_split_by_backoff_and_active(fresh):
+    """``move_for_event`` writes one ``sched.queue.wake`` row a call that finds
+    pods parked: ``n`` moved, ``a0`` of them into backoff, ``a1`` straight to
+    active; an event that finds nothing parked writes nothing."""
+    from kubernetes_tpu.ops import assign
+    from kubernetes_tpu.scheduler.queue import SchedulingQueue
+    from kubernetes_tpu.testing.wrappers import make_pod
+
+    clock = [100.0]
+    q = SchedulingQueue(backoff_base=1.0, backoff_max=10.0, clock=lambda: clock[0])
+    t0 = time.perf_counter()
+    assert q.move_for_event("AssignedPodDelete") == 0      # nothing parked: no row
+    for i in range(5):
+        q.add(make_pod(f"p{i}").obj())
+    infos = q.pop_batch(10, timeout=0.0)
+    assert [q.add_unschedulable(i, reason=assign.REASON_RESOURCES) for i in infos[:3]] == [True] * 3
+    clock[0] += 5.0                     # the first three are past their 1 s backoff
+    assert [q.add_unschedulable(i, reason=assign.REASON_RESOURCES) for i in infos[3:]] == [True] * 2
+    assert q.move_for_event("AssignedPodAdd") == 0         # parked, and this event wakes none of them
+    assert q.move_for_event("AssignedPodDelete") == 5
+    assert q.move_for_event("AssignedPodDelete") == 0      # the set is empty again: no row
+    rows = spans(trace.snapshot(t0), "sched.queue.wake")
+    assert [(r["n"], r["a0"], r["a1"]) for r in rows] == [(0, 0.0, 0.0), (5, 2.0, 3.0)]
+    assert all(r["end"] >= r["start"] and r["cycle"] == 0 for r in rows)
+    assert q.stats()["active"] == 3 and q.stats()["backoff"] == 2
+
+
+def test_a_pod_that_missed_an_event_is_not_parked_and_says_so(fresh):
+    from kubernetes_tpu.ops import assign
+    from kubernetes_tpu.scheduler.queue import SchedulingQueue
+    from kubernetes_tpu.testing.wrappers import make_pod
+
+    q = SchedulingQueue()
+    q.add(make_pod("p").obj())
+    (info,) = q.pop_batch(10, timeout=0.0)
+    q.move_for_event("AssignedPodDelete")       # lands while the pod is in its cycle
+    assert q.add_unschedulable(info, reason=assign.REASON_RESOURCES) is False
+    assert q.stats()["backoff"] == 1 and q.stats()["unschedulable"] == 0
+
+
+def test_every_pop_says_how_deep_the_tiers_stand_from_counters_it_keeps(fresh):
+    """``sched.queue.depth``: one row of no length a pop that took pods, ``n``
+    active, ``a0`` backoff, ``a1`` unschedulable as the pop leaves them, the
+    counters ``stats()`` reads too; a walk over the tier map agrees."""
+    from kubernetes_tpu.ops import assign
+    from kubernetes_tpu.scheduler.queue import SchedulingQueue
+    from kubernetes_tpu.testing.wrappers import make_pod
+
+    clock = [100.0]
+    q = SchedulingQueue(clock=lambda: clock[0])
+    t0 = time.perf_counter()
+    pods = [make_pod(f"p{i}").obj() for i in range(12)]
+    for p in pods:
+        q.add(p)
+    assert len(q.pop_batch(10, timeout=0.0, window=0.0)) == 10      # leaves 2 active
+    assert q.pop_batch(0, timeout=0.0) == []                        # took nothing: no row
+    assert len(q.pop_batch(4, timeout=0.0, window=0.0)) == 2
+    depth = lambda: {k: v for k, v in q._tier.depth.items() if v}
+    assert depth() == {"inflight": 12}
+    inflight = [q._infos[f"default/p{i}"] for i in range(12)]
+    for info in inflight[:5]:
+        q.add_unschedulable(info, reason=assign.REASON_RESOURCES)
+    for info in inflight[5:8]:
+        q.requeue_backoff(info)
+    for info in inflight[8:]:
+        q.done(info.pod)
+    q.delete(pods[0])                   # a parked pod deleted: its tier's count goes with it
+    assert depth() == {"unsched": 4, "backoff": 3}
+    s = q.stats()
+    assert (s["active"], s["backoff"], s["unschedulable"], s["inflight"]) == (0, 3, 4, 0)
+    import collections
+
+    assert depth() == dict(collections.Counter(q._tier.values()))
+    q.add(make_pod("late").obj())
+    assert len(q.pop_batch(4, timeout=0.0, window=0.0)) == 1
+    rows = spans(trace.snapshot(t0), "sched.queue.depth")
+    assert [(r["n"], r["a0"], r["a1"]) for r in rows] == [
+        (2, 0.0, 0.0), (0, 0.0, 0.0), (0, 3.0, 4.0)]
+    assert all(r["start"] == r["end"] for r in rows)
+    clock[0] += 30.0                    # every backoff is over: the next pop takes them
+    assert len(q.pop_batch(10, timeout=0.0, window=0.0)) == 3
+    assert depth() == {"unsched": 4, "inflight": 4}
+
+
+def test_a_cycle_with_failing_pods_writes_one_fail_row_and_a_cycle_without_none(fresh):
+    """``sched.fail``: ``n`` pods of the cycle that ended without a bind, ``a1``
+    of them parked, ``a0`` the seconds their branches took, under the cycle."""
+    from kubernetes_tpu.api import store as st
+    from kubernetes_tpu.scheduler import Scheduler
+    from kubernetes_tpu.testing.wrappers import GI, MI, make_node, make_pod
+
+    store = st.Store()
+    sched = Scheduler(store, batch_size=16)
+    try:
+        sched.cache.add_node(
+            make_node("n0").capacity(cpu_milli=4000, mem=32 * GI, pods=110).obj())
+        t0 = time.perf_counter()
+        for i in range(3):
+            p = make_pod(f"big-{i}").req(cpu_milli=9000, mem=500 * MI).obj()
+            store.create(p)
+            sched.queue.add(p)
+        for i in range(4):
+            p = make_pod(f"small-{i}").req(cpu_milli=100, mem=500 * MI).obj()
+            store.create(p)
+            sched.queue.add(p)
+        stats = sched.schedule_batch(timeout=0.5)
+        assert (stats["scheduled"], stats["unschedulable"]) == (4, 3)
+        assert sched.flush_binds(timeout=30.0)
+        snap = trace.snapshot(t0)
+        (row,) = spans(snap, "sched.fail")
+        (cycle,) = spans(snap, "sched.cycle")
+        assert (row["n"], row["a1"]) == (3, 3.0) and row["cycle"] == cycle["id"]
+        assert 0.0 < row["a0"] <= row["end"] - row["start"] + 1e-9
+        assert cycle["start"] <= row["start"] <= row["end"] <= cycle["end"]
+        assert sched.queue.stats()["unschedulable"] == 3
+        # a cycle in which every pod binds writes none
+        for i in range(4, 8):
+            p = make_pod(f"small-{i}").req(cpu_milli=100, mem=500 * MI).obj()
+            store.create(p)
+            sched.queue.add(p)
+        assert sched.schedule_batch(timeout=0.5)["scheduled"] == 4
+        assert sched.flush_binds(timeout=30.0)
+        snap = trace.snapshot(t0)
+        assert len(spans(snap, "sched.cycle")) == 2 and len(spans(snap, "sched.fail")) == 1
+    finally:
+        sched.stop()
