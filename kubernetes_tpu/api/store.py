@@ -1857,6 +1857,14 @@ class Store:
             )
 
     def delete(self, kind: str, name: str, namespace: str = "default") -> Any:
+        # summed per thread like a create's (utils/trace.tally)
+        t0 = trace.now()
+        try:
+            return self._delete(kind, name, namespace)
+        finally:
+            trace.tally("store.delete", t0, trace.now())
+
+    def _delete(self, kind: str, name: str, namespace: str) -> Any:
         """Remove an object.  Objects carrying finalizers get the
         reference's two-phase deletion (registry/generic/registry/
         store.go:1116): deletionTimestamp is set and a MODIFIED event

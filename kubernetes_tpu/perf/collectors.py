@@ -269,13 +269,12 @@ class MetricsCollector:
         "scheduler_lane_count",
         "scheduler_speculative_solves_total",
         "scheduler_misspeculation_total",
-        # columnar host plane: encode throughput, framed journal bytes,
-        # fan-out chunking, and the c6s ramp knee
+        # columnar host plane: encode throughput, framed journal bytes
+        # and fan-out chunking
         # (docs/scheduler_loop.md host plane section)
         "scheduler_encode_rows_per_s",
         "scheduler_journal_frame_bytes",
         "scheduler_fanout_chunk_size",
-        "scheduler_c6s_arrival_knee_pods_per_s",
         # serving plane: adaptive APF seat/shed accounting, write-
         # deadline stalls, and replica failovers
         # (docs/robustness.md serving-plane section)

@@ -508,12 +508,6 @@ class Registry:
         # mean events per watch fan-out chunk (batched per-watcher
         # hand-off under one publish-lock hold), read from the store
         self.fanout_chunk_size = Gauge("scheduler_fanout_chunk_size")
-        # the c6s ramp hunt's capacity knee: highest arrival rate whose
-        # backlog stayed bounded.  Kept for the series' name: nothing in
-        # the tree sets it since the CPU bench went (ROADMAP D4)
-        self.c6s_arrival_knee = Gauge(
-            "scheduler_c6s_arrival_knee_pods_per_s"
-        )
         # -- serving plane (docs/robustness.md serving-plane section) ------
         # effective APF seats across all priority levels (shrinks under
         # adaptive pressure, recovers with hysteresis) — read from the

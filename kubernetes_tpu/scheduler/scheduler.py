@@ -673,10 +673,12 @@ class Scheduler:
     # -- lifecycle ---------------------------------------------------------
 
     def start(self) -> None:
-        """Start informers + the scheduling loop thread."""
+        """Start informers + the scheduling loop thread, and the
+        recorder's CPU ledger (utils/trace.py) for as long as this runs."""
         logging.getLogger(__name__).info(
             "scheduler starting: solves run on %s", device_label()
         )
+        _trace.start_cpu_ledger(self)
         self.informers.informer("Node").start()
         self.informers.informer("Pod").start()
         self.informers.informer("PersistentVolume").start()
@@ -723,6 +725,7 @@ class Scheduler:
             self._commit_pool.shutdown(wait=True)
         self.informers.stop()
         self.events.stop()
+        _trace.stop_cpu_ledger(self)
 
     def kill(self) -> None:
         """Ungraceful teardown — the chaos harness's process-death
@@ -750,6 +753,7 @@ class Scheduler:
             self._commit_pool.shutdown(wait=False)
         self.informers.stop()
         self.events.stop()
+        _trace.stop_cpu_ledger(self)
 
     # -- leadership / restart reconciliation -------------------------------
 

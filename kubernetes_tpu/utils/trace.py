@@ -21,6 +21,12 @@ to and any reader snapshots afterwards.
   pods    one row per pending pod: the six stamps of its path
           (enqueued, popped, solved, commit_begin, committed, failed),
           its cycle, attempts and route.  No CPU reading per pod.
+  cpu     the CPU ledger: while a ``Scheduler`` runs, one sampler
+          thread reads every Python thread's own CPU clock and the
+          process's every quarter second and writes what advanced into
+          the span ring (``proc.cpu``, ``proc.cpu.process``), with how
+          late the wake ran past its timer: how long a thread that wants
+          the interpreter waits for it (``proc.stall`` past a second).
 
 One clock, ``time.perf_counter()``, read directly (never a scheduler's
 injectable ``clock=``): a harness that times its client on the same
@@ -34,8 +40,8 @@ None rather than rows from a torn interval.
 ``Trace`` is the utiltrace analogue over the same ring: a root span with
 a fresh cycle id, ``step()`` children, one log line when the total
 passes the threshold — now with what the OTHER threads were doing
-meanwhile (their overlapping spans with wall and CPU seconds) and any
-collection inside it.
+meanwhile (their overlapping spans with wall and CPU seconds), any
+collection inside it and, where the ledger runs, every thread's CPU.
 
 The module imports only the standard library: the storage layer stamps
 here too, and a client that imports the store loads neither numpy nor
@@ -48,6 +54,7 @@ can run in.
 from __future__ import annotations
 
 import gc
+import heapq
 import itertools
 import logging
 import mmap
@@ -96,9 +103,10 @@ TALLY_S = 0.1
 _names: List[str] = ["?"]
 _name_ids: Dict[str, int] = {}
 _tls = threading.local()
-# the once-only guard of every Trace's log line: the module's one lock,
-# taken only by a cycle already over its threshold
-_LOG_ONCE = threading.Lock()
+# the module's one lock: the once-only guard of every Trace's log line,
+# taken only by a cycle already over its threshold, and the CPU ledger's
+# start and stop.  No stamp takes it.
+_LOCK = threading.Lock()
 # which root spans passed their threshold; the durations stay in the ring
 _overran: deque = deque(maxlen=256)
 # (thread, name) -> [row id, row base, start of the row]: the open tallies
@@ -333,6 +341,191 @@ gc.callbacks[:] = [
 gc.callbacks.append(_on_gc)
 
 
+# -- the CPU ledger: who holds the interpreter -----------------------------
+
+# a wake reads every Python thread's CPU clock and writes a row for each
+# that ran
+CPU_PERIOD_S = 0.25
+# a wake later than this past its timer also writes a ``proc.stall`` row
+STALL_S = 1.0
+# the thread key of the process's CPU that no Python thread read holds:
+# XLA's and the runtime's own threads, and threads that have ended
+NATIVE = 0
+LEDGER_ROWS = ("proc.cpu", "proc.cpu.process", "proc.stall")
+
+# thread key -> name of every thread the ledger reads, and of every ended
+# one a row in the ring still names, so that a reader after the threads
+# are gone names them; a key is never given twice, so a thread that reuses
+# an ended one's ident is another key
+_thread_names: Dict[int, str] = {NATIVE: "native"}
+_thread_keys = itertools.count(NATIVE + 1)
+# (id of its last row, key) of each ended thread still named, least first:
+# its name goes once the ring has overwritten that row (``_forget``)
+_ended: List[tuple] = []
+_ledger_owners: set = set()
+_ledger = None      # the running _CpuLedger, or None
+
+
+def thread_names() -> Dict[int, str]:
+    """{thread key: name} of every thread the CPU ledger reads, and of every
+    ended one that a ``proc.cpu`` or ``proc.stall`` row in the ring names
+    (its ``n``)."""
+    return dict(_thread_names)
+
+
+def _retire(key: int, row: int) -> None:
+    """Thread `key` has ended or is no longer read; `row` is the id of its
+    last row, 0 if it wrote none (then nothing names it)."""
+    if row:
+        heapq.heappush(_ended, (row, key))
+    else:
+        _thread_names.pop(key, None)
+
+
+def _forget() -> None:
+    """Drop the names of ended threads whose last row the ring no longer
+    holds: rows are overwritten in the order of their ids."""
+    while _ended and _smv[(_ended[0][0] % SPAN_ROWS) * _SW + _ID] != _ended[0][0]:
+        _thread_names.pop(heapq.heappop(_ended)[1], None)
+
+
+def _ledger_row(name: str, t0: float, t1: float, tid: int, n: float,
+                cpu0: float = _NAN, cpu1: float = _NAN,
+                a0: float = 0.0, a1: float = 0.0) -> int:
+    """A closed row of the ledger's, in no cycle and under no span, on
+    behalf of thread `tid` (the row's ``thread``); returns its id."""
+    i = _open_row(name, t0, cpu0, 0, 0, n)
+    mv, base = _smv, (i % SPAN_ROWS) * _SW
+    mv[base + _END] = t1
+    mv[base + _CPU1] = cpu1
+    mv[base + _TID] = tid
+    mv[base + _A0] = a0
+    mv[base + _A1] = a1
+    return i
+
+
+class _CpuLedger:
+    """The sampler thread.  Every wake, on one clock read ``t`` (and the
+    timer ``due`` it was woken for), it writes:
+
+      proc.cpu          one row a Python thread whose CPU clock advanced
+                        since its last row: zero length at ``t``,
+                        ``cpu0`` / ``cpu1`` the thread's CPU seconds at
+                        its last row and now, ``thread`` its ident, ``n``
+                        its key (``thread_names()``).  An idle thread
+                        writes none.
+      proc.cpu.process  one row a wake: from ``due`` to ``t``, so its
+                        length is how late the wake ran past its timer;
+                        ``cpu0`` / ``cpu1`` the process's CPU seconds at
+                        the last wake and now (read after every thread's,
+                        so it holds their sum), ``n`` the threads read,
+                        ``a0`` the wake's own seconds from ``t`` to this
+                        row: what the ledger costs, on a clock finer
+                        than a CPU clock that ticks.
+      proc.stall        a wake more than STALL_S late: from ``due`` to
+                        ``t``, ``n`` the key of the thread whose CPU
+                        advanced most across it (NATIVE if no Python
+                        thread's did as much as the rest of the
+                        process), ``thread`` its ident, ``a0`` that
+                        advance, ``a1`` the process's.
+    """
+
+    def __init__(self):
+        self._stop = threading.Event()
+        # Thread -> [key, CPU clock id or None, CPU seconds at its last row,
+        #            id of its last row or 0]
+        self._seen: dict = {}
+        self._proc = time.process_time()
+        self.thread = threading.Thread(target=self._run, name="trace-cpu",
+                                       daemon=True)
+        self.thread.start()
+
+    def stop(self) -> None:
+        self._stop.set()
+        self.thread.join()
+        for key, _, _, row in self._seen.values():
+            _retire(key, row)       # a later ledger reads them under new keys
+        self._seen = {}
+
+    def _run(self) -> None:
+        due = now() + CPU_PERIOD_S
+        while not self._stop.wait(max(0.0, due - now())):
+            self._read(due, now())
+            due += CPU_PERIOD_S
+            if due <= now():
+                due = now() + CPU_PERIOD_S      # past a late wake: a period on
+
+    def _read(self, due: float, t: float) -> None:
+        seen, live = self._seen, {}
+        for th in threading.enumerate():
+            ent = seen.pop(th, None)
+            if ent is None:
+                if th.ident is None:
+                    continue        # started, not running yet: the next wake
+                key = next(_thread_keys)
+                _thread_names[key] = th.name
+                try:
+                    clock = time.pthread_getcpuclockid(th.ident)
+                except (OSError, AttributeError):
+                    clock = None
+                ent = [key, clock, 0.0, 0]
+            live[th] = ent
+        for key, _, _, row in seen.values():       # ended since the last wake
+            _retire(key, row)
+        self._seen = live
+        _forget()
+        top, top_th, ran = 0.0, None, 0.0
+        for th, ent in live.items():
+            if ent[1] is None:
+                continue
+            try:
+                c = time.clock_gettime(ent[1])
+            except OSError:
+                continue            # it ended since enumerate()
+            d = c - ent[2]
+            if d > 0.0:
+                ent[3] = _ledger_row("proc.cpu", t, t, th.ident, ent[0], ent[2], c)
+                ent[2] = c
+                ran += d
+                if d > top:
+                    top, top_th = d, th
+        proc = time.process_time()
+        _ledger_row("proc.cpu.process", due, t, _ident(), len(live),
+                    self._proc, proc, a0=now() - t)
+        if t - due > STALL_S:
+            rest = proc - self._proc - ran
+            if top_th is None or rest > top:
+                _ledger_row("proc.stall", due, t, 0, NATIVE,
+                            a0=rest, a1=proc - self._proc)
+            else:
+                ent = live[top_th]
+                ent[3] = _ledger_row("proc.stall", due, t, top_th.ident, ent[0],
+                                     a0=top, a1=proc - self._proc)
+        self._proc = proc
+
+
+def start_cpu_ledger(owner) -> None:
+    """Keep the CPU ledger running while `owner` runs (``Scheduler.start``):
+    one sampler thread in the process however many owners; starting an
+    owner that runs already changes nothing."""
+    global _ledger
+    with _LOCK:
+        _ledger_owners.add(owner)
+        if _ledger is None:
+            _ledger = _CpuLedger()
+
+
+def stop_cpu_ledger(owner) -> None:
+    """`owner` has stopped (``Scheduler.stop``); once no owner is left the
+    sampler thread has ended when this returns.  Stopping twice is once."""
+    global _ledger
+    with _LOCK:
+        _ledger_owners.discard(owner)
+        if _ledger is not None and not _ledger_owners:
+            _ledger.stop()
+            _ledger = None
+
+
 # -- reading ---------------------------------------------------------------
 
 
@@ -405,6 +598,43 @@ def snapshot(t0: float = float("-inf"), t1: float = float("inf")) -> Optional[di
                 + [int(r[7]), int(r[8]), int(r[9]), int(r[10])]
                 for r in keep.tolist()
             ]
+    return out
+
+
+def _cpu_by_thread(t, t0: float, t1: float) -> str:
+    """From the ledger's rows in table `t`: the CPU seconds each thread
+    spent from the last wake at or before `t0` to the last wake so far
+    (by name, the most first; ``native`` the rest of the process), and
+    every stall that overlapped ``[t0, t1)`` with the thread that ran
+    through it.  Empty where the ledger has no wake before `t0`."""
+    import numpy as np
+
+    live = t[t[:, _ID] > 0]
+    wakes = live[live[:, _NAME] == _name_ids.get("proc.cpu.process", -1)]
+    before = wakes[wakes[:, _END] <= t0]
+    if not len(before):
+        return ""
+    w0 = before[np.argmax(before[:, _END])]
+    w1 = wakes[np.argmax(wakes[:, _END])]
+    if w1[_END] <= w0[_END]:
+        return ""
+    rows = live[(live[:, _NAME] == _name_ids.get("proc.cpu", -1))
+                & (live[:, _START] > w0[_END]) & (live[:, _START] <= w1[_END])]
+    by: dict = {}
+    for r in rows.tolist():
+        who = _thread_names.get(int(r[_N]), "?")
+        by[who] = by.get(who, 0.0) + r[_CPU1] - r[_CPU0]
+    rest = (w1[_CPU1] - w0[_CPU1]) - sum(by.values())
+    if rest > 0.0:
+        by[_thread_names[NATIVE]] = rest
+    out = f"; cpu over {w1[_END] - w0[_END]:.3f}s: " + ", ".join(
+        f"{who} {cpu:.3f}s" for who, cpu in sorted(by.items(), key=lambda kv: -kv[1])
+    )
+    stalls = live[(live[:, _NAME] == _name_ids.get("proc.stall", -1))
+                  & (live[:, _START] < t1) & (live[:, _END] > t0)]
+    for r in stalls.tolist():
+        out += (f"; stall {r[_END] - r[_START]:.3f}s: "
+                f"{_thread_names.get(int(r[_N]), '?')} cpu {r[_A0]:.3f}s")
     return out
 
 
@@ -527,8 +757,9 @@ class Trace:
         """What ran beside this cycle: the other threads' spans that
         overlapped it (count and wall seconds by thread and name, and
         the thread's CPU seconds where every one of them has the
-        reading: a tally, a collection and a span still open have none)
-        and every collection inside it."""
+        reading: a tally, a collection and a span still open have none),
+        every collection inside it, and every thread's CPU from the
+        ledger (``_cpu_by_thread``)."""
         import numpy as np
 
         t = _table(_smv, _SW, SPAN_ROWS, _span_ctr)[0]
@@ -540,9 +771,12 @@ class Trace:
         ends = np.where(t[:, _END] == t[:, _END], t[:, _END], t1)
         hit = t[(t[:, _ID] > 0) & (t[:, _START] < t1) & (ends > t0)]
         gc_id = _name_ids.get("gc", -1)
+        ledger = {_name_ids.get(n, -1) for n in LEDGER_ROWS}
         threads = {th.ident: th.name for th in threading.enumerate()}
         agg: dict = {}
         for r in hit.tolist():
+            if r[_NAME] in ledger:
+                continue
             if r[_NAME] == gc_id:
                 key = ("gc", f"gen{int(r[_A0])}")
             elif r[_TID] != root[_TID]:
@@ -573,7 +807,7 @@ class Trace:
             )
         if gcs:
             out += "; gc " + ", ".join(gcs)
-        return out
+        return out + _cpu_by_thread(t, t0, t1)
 
     def log_if_long(self, threshold: Optional[float] = None) -> None:
         limit = self.threshold if threshold is None else threshold
@@ -587,7 +821,7 @@ class Trace:
         # thread (deferred-cycle finalize) can't double-emit either.
         if total < limit:
             return
-        with _LOG_ONCE:
+        with _LOCK:
             if self._logged:
                 return
             self._logged = True

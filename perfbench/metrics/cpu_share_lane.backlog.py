@@ -1,0 +1,10 @@
+"""Share of the process's CPU over the ledger's span between the edges (the first to the last
+proc.cpu.process row) that the scheduling lanes spent (scheduler, scheduler-lane*: pop, encode,
+dispatch, decode, staging), in percent (perfbench/threadcpu.py).  None on a program without the
+CPU ledger."""
+
+from perfbench import threadcpu
+
+
+def read(rec):
+    return threadcpu.share(rec, "lane")

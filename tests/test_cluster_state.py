@@ -2,6 +2,7 @@
 and the assume/forget protocol (cache.go:57-260 analogue)."""
 
 import copy
+import gc
 import sys
 import time
 
@@ -413,11 +414,15 @@ def _encode_counting_calls(st, pending):
             calls += 1
 
     t0 = time.perf_counter()
+    # no collection inside: whether one falls there follows what every other
+    # thread of the process allocates, and its callback's calls would count
+    gc.disable()
     sys.setprofile(count)
     try:
         st.builder.build_from_state(st, pending)
     finally:
         sys.setprofile(None)
+        gc.enable()
     rows = [dict(zip(trace.SPAN_FIELDS, r))
             for r in trace.snapshot(t0, float("inf"))["spans"]]
     span, = [r for r in rows if r["name"] == "sched.encode.constraints"]

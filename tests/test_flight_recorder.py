@@ -154,6 +154,10 @@ def test_the_module_creates_one_lock_and_a_pods_path_takes_none(monkeypatch):
         def __exit__(self, *exc):
             self.release()
 
+    # a reload starts the module afresh: no CPU ledger may run across it
+    owners = set(trace._ledger_owners)
+    for o in owners:
+        trace.stop_cpu_ledger(o)
     monkeypatch.setattr(threading, "Lock", Counting)
     monkeypatch.setattr(threading, "RLock", Counting)
     try:
@@ -171,6 +175,8 @@ def test_the_module_creates_one_lock_and_a_pods_path_takes_none(monkeypatch):
     finally:
         monkeypatch.undo()
         importlib.reload(trace)
+        for o in owners:
+            trace.start_cpu_ledger(o)
     assert sum(1 for cb in gc.callbacks
                if getattr(cb, "__module__", "") == trace.__name__) == 1
 
