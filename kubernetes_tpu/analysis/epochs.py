@@ -211,7 +211,8 @@ def audit_mirror(mirror, state) -> None:
 
 def audit_partials(partials, state) -> None:
     """Consume-time audit of a PartialsCache: called from
-    encode_pending right after partials.sync(), under the cache lock.
+    encode_pending once the partials have synced (PartialsCache.prepare
+    or the encode's staging program), under the cache lock.
     Skips cleanly when the cache declined the batch (no stamp and no
     store is a cold solve, not a violation)."""
     a = _active

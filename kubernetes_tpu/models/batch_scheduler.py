@@ -29,7 +29,7 @@ import contextlib
 import logging
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -41,11 +41,12 @@ from ..analysis.markers import hot_path
 from ..api import types as api
 from ..ops import assign as assign_ops
 from ..ops import auction as auction_ops
+from ..ops import partials as partials_ops
 from ..ops import schema
 from ..ops.scores import DEFAULT_SCORE_CONFIG, ScoreConfig
 from ..testing import faults
 from ..utils import trace
-from .mirror import DeviceClusterMirror
+from .mirror import DeviceClusterMirror, pad_rows, scatter_usage
 from .partials import PartialsCache
 
 Result = Union[assign_ops.SolveResult, auction_ops.AuctionResult]
@@ -300,6 +301,7 @@ def _device_fill_shortcut(
     no_bound_pods: bool = False,
     features=None,
     put=None,
+    issued: Optional[_Issued] = None,
 ) -> schema.Snapshot:
     """Replace constant-filled pod/constraint tables with (cached)
     device-side fills before transfer.
@@ -320,20 +322,25 @@ def _device_fill_shortcut(
     mesh mode passes a replicated-NamedSharding device_put so every
     leaf lands on the same device set as the sharded mirror (mixing
     single-device-committed and mesh-committed jit operands is a
-    placement error)."""
+    placement error).  issued: counts the fills' programs and the
+    pre-wrapped transfers."""
     import jax.numpy as jnp
 
     if put is None:
         put = jax.device_put
+    if issued is None:
+        issued = _Issued()
 
     def fill(shape, dtype, value):
         key = (shape, np.dtype(dtype).str, value)
         if cache is None:
+            issued.programs += 1
             return put(jnp.full(shape, value, dtype))
         hit = cache.get(key)
         if hit is None:
             if len(cache) >= _FILL_CACHE_MAX:
                 cache.clear()
+            issued.programs += 1
             hit = cache[key] = put(jnp.full(shape, value, dtype))
         return hit
 
@@ -354,6 +361,7 @@ def _device_fill_shortcut(
             return arr
         if is_zero:
             return fill(a.shape, a.dtype, 0.0)
+        issued.transfers += 1
         return put(a)  # pre-wrap: skips shortcut's min/max
 
     spread_z = terms_z = pref_z = no_bound_pods
@@ -383,7 +391,72 @@ def _device_fill_shortcut(
     return rest._replace(cluster=snap.cluster)
 
 
-def _packed_device_put(tree, unpack_cache: dict, put=None):
+class _Issued:
+    """Host→device transfers and device programs counted by the encode's
+    own staging helpers (span sched.encode.stage adds the mirror's and
+    the partials' counters to these)."""
+
+    __slots__ = ("transfers", "programs")
+
+    def __init__(self):
+        self.transfers = 0
+        self.programs = 0
+
+
+class _Stage(NamedTuple):
+    """What the staging program carries beside the pod leaves: host
+    segments `segs` = (usage row indices, the rows of requested,
+    nonzero_requested and port_bits at them, the partials' refresh
+    indices, the batch's slot array), each row segment
+    PartialsCache.ROW_CHUNK long and padded with the node bucket (the
+    program's scatters drop those), and the device residents it applies
+    them to."""
+
+    segs: tuple
+    cluster: schema.ClusterTensors
+    store: partials_ops.PartialsStore
+    specs: partials_ops.ClassSpecs
+
+
+def _segment(b, spec):
+    shape, dt, seg_bytes, o = spec
+    seg = jax.lax.slice(b, (o,), (o + seg_bytes,))
+    return seg.view(np.dtype(dt)).reshape(shape)
+
+
+def _unpack_program(specs):
+    def _unpack(b):
+        return tuple(_segment(b, spec) for spec in specs)
+
+    return jax.jit(_unpack)
+
+
+def _stage_program(specs, stage_specs):
+    """The staging program of one layout: in order, scatter the usage
+    rows into the resident mirror leaves, refresh the partials' dirty
+    columns against the updated leaves, gather the batch's statics by
+    slot, and unpack the pod leaves.  Returns (usage leaves, store,
+    statics, pod leaves).  Nothing is donated: the old residents may be
+    a speculation bookmark's."""
+
+    def _stage(b, cluster, store, class_specs):
+        uidx, *vals, ridx, slots = (_segment(b, spec) for spec in stage_specs)
+        usage = scatter_usage(cluster, uidx, vals)
+        store = partials_ops.refresh_rows(
+            store, class_specs, cluster._replace(**usage), ridx
+        )
+        statics = partials_ops.gather_statics(store, slots)
+        return usage, store, statics, tuple(
+            _segment(b, spec) for spec in specs
+        )
+
+    return jax.jit(_stage)
+
+
+def _packed_device_put(
+    tree, unpack_cache: dict, put=None, stage: Optional[_Stage] = None,
+    issued: Optional[_Issued] = None,
+):
     """device_put with all host leaves coalesced into ONE transfer.
 
     A Snapshot has ~40 host-side pod/constraint leaves and a naive
@@ -394,13 +467,20 @@ def _packed_device_put(tree, unpack_cache: dict, put=None):
     Device-resident leaves (mirror tensors, cached fills) pass through
     untouched.
 
+    With `stage` the buffer also carries the stage's segments, after the
+    pod leaves, and the layout's program is the staging program
+    (_stage_program), which applies them to the stage's residents before
+    it unpacks; the result is then (tree, (usage leaves, store,
+    statics)).  Its key is the unpack's layout plus the segments', which
+    are fixed per deployment: one program per layout either way.
+
     The staging buffer is double-buffered per layout instead of freshly
     allocated per batch: the allocate+zero of a multi-MB buffer every
     step showed up in encode profiles, and a layout recurs every batch
     once shapes warm up.  device_put returns BEFORE the runtime has
     read the host memory (on a TPU v5e a buffer overwritten right after
     the call reached the device corrupted; the CPU backend may alias it
-    outright), so each slot keeps one output of the unpack program that
+    outright), so each slot keeps one output of the program that
     last consumed it and is rewritten only once that output is ready:
     the program has run, so the copy out of the host buffer is over.
     With two alternating slots that wait is for the batch before last
@@ -408,43 +488,48 @@ def _packed_device_put(tree, unpack_cache: dict, put=None):
     on how deep the caller pipelines.
 
     put: placement for the staging buffer (mesh mode passes a
-    replicated-NamedSharding device_put — see _device_fill_shortcut)."""
+    replicated-NamedSharding device_put — see _device_fill_shortcut).
+    issued: counts the transfers and programs."""
     if put is None:
         put = jax.device_put
+    if issued is None:
+        issued = _Issued()
     leaves, treedef = jax.tree.flatten(tree)
     host_idx = [i for i, l in enumerate(leaves) if not isinstance(l, jax.Array)]
-    if len(host_idx) <= 2:
+    if stage is None and len(host_idx) <= 2:
         # put only the host leaves: re-putting the device-resident ones
         # (the sharded mirror tensors under a mesh) would reshard them
         for i in host_idx:
             leaves[i] = put(leaves[i])
+        issued.transfers += len(host_idx)
         return jax.tree.unflatten(treedef, leaves)
     arrs = [np.ascontiguousarray(leaves[i]) for i in host_idx]
+    extra = (
+        [] if stage is None else [np.ascontiguousarray(a) for a in stage.segs]
+    )
     offsets, off = [], 0
-    for a in arrs:
+    for a in arrs + extra:
         off = (off + 3) & ~3  # 4-byte align each segment
         offsets.append(off)
         off += a.nbytes
-    specs = tuple(
-        (a.shape, a.dtype.str, a.nbytes, o) for a, o in zip(arrs, offsets)
+    layout = tuple(
+        (a.shape, a.dtype.str, a.nbytes, o)
+        for a, o in zip(arrs + extra, offsets)
     )
+    specs, stage_specs = layout[: len(arrs)], layout[len(arrs):]
+    key = specs if stage is None else (specs, stage_specs)
     nbytes = (off + 3) & ~3
-    entry = unpack_cache.get(specs)
+    entry = unpack_cache.get(key)
     if entry is None:
         if len(unpack_cache) >= _FILL_CACHE_MAX:
             unpack_cache.clear()  # retired layouts: drop their executables
-
-        def _unpack(b):
-            outs = []
-            for shape, dt, seg_bytes, o in specs:
-                seg = jax.lax.slice(b, (o,), (o + seg_bytes,))
-                outs.append(seg.view(np.dtype(dt)).reshape(shape))
-            return tuple(outs)
-
-        entry = unpack_cache[specs] = {
-            "unpack": jax.jit(_unpack),
+        entry = unpack_cache[key] = {
+            "program": (
+                _unpack_program(specs) if stage is None
+                else _stage_program(specs, stage_specs)
+            ),
             "bufs": [None, None],
-            "consumed": [None, None],  # an unpack output per slot
+            "consumed": [None, None],  # a program output per slot
             "flip": 0,
         }
     flip = entry["flip"]
@@ -454,18 +539,37 @@ def _packed_device_put(tree, unpack_cache: dict, put=None):
         buf = entry["bufs"][flip] = np.zeros(nbytes, dtype=np.uint8)
     elif entry["consumed"][flip] is not None:
         entry["consumed"][flip].block_until_ready()
-    for a, o in zip(arrs, offsets):
+    for a, o in zip(arrs + extra, offsets):
         buf[o : o + a.nbytes] = a.view(np.uint8).ravel()
-    unpack = entry["unpack"]
-    outs = unpack(put(buf[:nbytes]))
-    entry["consumed"][flip] = outs[0]
-    # layout churn recompiles the unpack program: report it to the
-    # recompile-discipline tracker like the solver dispatches (specs IS
-    # the executable key here)
-    retrace.note("snapshot-unpack", unpack, lambda: specs)
+    program = entry["program"]
+    issued.transfers += 1
+    issued.programs += 1
+    if stage is None:
+        outs = program(put(buf[:nbytes]))
+        applied = None
+        # layout churn recompiles the unpack program: report it to the
+        # recompile-discipline tracker like the solver dispatches (specs
+        # IS the executable key here)
+        retrace.note("snapshot-unpack", program, lambda: specs)
+    else:
+        usage, store, statics, outs = program(
+            put(buf[:nbytes]), stage.cluster, stage.store, stage.specs
+        )
+        applied = (usage, store, statics)
+        # the residents' shapes are fixed per deployment (cap, n, r)
+        # but belong to the key all the same
+        retrace.note(
+            "snapshot-stage", program,
+            lambda: (key, tuple(
+                np.shape(a)
+                for a in jax.tree.leaves((stage.cluster, stage.store))
+            )),
+        )
+    entry["consumed"][flip] = outs[0] if stage is None else applied[2].sfeas
     for i, out in zip(host_idx, outs):
         leaves[i] = out
-    return jax.tree.unflatten(treedef, leaves)
+    tree = jax.tree.unflatten(treedef, leaves)
+    return tree if stage is None else (tree, applied)
 
 
 class DeviceSolve:
@@ -1330,47 +1434,8 @@ class TPUBatchScheduler:
             # per-batch host→device traffic stays O(changed rows) in
             # both layouts.
             if self.use_mirror:
-                dev_cluster = self._mirror.sync()
-                epochs.audit_mirror(self._mirror, self.state)
-                if (
-                    self._partials is not None
-                    and meta.route in ("greedy", "wavefront")
-                ):
-                    # warm-start statics for the greedy-family routes:
-                    # re-evaluate only the rows dirtied since the last
-                    # sync (plus first-seen classes) against the SAME
-                    # resident tensors the solve consumes.  The cache is
-                    # an optimization layer: any failure inside it
-                    # (including injected solve.partials faults) falls
-                    # back to the cold in-program class_statics path and
-                    # invalidates the residents.
-                    try:
-                        meta.statics = self._partials.sync(
-                            dev_cluster, snap, meta,
-                            cluster_epoch=self._mirror.epoch(),
-                        )
-                    except Exception:  # noqa: BLE001 — cold solve instead
-                        self._partials.invalidate()  # graftlint: disable=coherence -- partials-only fault: the mirror synced cleanly above and is not a suspect
-                        _log.exception(
-                            "partials sync failed on %s; cold solve for "
-                            "this batch", device_label(),
-                        )
-                    if meta.statics is not None:
-                        # a MAX_SLOTS decline (statics None) leaves the
-                        # store legitimately behind the cache — audit
-                        # only what this solve actually consumes
-                        epochs.audit_partials(self._partials, self.state)
-                        meta.coherence_stamp = (
-                            self._mirror.epoch(), self._partials.epoch()
-                        )
-                snap = snap._replace(cluster=dev_cluster)
-                snap = _device_fill_shortcut(
-                    snap, self._fill_cache, no_bound_pods=no_bound,
-                    features=meta.features, put=self._put,
-                )
-                snap = _packed_device_put(
-                    snap, self._unpack_cache, put=self._put
-                )
+                with trace.span("sched.encode.stage") as sp:
+                    snap = self._stage(snap, meta, no_bound, sp)
             else:
                 # DeviceClusterMirror gate off: full host copy +
                 # transfer every step (the pre-mirror behavior — the
@@ -1395,6 +1460,134 @@ class TPUBatchScheduler:
             )
             snap = snap._replace(cluster=cluster)
         return snap, meta
+
+    def _stage(
+        self, snap: schema.Snapshot, meta: schema.SnapshotMeta,
+        no_bound: bool, sp,
+    ) -> schema.Snapshot:
+        """The encode's staging: bring the resident mirror and partials
+        to the state's current generation and put the pod tables on the
+        device (docs/scheduler_loop.md, "The encode's staging").
+
+        Where the mirror's sync is a scatter of at most ROW_CHUNK usage
+        rows and the partials' a refresh of at most ROW_CHUNK dirty rows
+        and a gather — what the encode observes, no knob — all of it
+        rides the pod tables' packed buffer: ONE transfer and ONE staging
+        program.  Everything else (a full upload, dirty static rows, more
+        rows, a resize, first-seen classes, a periodic recompute, an
+        injected fault, a mesh) takes mirror.sync() and
+        PartialsCache.prepare() as they are, and the staging program then
+        only gathers and unpacks; a batch without warm statics (the
+        auction's route, a declined or failed sync, the partials gate
+        off) takes the plain unpack.  Fault points fire in the same order
+        on both paths.  `sp` (span sched.encode.stage) gets n =
+        transfers, a0 = programs, a1 = 1 where the staging program
+        carried the cycle's state."""
+        mirror, partials = self._mirror, self._partials
+        issued = _Issued()
+        counters = [issued, mirror] + ([partials] if partials else [])
+
+        def count():
+            return (sum(c.transfers for c in counters),
+                    sum(c.programs for c in counters))
+
+        transfers0, programs0 = count()
+        statics = None
+        rows = PartialsCache.ROW_CHUNK
+        warm = partials is not None and meta.route in ("greedy", "wavefront")
+        # the mirror's plan fires no fault point: where it declines,
+        # sync() runs first, as before, so mirror.grow still fires before
+        # solve.partials
+        mplan = mirror.plan_rows(rows) if warm else None
+        if mplan is None:
+            dev_cluster = mirror.sync()
+            epochs.audit_mirror(mirror, self.state)
+        plan = dirty = None
+        if warm:
+            # warm-start statics for the greedy-family routes: the cache
+            # is an optimization layer, so any failure inside it
+            # (including injected solve.partials faults) falls back to
+            # the cold in-program class_statics path and invalidates its
+            # residents
+            try:
+                plan = partials.plan(
+                    snap, meta, int(snap.cluster.allocatable.shape[0])
+                )
+            except Exception:  # noqa: BLE001 — cold solve instead
+                self._partials_failed(partials)
+        if mplan is not None and plan is not None:
+            dirty = partials.staged_rows(plan, rows)
+        if dirty is not None:
+            host, usage_idx = mplan
+            cluster = mirror.resident()
+            stage = _Stage(
+                mirror.stage_rows(host, usage_idx, rows)
+                + (pad_rows(dirty, plan.n, rows),
+                   partials.slot_array(plan)),
+                cluster, *partials.residents(),
+            )
+        else:
+            if mplan is not None:
+                dev_cluster = mirror.sync()  # no resize: no fault point
+                epochs.audit_mirror(mirror, self.state)
+            slots = None
+            if plan is not None:
+                try:
+                    slots = partials.prepare(
+                        dev_cluster, snap, plan,
+                        cluster_epoch=mirror.epoch(),
+                    )
+                except Exception:  # noqa: BLE001 — cold solve instead
+                    self._partials_failed(partials)
+            cluster = dev_cluster
+            stage = None
+            if slots is not None and self.mesh is not None:
+                # the residents are sharded: the cache's own pinned
+                # gather, and the plain unpack
+                statics = partials.gather(slots, plan)
+            elif slots is not None:
+                # the staging program only gathers and unpacks: its row
+                # segments are empty (every index past the last row)
+                none = np.zeros(0, dtype=np.int32)
+                stage = _Stage(
+                    mirror.stage_rows(self.state.tensors(), none, rows)
+                    + (pad_rows(none, plan.n, rows), slots),
+                    cluster, *partials.residents(),
+                )
+        snap = _device_fill_shortcut(
+            snap._replace(cluster=cluster), self._fill_cache,
+            no_bound_pods=no_bound, features=meta.features, put=self._put,
+            issued=issued,
+        )
+        if stage is None:
+            snap = _packed_device_put(
+                snap, self._unpack_cache, put=self._put, issued=issued
+            )
+        else:
+            snap, (usage, store, statics) = _packed_device_put(
+                snap, self._unpack_cache, put=self._put, stage=stage,
+                issued=issued,
+            )
+            if dirty is not None:
+                mirror.adopt_rows(usage, mplan[1].shape[0])
+                epochs.audit_mirror(mirror, self.state)
+                partials.adopt(plan, dirty, store, mirror.epoch())
+                snap = snap._replace(cluster=mirror.resident())
+        if statics is not None:
+            meta.statics = statics
+            epochs.audit_partials(partials, self.state)
+            meta.coherence_stamp = (mirror.epoch(), partials.epoch())
+        transfers1, programs1 = count()
+        sp.n, sp.a0 = transfers1 - transfers0, programs1 - programs0
+        sp.a1 = 1.0 if dirty is not None else 0.0
+        return snap
+
+    def _partials_failed(self, partials: PartialsCache) -> None:
+        partials.invalidate()  # graftlint: disable=coherence -- partials-only fault: the mirror synced cleanly above and is not a suspect
+        _log.exception(
+            "partials sync failed on %s; cold solve for this batch",
+            device_label(),
+        )
 
     @hot_path
     def solve_encoded_async(

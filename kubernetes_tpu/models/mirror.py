@@ -39,10 +39,22 @@ Row updates are bucketed to powers of two and padded by repeating the
 first dirty row (duplicate scatter-set of identical values is a
 no-op), so the jit cache stays small and stable.
 
+A sync that is a usage scatter of at most a few hundred rows and
+nothing else can skip sync() altogether: plan_rows() says so,
+stage_rows() lays the rows out as segments of the encode's one staging
+buffer (models/batch_scheduler._packed_device_put), and adopt_rows()
+takes the staging program's scattered usage leaves as the resident ones
+— one transfer and one program for the mirror, the partials and the pod
+tables together, where the scatter here costs four transfers and three
+programs (docs/scheduler_loop.md, "The encode's staging").
+
 `resync_total` / `delta_rows_total` / `delta_syncs` count full uploads
-and real (unbucketed) scattered rows — `scheduler_mirror_resync_total`
-/ `scheduler_mirror_delta_rows` read them through `stats()`;
-tests/test_mirror.py holds steady-state transfer to O(changed rows).
+and real (unbucketed) scattered rows, on either path —
+`scheduler_mirror_resync_total` / `scheduler_mirror_delta_rows` read
+them through `stats()`; tests/test_mirror.py holds steady-state
+transfer to O(changed rows).  `transfers` / `programs` count the
+host→device transfers and device programs the mirror issued (span
+`sched.encode.stage` reads their change over one encode).
 """
 
 from __future__ import annotations
@@ -125,6 +137,26 @@ def _pad_idx(idx: np.ndarray, bucket: int) -> np.ndarray:
     return out
 
 
+def scatter_usage(cluster: schema.ClusterTensors, idx, vals) -> dict:
+    """{usage leaf: `cluster`'s leaf with its row of `vals` (in
+    _USAGE_LEAVES order) written at rows `idx`}; an index past the last
+    row is dropped.  Traced inside the encode's staging program
+    (DeviceClusterMirror.stage_rows lays out `idx` and `vals`,
+    adopt_rows takes the result)."""
+    return {
+        leaf: getattr(cluster, leaf).at[idx].set(v, mode="drop")
+        for leaf, v in zip(_USAGE_LEAVES, vals)
+    }
+
+
+def pad_rows(idx: np.ndarray, n: int, rows: int) -> np.ndarray:
+    """`idx` padded to `rows` with n, one past the last row of an n-row
+    bucket: the staging program's scatters drop those writes."""
+    out = np.full(rows, n, dtype=np.int32)
+    out[: idx.shape[0]] = idx
+    return out
+
+
 class DeviceClusterMirror:
     """One consumer's device copy of a ClusterState's cluster tensors.
 
@@ -174,6 +206,10 @@ class DeviceClusterMirror:
         # into scheduler_mirror_grow_total / scheduler_mirror_grow_rows.
         self.grow_syncs = 0        # in-place resident grows/shrinks
         self.grow_rows_total = 0   # axis rows added without a re-upload
+        # host→device transfers and device programs issued, whatever the
+        # path (span sched.encode.stage: their change over one encode)
+        self.transfers = 0
+        self.programs = 0
         # safety valve: False restores the pre-elastic behavior — every
         # shape change performs the full (RESHARDED under a mesh)
         # re-upload; the parity oracle tests drive it
@@ -305,6 +341,76 @@ class DeviceClusterMirror:
         )
         return dev
 
+    def plan_rows(
+        self, rows: int
+    ) -> Optional[Tuple[schema.ClusterTensors, np.ndarray]]:
+        """(host tensors, dirty usage rows) when sync() would scatter at
+        most `rows` usage rows and do nothing else, so that the encode's
+        staging program can carry them instead; None when sync() has more
+        to do (a first or full upload, an elastic resize, dirty static
+        rows, more rows) or the resident copy is sharded over a mesh.
+        No device work, no fault point, no state change.  Caller holds
+        the cache lock (sync() contract)."""
+        state = self.state
+        if (
+            self.mesh is not None
+            or self._dev is None
+            or self._struct_gen < state.struct_generation
+        ):
+            return None
+        host = state.tensors()
+        if tuple(np.shape(leaf) for leaf in host) != self._shape:
+            return None
+        if self._synced_gen == state.generation:
+            return host, np.zeros(0, dtype=np.int32)
+        n = host.allocatable.shape[0]
+        static_idx, usage_idx = state.dirty_rows(self._synced_gen, n)
+        if static_idx.shape[0] or usage_idx.shape[0] > min(
+            rows, self.FULL_SYNC_FRACTION * n
+        ):
+            return None
+        return host, usage_idx
+
+    def resident(self) -> Optional[schema.ClusterTensors]:
+        """The resident tensors as they stand, unsynced: what the staging
+        program scatters plan_rows()' rows into."""
+        return self._dev
+
+    @staticmethod
+    def stage_rows(
+        host: schema.ClusterTensors, usage_idx: np.ndarray, rows: int
+    ) -> tuple:
+        """The staging buffer's mirror segments: the dirty rows' indices
+        padded to `rows` with n (one past the last row: the staging
+        program's scatter drops them), then each usage leaf's host rows
+        at those indices, zero-padded."""
+        k = usage_idx.shape[0]
+        segs = [pad_rows(usage_idx, host.allocatable.shape[0], rows)]
+        for leaf in _USAGE_LEAVES:
+            src = np.asarray(getattr(host, leaf))
+            vals = np.zeros((rows,) + src.shape[1:], dtype=src.dtype)
+            vals[:k] = src[usage_idx]
+            segs.append(vals)
+        return tuple(segs)
+
+    def adopt_rows(self, usage: dict, rows: int) -> None:
+        """Take the staging program's usage leaves (scatter_usage) as the
+        resident ones: what sync() leaves after a delta of `rows` usage
+        rows, counters included.  The old leaves are not donated: a
+        speculation_point() bookmark may hold them."""
+        state = self.state
+        if self._synced_gen == state.generation:
+            return  # sync() would have returned at once
+        if rows:
+            self._dev = self._dev._replace(**usage)
+        self.delta_syncs += 1
+        self.delta_rows_total += int(rows)
+        self._synced_gen = state.generation
+        self._epoch = epochs.EpochStamp(
+            "mirror", self._struct_gen, None, self._synced_gen,
+            self._buffer_id,
+        )
+
     def _resize_resident(self, shape) -> Optional[schema.ClusterTensors]:
         """Grow (device-side pad) or shrink (device-side slice) the
         resident tensors to the new padded bucket, preserving every
@@ -362,6 +468,7 @@ class DeviceClusterMirror:
                     if dn > 0 else shrink(leaf, new_n)
                 )
         self.grow_syncs += 1
+        self.programs += len(updates)
         if dn > 0:
             self.grow_rows_total += dn
         kernel = grow if dn > 0 else shrink
@@ -380,6 +487,7 @@ class DeviceClusterMirror:
             dev = dev._replace(
                 allocatable=jnp.full_like(dev.allocatable, jnp.inf)
             )
+            self.programs += 1
         return dev
 
     def stats(self) -> dict:
@@ -394,7 +502,7 @@ class DeviceClusterMirror:
     def epoch(self) -> Optional[epochs.EpochStamp]:
         """The resident buffer's epoch stamp (None when invalidated or
         never synced) — read by the GRAFTLINT_COHERENCE auditor and by
-        PartialsCache.sync's lineage stamping."""
+        the PartialsCache's lineage stamping."""
         return self._epoch
 
     def speculation_point(self) -> tuple:
@@ -462,6 +570,7 @@ class DeviceClusterMirror:
         # zero-copy a numpy view, which would alias live cache state
         # (see TPUBatchScheduler.encode_pending's aliasing note)
         self.resync_total += 1
+        self.transfers += len(host)
         self._buffer_id = epochs.fresh_buffer_id()
         copied = jax.tree.map(np.array, host)
         if self._shardings is None:
@@ -516,6 +625,8 @@ class DeviceClusterMirror:
     def _scatter_usage(self, base, host, pidx: np.ndarray, set_rows) -> dict:
         """{leaf: resident leaf with the host's rows `pidx` written in}
         for the usage family."""
+        self.transfers += 1 + len(_USAGE_LEAVES)
+        self.programs += len(_USAGE_LEAVES)
         idx_dev = self._put_small(pidx)
         return {
             leaf: set_rows(
@@ -539,6 +650,8 @@ class DeviceClusterMirror:
         if static_idx.shape[0]:
             bucket = vb.pad_dim(static_idx.shape[0], 1)
             pidx = _pad_idx(static_idx, bucket)
+            self.transfers += 2 + len(_STATIC_LEAVES)
+            self.programs += 1 + len(_STATIC_LEAVES)
             idx_dev = self._put_small(pidx)
             for leaf in _STATIC_LEAVES:
                 vals = self._put_small(np.asarray(getattr(host, leaf))[pidx])

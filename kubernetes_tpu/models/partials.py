@@ -22,6 +22,14 @@ mirror.sync()):
   3. the solver consumes a batch-ordered gather — the `statics=`
      operand of the warm greedy/wavefront executables.
 
+A sync is plan() (host only: keys, misses, staleness, the fault point),
+then either prepare() (steps 1 and 2 in their own programs) or, when the
+sync is a refresh of at most ROW_CHUNK dirty rows and a gather, neither:
+the encode's staging program refreshes and gathers beside the mirror's
+row scatter and the pod tables' unpack, and adopt() takes its store
+(models/batch_scheduler._packed_device_put).  The gather runs inside
+the staging program on either path; gather() is the mesh's.
+
 Resync discipline (the mirror's, applied whole):
 
   * full recompute when the struct generation moved, the padded node
@@ -43,14 +51,14 @@ resident score rows with NaN so the decode-side health check
 (SolveUnhealthy) trips and the breaker/retry path falls back to a full
 recompute — the parity gate's runtime wire.
 
-All state is mutated under the scheduler-cache lock (sync() shares
+All state is mutated under the scheduler-cache lock (a sync shares
 encode_pending's locked section), like the mirror's counters.
 """
 
 from __future__ import annotations
 
 import logging
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
@@ -82,6 +90,22 @@ def _poison_aff(store: pops.PartialsStore) -> pops.PartialsStore:
     import jax.numpy as jnp
 
     return store._replace(aff=jnp.full_like(store.aff, jnp.inf))
+
+
+class SyncPlan(NamedTuple):
+    """What one sync has to do (PartialsCache.plan): read on the host
+    before any device work, so the encode can choose between prepare()
+    and its staging program."""
+
+    act: object           # what the solve.partials fault point drew
+    keys: list            # class keys of the batch's real classes
+    c_dim: int            # the batch's padded class dim
+    n: int                # padded node bucket
+    vkey: tuple           # vocabulary watermark
+    sharded: bool         # resident layout node-axis sharded
+    stale: bool           # a full recompute is due
+    misses: list          # first-seen keys, in order
+    declined: bool        # more live classes than MAX_SLOTS
 
 
 class PartialsCache:
@@ -133,13 +157,17 @@ class PartialsCache:
         self._inval_gen = 0
         # counters (read by the scheduler_partials_* gauges through
         # stats()); mutated under the cache
-        # lock — sync() runs inside encode_pending's locked section
+        # lock — a sync runs inside encode_pending's locked section
         self.hit_rows_total = 0         # [class, row] entries served warm
         self.recomputed_rows_total = 0  # node rows re-evaluated
         self.full_recomputes = 0        # full store recomputes (any cause)
         self.rollbacks = 0              # speculation rollbacks
         self.delta_syncs = 0
         self.grows = 0                  # in-place node-axis grows/shrinks
+        # host→device transfers and device programs issued, whatever the
+        # path (span sched.encode.stage: their change over one encode)
+        self.transfers = 0
+        self.programs = 0
         # safety valve (the mirror's, same contract): False restores the
         # pre-elastic behavior — any node-axis change reseeds the whole
         # store, dropping every warm class row
@@ -437,43 +465,44 @@ class PartialsCache:
             )
         return self._eval, self._refresh, self._insert, self._gather
 
-    def sync(
-        self,
-        cluster,
-        snap: schema.Snapshot,
-        meta: schema.SnapshotMeta,
-        cluster_epoch: Optional[epochs.EpochStamp] = None,
-    ) -> Optional[pops.ClassStatics]:
-        """Warm statics for this batch, or None when the cache declines
-        (capacity overflow past MAX_SLOTS with more classes than fit).
-        `cluster` is the mirror's device-resident ClusterTensors for the
-        state's CURRENT generation — the exact tensors the solve
-        consumes, so warm rows are evaluated against what the cold path
-        would see.  Caller holds the cache lock (mirror.sync contract);
-        `snap` is still host-resident (pre-transfer).  `cluster_epoch`
-        is the mirror's epoch stamp for `cluster` — the resident store's
-        stamp inherits its buffer lineage so the GRAFTLINT_COHERENCE
-        auditor can tie the rows to the exact mirror buffer they were
-        evaluated against."""
-        state = self.state
+    def gather(self, slot_arr: np.ndarray, plan: SyncPlan) -> pops.ClassStatics:
+        """The batch-ordered [C, N] statics: the resident rows at
+        `slot_arr` (slot_array()), in the cache's own program."""
+        ga = self._kernels()[3]
+        self.transfers += 1
+        self.programs += 1
+        statics = ga(self._store, self._put(slot_arr))
+        retrace.note(
+            "partials-gather", ga,
+            lambda: ("partials-gather", self._cap, plan.n, plan.c_dim,
+                     self._resident_sharded),
+        )
+        return statics
+
+    def plan(
+        self, snap: schema.Snapshot, meta: schema.SnapshotMeta, n: int
+    ) -> SyncPlan:
+        """What this batch's sync has to do, decided on the host: the
+        `solve.partials` fault point fires here (once a sync), the class
+        keys are read and the misses, staleness and a MAX_SLOTS decline
+        are found.  No device work and no state change.  `n` is the
+        padded node bucket the mirror holds (or will hold, once synced:
+        the state's current bucket)."""
         class_rep = np.asarray(snap.pods.class_rep)
-        c_dim = class_rep.shape[0]
         n_real = int((class_rep >= 0).sum())
         act = faults.fire("solve.partials", classes=n_real)
         keys = [
             self.class_key(snap.pods, int(class_rep[c]), meta)
             for c in range(n_real)
         ]
-        n = int(cluster.allocatable.shape[0])
         vkey = self._vocab_watermark()
         if self.mesh is not None:
             sharded = n % int(self.mesh.devices.size) == 0
         else:
             sharded = False
-
         stale = (
             self._store is None
-            or self._struct_gen < state.struct_generation
+            or self._struct_gen < self.state.struct_generation
             or self._vocab_key != vkey
             or self._resident_sharded != sharded
             # the incremental_grow valve off: any node-axis change
@@ -487,16 +516,116 @@ class PartialsCache:
             dict.fromkeys(k for k in keys if k not in self._slots)
         )
         needed = len(self._slots) + len(misses)
+        declined = False
         if needed > self._cap:
             if needed > self.MAX_SLOTS:
-                return None  # more live classes than the cache may hold
+                declined = True  # more live classes than the cache may hold
             stale = True  # reallocation: reseed from this batch
         if not stale and self._since_full >= self.resync_interval:
             stale = True  # periodic full recompute (parity discipline)
+        return SyncPlan(
+            act, keys, class_rep.shape[0], n, vkey, sharded, stale,
+            misses, declined,
+        )
 
-        self._resident_sharded = sharded
+    def staged_rows(self, plan: SyncPlan, rows: int) -> Optional[np.ndarray]:
+        """The rows dirtied since the last sync when this sync is a
+        refresh of at most `rows` of them and a gather and nothing else,
+        so that the encode's staging program can run both; None when
+        prepare() has more to do (a full recompute, first-seen classes,
+        a resize, more rows), when the sync is declined, under a mesh,
+        and when the fault point drew CORRUPT (its poison is prepare()'s
+        to apply)."""
+        n = plan.n
+        if (
+            plan.declined or plan.stale or plan.misses
+            or plan.act == faults.CORRUPT
+            or self.mesh is not None or self._n != n
+        ):
+            return None
+        static_idx, usage_idx = self.state.dirty_rows(self._synced_gen, n)
+        dirty = np.union1d(static_idx, usage_idx).astype(np.int32)
+        if dirty.shape[0] > min(rows, self.FULL_SYNC_FRACTION * n):
+            return None
+        return dirty
+
+    def residents(self) -> Tuple[pops.PartialsStore, pops.ClassSpecs]:
+        """(store, specs) as they stand: what the staging program
+        refreshes and gathers from."""
+        return self._store, self._specs
+
+    def slot_array(self, plan: SyncPlan) -> np.ndarray:
+        """The batch-ordered slot of each class ([C]: padded classes
+        alias class 0's slot, the clipped-representative convention):
+        what the gather takes.  Every key has a slot once prepare() ran
+        or staged_rows() found no miss."""
+        keys = plan.keys
+        slot_arr = np.empty(plan.c_dim, dtype=np.int32)
+        for c in range(plan.c_dim):
+            slot_arr[c] = self._slots[keys[c if c < len(keys) else 0]]
+        return slot_arr
+
+    def adopt(
+        self,
+        plan: SyncPlan,
+        dirty: np.ndarray,
+        store: pops.PartialsStore,
+        cluster_epoch: Optional[epochs.EpochStamp] = None,
+    ) -> None:
+        """Take the staging program's refreshed store as the resident
+        one: what prepare()'s delta path leaves after refreshing the rows
+        `dirty` (staged_rows()), counters and stamp included."""
+        n = plan.n
+        self._store = store
+        self._resident_sharded = plan.sharded
+        self.recomputed_rows_total += int(dirty.shape[0])
+        self.hit_rows_total += len(plan.keys) * (n - int(dirty.shape[0]))
+        self.delta_syncs += 1
+        self._since_full += 1
+        self._synced_gen = self.state.generation
+        self._stamp(cluster_epoch)
+
+    def _stamp(self, cluster_epoch: Optional[epochs.EpochStamp]) -> None:
+        # the store now matches the cache's current generations, and its
+        # lineage follows the mirror buffer the rows were evaluated
+        # against (a CORRUPT fault poisons CONTENT, not epochs — the
+        # parity gate / heal wire owns that)
+        self._epoch = epochs.EpochStamp(
+            "partials", self._struct_gen, self._vocab_key,
+            self._synced_gen,
+            cluster_epoch.buffer_id if cluster_epoch is not None else 0,
+        )
+
+    def prepare(
+        self,
+        cluster,
+        snap: schema.Snapshot,
+        plan: SyncPlan,
+        cluster_epoch: Optional[epochs.EpochStamp] = None,
+    ) -> Optional[np.ndarray]:
+        """The sync by `plan` up to its gather: bring the resident store
+        to the state's current generation (full recompute, resize,
+        first-seen inserts, dirty-row refresh, each its own program) and
+        return the batch's slot array, or None when the cache declines
+        (capacity overflow past MAX_SLOTS with more classes than fit).
+        `cluster` is the mirror's device-resident ClusterTensors for the
+        state's CURRENT generation — the exact tensors the solve
+        consumes, so warm rows are evaluated against what the cold path
+        would see.  Caller holds the cache lock (mirror.sync contract);
+        `snap` is still host-resident (pre-transfer).  `cluster_epoch`
+        is the mirror's epoch stamp for `cluster` — the resident store's
+        stamp inherits its buffer lineage so the GRAFTLINT_COHERENCE
+        auditor can tie the rows to the exact mirror buffer they were
+        evaluated against."""
+        if plan.declined:
+            return None
+        state = self.state
+        class_rep = np.asarray(snap.pods.class_rep)
+        keys, misses, n, vkey = plan.keys, plan.misses, plan.n, plan.vkey
+        n_real = len(keys)
+        self._resident_sharded = plan.sharded
         ev, rf, ins, ga = self._kernels()
-        if stale:
+        if plan.stale:
             self._full_reset(cluster, snap, keys, n, vkey, ev)
         else:
             static_idx, usage_idx = state.dirty_rows(self._synced_gen, n)
@@ -534,6 +663,8 @@ class PartialsCache:
                             self._put,
                             self._stack_spec_rows(seg_rows, chunk),
                         )
+                        self.transfers += 1 + len(rows)
+                        self.programs += 2
                         self._specs = self._set_specs(self._specs, rows, idx)
                         self._store = ins(
                             self._store, self._specs, cluster, idx
@@ -551,6 +682,8 @@ class PartialsCache:
                         idx = self._put(
                             _pad_idx(dirty[off:off + chunk], chunk)
                         )
+                        self.transfers += 1
+                        self.programs += 1
                         self._store = rf(
                             self._store, self._specs, cluster, idx
                         )
@@ -564,35 +697,38 @@ class PartialsCache:
                 self.delta_syncs += 1
                 self._since_full += 1
                 self._synced_gen = state.generation
-        # stamp AFTER both paths: the store now matches the cache's
-        # current generations, and its lineage follows the mirror buffer
-        # the rows were evaluated against (a CORRUPT fault below poisons
-        # CONTENT, not epochs — the parity gate / heal wire owns that)
-        self._epoch = epochs.EpochStamp(
-            "partials", self._struct_gen, self._vocab_key,
-            self._synced_gen,
-            cluster_epoch.buffer_id if cluster_epoch is not None else 0,
-        )
+        # stamp AFTER both paths
+        self._stamp(cluster_epoch)
 
-        if act == faults.CORRUPT:
+        if plan.act == faults.CORRUPT:
             # poison the RESIDENT partials: the warm solve's scores go
             # NaN, the decode health check trips, and the retry path
             # invalidates this cache → full recompute (or the breaker's
             # host fallback) — chaos seeds 700-704 assert the healing
+            self.programs += 1
             self._store = _poison_aff(self._store)
+        return self.slot_array(plan)
 
-        # batch-ordered slot gather ([C] — padded classes alias class
-        # 0's slot, the clipped-representative convention)
-        slot_arr = np.empty(c_dim, dtype=np.int32)
-        for c in range(c_dim):
-            slot_arr[c] = self._slots[keys[c if c < n_real else 0]]
-        statics = ga(self._store, self._put(slot_arr))
+    def warm_refresh(self, cluster) -> None:
+        """Build or load the dirty-row refresh against the resident
+        store: Scheduler.warmup's share of the cache.  The encode's
+        staging program refreshes most cycles' dirty rows, so warmup's
+        own cycles may never run this executable; a live cycle that also
+        meets a first-seen class does (prepare()).  The result is
+        dropped: nothing resident changes.  `cluster` is the mirror's
+        resident tensors; caller holds the cache lock."""
+        if self._store is None or self._n != int(cluster.allocatable.shape[0]):
+            return
+        rf = self._kernels()[1]
+        n, r = self._n, int(cluster.allocatable.shape[1])
+        chunk = min(self.ROW_CHUNK, n)
+        rf(self._store, self._specs, cluster,
+           self._put(np.zeros(chunk, dtype=np.int32)))
         retrace.note(
-            "partials-gather", ga,
-            lambda: ("partials-gather", self._cap, n, c_dim,
+            "partials-refresh", rf,
+            lambda: ("partials-refresh", self._cap, n, r, chunk,
                      self._resident_sharded),
         )
-        return statics
 
     def _grow_kernels(self):
         """(grow_cols, shrink_cols): the pinned-sharding twins when the
@@ -613,6 +749,8 @@ class PartialsCache:
         grow_c, shrink_c = self._grow_kernels()
         old_n = self._n
         if n > old_n:
+            self.transfers += 1
+            self.programs += 2
             self._store = grow_c(self._store, n - old_n)
             gidx = np.arange(old_n, n, dtype=np.int32)
             chunk = vb.pad_dim(int(gidx.shape[0]), 1)
@@ -625,6 +763,7 @@ class PartialsCache:
                          self._resident_sharded),
             )
         else:
+            self.programs += 1
             self._store = shrink_c(self._store, n)
             retrace.note(
                 "partials-shrink", shrink_c,
@@ -674,6 +813,8 @@ class PartialsCache:
                 ),
             )
         self._specs = jax.tree.map(self._put, specs)
+        self.transfers += len(specs)
+        self.programs += 1
         self._store = ev(cluster, self._specs)
         r = int(cluster.allocatable.shape[1])
         retrace.note(

@@ -219,13 +219,15 @@ def refresh_rows(
     """Re-evaluate ONLY the node columns at `idx` (the rows dirtied
     since the last sync, bucket-padded by repeating the first index) for
     every cached slot, and scatter them into the store — the
-    O(changed-rows) half of the warm start."""
+    O(changed-rows) half of the warm start.  An index past the last
+    column is dropped (the staging program pads with one): its gathered
+    row is clamped and evaluated, and its write goes nowhere."""
     sub = take_rows(cluster, idx)
     cols = eval_store(sub, specs)  # [G, D]
     return PartialsStore(
-        sfeas=store.sfeas.at[:, idx].set(cols.sfeas),
-        aff=store.aff.at[:, idx].set(cols.aff),
-        taint=store.taint.at[:, idx].set(cols.taint),
+        sfeas=store.sfeas.at[:, idx].set(cols.sfeas, mode="drop"),
+        aff=store.aff.at[:, idx].set(cols.aff, mode="drop"),
+        taint=store.taint.at[:, idx].set(cols.taint, mode="drop"),
     )
 
 

@@ -2176,7 +2176,9 @@ class Scheduler:
         and the mirror's usage scatter at every dirty-row bucket its
         delta path serves: what a bind wave leaves and what the pods
         that left the cluster since the last encode leave, which no
-        batch size bounds (mirror.warm_usage_buckets).
+        batch size bounds (mirror.warm_usage_buckets), and the partials'
+        own dirty-row refresh, which the encode's staging program takes
+        over on most cycles (PartialsCache.warm_refresh).
 
         Before it returns it waits for the prewarm pool's outstanding
         jobs (the neighbour keys its own solves offered), so nothing is
@@ -2268,6 +2270,8 @@ class Scheduler:
             if mirror is not None:
                 with self.cache.lock:
                     mirror.warm_usage_buckets()
+                    if fwk.tpu._partials is not None:
+                        fwk.tpu._partials.warm_refresh(mirror.sync())
         except Exception:
             log.exception("warmup: warming the delta paths failed")
         finally:

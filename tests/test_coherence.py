@@ -99,6 +99,33 @@ def test_clean_steady_state_audits():
         auditor.assert_clean()
 
 
+def test_staged_cycles_audit_clean():
+    """Cycles the encode's staging program carries (the mirror's rows,
+    the partials' refresh and gather in the snapshot's one transfer)
+    stamp both residents as their own sync paths do: every consume and
+    dispatch audit passes, bookmarks taken between them included."""
+    from kubernetes_tpu.utils import trace
+
+    sched = _mk_sched()
+    _add_nodes(sched)
+    sched.schedule_pending(_mk_pods(0, 8, seed=5))
+    with _isolated() as auditor:
+        t0 = trace.now()
+        for step in range(1, 5):
+            _churn(sched, step)
+            sched._mirror.speculation_point()
+            sched._partials.speculation_point()
+            sched.schedule_pending(_mk_pods(0, 8, seed=5))
+        spans = [
+            dict(zip(trace.SPAN_FIELDS, row))
+            for row in trace.snapshot(t0, float("inf"))["spans"]
+        ]
+        staged = [r["a1"] for r in spans if r["name"] == "sched.encode.stage"]
+        assert staged == [1.0] * 4
+        assert auditor.audits_total > 0
+        auditor.assert_clean()
+
+
 def test_disarmed_hooks_are_noops():
     prev = epochs._active
     epochs._active = None

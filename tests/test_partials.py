@@ -391,3 +391,36 @@ def test_mesh_small_bucket_replicates():
         for i in range(4)
     ]
     assert warm.schedule_pending(pods) == cold.schedule_pending(pods)
+
+
+@pytest.mark.parametrize("dirty", [1, 9, 64])
+def test_staged_refresh_is_bit_identical_to_a_cold_solve(dirty):
+    """Where only usage rows moved, the encode's staging program runs the
+    refresh and the gather (span sched.encode.stage: one transfer, one
+    program, a1 = 1): the warm solve stays bit-identical to the cold one
+    and the store to a full recompute, whatever the dirty rows' count up
+    to the chunk."""
+    from kubernetes_tpu.utils import trace
+
+    warm, cold = _mk_sched(True), _mk_sched(False)
+    _add_nodes((warm, cold), 160, seed=23)
+    _solve_both(warm, cold, _mk_pods(0, 12, seed=40))
+    for i in range(dirty):
+        p = make_pod(f"fill-{i}").req(cpu_milli=100, mem=128 * MI).obj()
+        for s in (warm, cold):
+            s.assume(p, f"n-{i}")
+    t0 = trace.now()
+    _solve_both(warm, cold, _mk_pods(0, 12, seed=40))
+    spans = [
+        dict(zip(trace.SPAN_FIELDS, row))
+        for row in trace.snapshot(t0, float("inf"))["spans"]
+    ]
+    rows = [
+        (int(r["n"]), int(r["a0"]), int(r["a1"]))
+        for r in spans if r["name"] == "sched.encode.stage"
+    ]
+    # the warm side's row is staged; the cold side has no partials and
+    # takes the mirror's own scatter and the plain unpack
+    assert rows[0] == (1, 1, 1) and rows[1][2] == 0, rows
+    assert warm._partials.verify(warm._mirror._dev, None)
+    assert warm._partials.stats()["recomputed_rows_total"] > 0
