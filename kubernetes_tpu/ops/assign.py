@@ -38,6 +38,7 @@ import numpy as np
 
 from ..analysis import retrace
 from ..analysis.markers import hot_path
+from ..utils import trace
 from .filters import (
     fits_resources,
     pod_view,
@@ -916,6 +917,15 @@ def greedy_assign_jit(cfg: ScoreConfig = DEFAULT_SCORE_CONFIG):
 #     (ports/spread/term pairwise masks) and serializes the whole wave
 #     through the original step body when the partitioner got it wrong —
 #     ANY contiguous partition of the solve order is therefore correct.
+#   * A saturated affinity term is no write.  A pod that requires term t
+#     by affinity, once t has a matching pod somewhere (global_any), can
+#     only land where t is already present: the escape needs every term
+#     of the pod unmatched everywhere.  Presence is domain-wide and only
+#     goes 0 -> 1, so its own match of t flips no bit any member reads.
+#     Both the plan (against the batch-start table) and the device
+#     (against the wave-start global_any) drop that write; anti-affinity
+#     writes, matches of terms the pod does not require, and the escape
+#     (global_any[t] == 0) couple as ever.
 #   * Within a safe wave, a member's sequential score vector differs from
 #     its wave-start vector only at nodes picked earlier in the wave, so
 #     the mini-scan compares the corrected picked-node scores against the
@@ -970,7 +980,10 @@ class WavePlan(NamedTuple):
 def waves_couple(features: FeatureFlags) -> bool:
     """True when members of one wave can conflict (host ports, spread
     rows, inter-pod terms): how many waves a batch then splits into is
-    its composition's, anything from P/K to one wave a pod."""
+    its composition's, anything from P/K to one wave a pod.  (A batch
+    whose only term rows are saturated affinity terms plans P/K waves,
+    but its shape stays the coupled one: whether a term is saturated is
+    the cluster's state, not the feature set.)"""
     return bool(
         features.ports or features.spread or features.soft_spread
         or features.interpod
@@ -1025,7 +1038,11 @@ def plan_waves(  # graftlint: disable=purity -- host-side prep: the wave partiti
       * spread/terms: a wave member WRITES a constraint row this pod
         READS (spread: pod_matches vs pod_idx; terms: matches_incoming ∪
         anti vs matches_incoming ∪ anti ∪ aff) — count/bit drift inside
-        the wave would break the wave-start evaluation;
+        the wave would break the wave-start evaluation.  A pod's match
+        of a term it requires by affinity is no write where the term has
+        a matching pod somewhere at the batch's start (saturated: the pod
+        can only land where the term is present already, see the
+        wavefront section comment);
       * headroom: aggregate wave demand would exceed `headroom_frac` of
         the emptiest node's free capacity (elementwise) — a heuristic
         that keeps per-member fit-flip fallbacks rare, not a correctness
@@ -1033,7 +1050,13 @@ def plan_waves(  # graftlint: disable=purity -- host-side prep: the wave partiti
 
     The partition is a pure performance hint: wavefront_assign re-checks
     coupling on device and serializes unsafe waves, so any output of this
-    function yields placements identical to the scan."""
+    function yields placements identical to the scan.
+
+    Row ``sched.plan.terms`` (one a plan whose batch has term rows): n =
+    pods that carry or match a term, a0 = those whose match of a term
+    they require was dropped as saturated, a1 = affinity terms with no
+    matching pod at the batch's start (the escape is open)."""
+    t_plan = trace.now()
     if features is None:
         features = features_of(snapshot)
     pods = snapshot.pods
@@ -1060,11 +1083,16 @@ def plan_waves(  # graftlint: disable=purity -- host-side prep: the wave partiti
     if use_terms:
         t_dim = np.asarray(snapshot.terms.valid).shape[0]
         mi = np.asarray(snapshot.terms.matches_incoming)
+        aff_idx = np.asarray(snapshot.terms.aff_idx)
         anti = _pack_idx_rows(np.asarray(snapshot.terms.anti_idx), t_dim)
-        aff = _pack_idx_rows(np.asarray(snapshot.terms.aff_idx), t_dim)
+        aff = _pack_idx_rows(aff_idx, t_dim)
         w = min(mi.shape[1], anti.shape[1])
-        writes_tm = mi[:, :w] | anti[:, :w]
-        reads_tm = writes_tm | aff[:, :w]
+        present = _terms_present(snapshot, aff_idx)
+        sat = aff[:, :w] & _pack_idx_rows(
+            np.flatnonzero(present).astype(np.int32)[None, :], t_dim
+        )[:, :w]
+        writes_tm = (mi[:, :w] & ~sat) | anti[:, :w]
+        reads_tm = mi[:, :w] | anti[:, :w] | aff[:, :w]
 
     req = np.asarray(pods.req)
     alloc = np.asarray(snapshot.cluster.allocatable)
@@ -1139,7 +1167,33 @@ def plan_waves(  # graftlint: disable=purity -- host-side prep: the wave partiti
     members = np.full((w_pad, wave_cap), -1, dtype=np.int32)
     for wi, wv in enumerate(waves):
         members[wi, : len(wv)] = wv
+    if use_terms:
+        real = np.asarray(pods.valid)
+        required = np.unique(aff_idx[real][aff_idx[real] >= 0])
+        trace.event(
+            "sched.plan.terms", t_plan, trace.now(),
+            n=int((reads_tm[real] != 0).any(axis=1).sum()),
+            a0=int(((mi[:, :w] & sat)[real] != 0).any(axis=1).sum()),
+            a1=int((~present[required]).sum()),
+        )
     return WavePlan(members=members, n_waves=n_waves)
+
+
+def _terms_present(snapshot: Snapshot, aff_idx: np.ndarray) -> np.ndarray:  # graftlint: disable=purity -- host-side prep on the pre-transfer snapshot
+    """bool[T]: the terms some pod requires by affinity that have a
+    matching bound pod on a valid node carrying the term's key — the
+    host-side twin of interpod.prep_terms' global_any, row by row."""
+    terms = snapshot.terms
+    valid = np.asarray(terms.valid)
+    out = np.zeros(valid.shape[0], dtype=bool)
+    rows = np.unique(aff_idx[aff_idx >= 0])
+    if rows.size == 0:
+        return out
+    cluster = snapshot.cluster
+    keyed = np.asarray(cluster.topo_ids)[:, np.asarray(terms.slot)[rows]].T >= 0
+    ok = keyed & np.asarray(cluster.node_valid)[None, :] & valid[rows, None]
+    out[rows] = ((np.asarray(terms.node_matches)[rows] > 0) & ok).any(axis=1)
+    return out
 
 
 def _rows_cluster(cap, requested, nonzero):
@@ -1244,17 +1298,22 @@ def wavefront_assign(
         anti_w = _pack_bits_t(_idx_to_bits(terms.anti_idx, t_dim))
         aff_w = _pack_bits_t(_idx_to_bits(terms.aff_idx, t_dim))
         tw = min(terms.matches_incoming.shape[1], anti_w.shape[1])
-        tm_writes = terms.matches_incoming[:, :tw] | anti_w[:, :tw]
-        tm_reads = tm_writes | aff_w[:, :tw]
+        tm_mi = terms.matches_incoming[:, :tw]
+        tm_anti = anti_w[:, :tw]
+        tm_aff = aff_w[:, :tw]
+        tm_reads = tm_mi | tm_anti | tm_aff
     if features.spread or features.soft_spread:
         c_rows = spread.valid.shape[0]
         sp_reads_all = (
             jnp.arange(c_rows)[None, None, :] == spread.pod_idx[:, :, None]
         ).any(axis=1)  # bool[P, C]
 
-    def wave_safe(mk, mvalid):
+    def wave_safe(mk, mvalid, tm_global):
         """True when no member writes dynamic state an in-wave successor
-        reads — the conflict-detection pass.  mk: clipped member ids."""
+        reads — the conflict-detection pass.  mk: clipped member ids;
+        tm_global: the wave-start global_any (a match of a term the
+        member requires by affinity is no write once that term is set
+        there: the member lands where it is present already)."""
         tri = (arange_k[:, None] < arange_k[None, :]) & (
             mvalid[:, None] & mvalid[None, :]
         )
@@ -1269,7 +1328,8 @@ def wavefront_assign(
             hit = (wr[:, None, :] & rd[None, :, :]).any(-1)
             ok = ok & ~(tri & hit).any()
         if features.interpod:
-            wr = tm_writes[mk]
+            sat = tm_aff[mk] & tm_global[None, :tw]
+            wr = (tm_mi[mk] & ~sat) | tm_anti[mk]
             rd = tm_reads[mk]
             hit = (wr[:, None, :] & rd[None, :, :]).any(-1)
             ok = ok & ~(tri & hit).any()
@@ -1616,7 +1676,9 @@ def wavefront_assign(
             return c + (jnp.int32(0), jnp.int32(1)), outs
 
         def many(_):
-            return jax.lax.cond(wave_safe(mk, mvalid), fast, serial, None)
+            return jax.lax.cond(
+                wave_safe(mk, mvalid, tm_global), fast, serial, None
+            )
 
         def skip_wave(_):
             return state0 + (jnp.int32(0), jnp.int32(0)), lane_rows()

@@ -27,8 +27,17 @@ All selector/namespace string matching was precomputed host-side into
 schema.TermTable matrices — the O(pods x nodes) pairwise term the north
 star turns into bit algebra.
 
-Not yet modelled: namespaceSelector on terms, matchLabelKeys, and the
-preferred (scoring) terms — required terms only.
+The preferred (scoring) terms are modelled too, apart from the filter:
+prep_pref_pod domain-sums their bound-pod match counts and owner weights
+once a solve (assign._solver_prep, auction.py), and pref_pod_raw scores
+both directions for one pod class (scores.py, auction.py).  Not yet
+modelled: namespaceSelector on terms and matchLabelKeys.
+
+Presence is also why a pod that requires term t by affinity couples with
+nothing through its own match of t once global_any[t] is set: it can land
+only where t is present already (the escape needs every term of the pod
+unmatched everywhere), so the write flips no bit.  The wavefront planner
+and its device check use this (assign.plan_waves, wave_safe).
 """
 
 from __future__ import annotations

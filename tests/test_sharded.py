@@ -418,3 +418,36 @@ def test_sharded_auction_gang_release_parity():
     np.testing.assert_array_equal(
         np.asarray(single.gang_dropped), np.asarray(multi.gang_dropped)
     )
+
+
+# -- a saturated affinity term is no write, under the node shard -------------
+#
+# global_any is replicated (psum-built, updated from the broadcast topology
+# row), so every shard reaches the same safety verdict: results and all
+# three counters equal the single chip's.  Two 32-member waves each: the
+# saturated batch's own plan (safe), and the escape batch forced into the
+# same shape: its first wave is serialized, its second is safe, because
+# the first pod's escape set global_any before the second wave began.
+
+
+@pytest.mark.parametrize("name", ["saturated", "escape"])
+def test_sharded_affine_waves_match_the_scan_and_the_single_chip(name):
+    from kubernetes_tpu.testing.oracle import Oracle
+
+    nodes, pods, bound = wave_cases.affine_case(name)
+    snap, meta = schema.SnapshotBuilder().build(nodes, pods, bound_pods=bound)
+    o = wave_cases.solve_order(snap)
+    members = wave_cases.plan_of([o[:32], o[32:]], k=32)
+    if name == "saturated":
+        np.testing.assert_array_equal(members, assign.plan_waves(snap).members[:8])
+    scan = assign.greedy_assign(snap)
+    single = assign.wavefront_assign(snap, members)
+    multi = sharded.sharded_wavefront_assign(snap, members, sharded.make_mesh(8))
+    for res in (single, multi):
+        wave_cases.assert_bit_parity(scan, res)
+    assert wave_cases.counters(single) == wave_cases.counters(multi) == (
+        (2, 0, 64) if name == "saturated" else (2, 32, 64)
+    )
+    got = [meta.node_name(int(i)) if i >= 0 else None
+           for i in np.asarray(multi.assignment)[: len(pods)]]
+    assert got == Oracle(nodes, bound_pods=bound).schedule(pods)

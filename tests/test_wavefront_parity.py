@@ -15,6 +15,7 @@ import wave_cases
 
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.ops import assign, schema
+from kubernetes_tpu.testing.oracle import Oracle
 from kubernetes_tpu.testing.wrappers import GI, MI, make_node, make_pod
 
 
@@ -383,3 +384,89 @@ def test_fit_flip_inside_a_five_member_wave_still_reevaluates():
     )
     wave_cases.assert_bit_parity(scan, wave)
     assert wave_cases.counters(wave) == (2, 4, 5 + 3)
+
+
+# -- a saturated affinity term is no write -----------------------------------
+#
+# A pod that requires term t by affinity lands only where t is present
+# once t has a match anywhere, so its own match of t flips no bit: the
+# plan fills its waves, and the device's safety check agrees.  Anti terms,
+# matches of a term the pod does not carry, and the escape couple as ever.
+
+
+def solve_affine(nodes, pods, bound, members=None, oracle=True):
+    """Scan parity always; the oracle's placements where it covers the
+    batch.  It scores no inter-pod term (InterPodAffinity's score, whose
+    hardPodAffinityWeight draws a pod that matches a bound pod's required
+    affinity term towards it), so a batch with writer-only pods is held
+    to the scan alone."""
+    snap, meta = schema.SnapshotBuilder().build(nodes, pods, bound_pods=bound)
+    scan = assign.greedy_assign_jit()(snap)
+    if members is None:
+        members = assign.plan_waves(snap).members
+    wave = assign.wavefront_assign_jit()(snap, wave_members=members)
+    assert_parity(scan, wave, len(pods))
+    if oracle:
+        want = Oracle(nodes, bound_pods=bound).schedule(pods)
+        got = [meta.node_name(int(i)) if i >= 0 else None
+               for i in np.asarray(wave.assignment)[: len(pods)]]
+        assert got == want, "placements diverge from the oracle"
+    return snap, wave
+
+
+def test_a_self_affine_batch_with_a_bound_match_plans_full_waves():
+    nodes, pods, bound = wave_cases.affine_case("saturated")
+    snap, _ = schema.SnapshotBuilder().build(nodes, pods, bound_pods=bound)
+    plan = assign.plan_waves(snap)
+    assert plan.n_waves <= 2            # 64 before the rule: one a pod
+    assert plan.members.shape == (assign.wave_rows(64, 32, True), 32)
+    _, wave = solve_affine(nodes, pods, bound, plan.members)
+    assert wave_cases.counters(wave) == (2, 0, 64)
+    zones = {int(i) % wave_cases.AFFINE_ZONES for i in np.asarray(wave.assignment)}
+    assert zones == {0}                 # every pod beside the bound matches
+
+
+def test_the_escape_keeps_its_coupling():
+    nodes, pods, bound = wave_cases.affine_case("escape")
+    snap, _ = schema.SnapshotBuilder().build(nodes, pods, bound_pods=bound)
+    plan = assign.plan_waves(snap)
+    assert plan.n_waves == 64           # the first pod alone, as every other
+    _, wave = solve_affine(nodes, pods, bound, plan.members)
+    assert wave_cases.counters(wave) == (64, 0, 64)
+
+
+def test_a_pod_that_matches_a_term_it_does_not_carry_still_couples():
+    nodes, pods, bound = wave_cases.affine_case("writer")
+    snap, _ = schema.SnapshotBuilder().build(nodes, pods, bound_pods=bound)
+    plan = assign.plan_waves(snap)
+    o = wave_cases.solve_order(snap)
+    # the writer rides with the saturated pods before it; the pod after
+    # it reads what it writes
+    assert plan.n_waves == 2
+    assert plan.members[0, :9].tolist() == o[:9] and plan.members[1, 0] == o[9]
+    solve_affine(nodes, pods, bound, plan.members, oracle=False)
+    # forced into one wave, the device finds the writer and serializes
+    _, forced = solve_affine(
+        nodes, pods, bound, one_wave_members(snap), oracle=False
+    )
+    assert int(forced.wave_fallbacks) == len(o)
+
+
+@pytest.mark.parametrize("name", ["saturated", "escape"])
+def test_a_hostile_one_wave_partition_of_an_affine_batch_is_exact(name):
+    nodes, pods, bound = wave_cases.affine_case(name)
+    snap, _ = schema.SnapshotBuilder().build(nodes, pods, bound_pods=bound)
+    _, wave = solve_affine(nodes, pods, bound, one_wave_members(snap))
+    # saturated: safe, no fallback; escape: the whole wave serialized
+    assert int(wave.wave_fallbacks) == (0 if name == "saturated" else 64)
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_random_affinity_mixes_solve_exactly(seed):
+    nodes, pods, bound = wave_cases.mixed_affine_case(seed)
+    snap, _ = solve_affine(nodes, pods, bound, oracle=False)
+    rng = np.random.default_rng(seed)
+    o = wave_cases.solve_order(snap)
+    cuts = sorted(rng.choice(np.arange(1, len(o)), size=3, replace=False).tolist())
+    rows = [o[a:b] for a, b in zip([0] + cuts, cuts + [len(o)])]
+    solve_affine(nodes, pods, bound, wave_cases.plan_of(rows, k=64), oracle=False)

@@ -107,3 +107,84 @@ def assert_bit_parity(scan, wave):
             np.asarray(getattr(wave.cluster, name)),
             err_msg=name,
         )
+
+
+# -- self-affine batches: a saturated affinity term is no write ----------------
+
+AFFINE_ZONES = 8
+
+
+def zoned_nodes(n):
+    """n nodes of 4 cpu (40 template pods each) over AFFINE_ZONES zones,
+    node i in zone-(i mod 8): the port's node-default.yaml at toy size."""
+    return [
+        make_node(f"n{i}").capacity(cpu_milli=4000, mem=32 * GI, pods=110)
+        .zone(f"zone-{i % AFFINE_ZONES}").obj()
+        for i in range(n)
+    ]
+
+
+def affine(name, color="blue", key=api.LABEL_ZONE):
+    """scheduler_perf's pod-with-pod-affinity: requires a pod of its own
+    label in its zone, so every pod of a batch reads the term every other
+    one matches."""
+    return (
+        make_pod(name).req(cpu_milli=100, mem=500 * MI).label("color", color)
+        .pod_affinity({"color": color}, key)
+    )
+
+
+def writer(name, color="blue"):
+    """Matches the affine pods' term and carries none: its match of the
+    term is a write whatever the term's state."""
+    return make_pod(name).req(cpu_milli=100, mem=500 * MI).label("color", color)
+
+
+def affine_case(name):
+    """(nodes, pods, bound pods) of one self-affine shape:
+
+    saturated    64 affine pods, 4 bound matches in zone-0: every pod's
+                 match of its own term is saturated
+    escape       the same pods and no bound match: the first pod takes
+                 the escape, and every pod couples with the one before
+    writer       8 affine pods, a writer-only pod, 8 affine pods, with the
+                 bound matches: the writer still couples with what follows
+    """
+    nodes = zoned_nodes(64)
+    bound = [affine(f"b{i}").node_name(f"n{8 * i}").obj() for i in range(4)]
+    if name == "saturated":
+        return nodes, [affine(f"p{i}").obj() for i in range(64)], bound
+    if name == "escape":
+        return nodes, [affine(f"p{i}").obj() for i in range(64)], []
+    assert name == "writer", name
+    pods = [affine(f"a{i}").obj() for i in range(8)] + [writer("w").obj()]
+    pods += [affine(f"c{i}").obj() for i in range(8)]
+    return nodes, pods, bound
+
+
+def mixed_affine_case(seed):
+    """A seeded batch mixing zone affinity, hostname affinity, hostname
+    anti-affinity and writer-only pods over two colors, on 32 zoned
+    nodes with a few bound pods of each color (none for some seeds)."""
+    rng = np.random.default_rng(seed)
+    nodes = zoned_nodes(32)
+    bound = []
+    for i in range(int(rng.integers(0, 4))):
+        color = str(rng.choice(["blue", "red"]))
+        bound.append(writer(f"b{i}", color).node_name(f"n{int(rng.integers(32))}").obj())
+    pods = []
+    for i in range(48):
+        color = str(rng.choice(["blue", "red"]))
+        r = rng.random()
+        if r < 0.45:
+            pw = affine(f"p{i}", color)
+        elif r < 0.6:
+            pw = affine(f"p{i}", color, api.LABEL_HOSTNAME)
+        elif r < 0.75:
+            pw = writer(f"p{i}", color).pod_anti_affinity(
+                {"color": "red" if color == "blue" else "blue"}, api.LABEL_HOSTNAME
+            )
+        else:
+            pw = writer(f"p{i}", color)
+        pods.append(pw.obj())
+    return nodes, pods, bound
